@@ -15,6 +15,9 @@ def main():
     args = ap.parse_args()
     t0 = time.time()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (bench_cycles, bench_embedding, bench_kernels,
                             bench_kvbank, bench_serve, bench_stream,
                             bench_sweep, fig18_dedup, fig19_split,

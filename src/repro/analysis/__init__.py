@@ -19,11 +19,10 @@ context manager asserting a code region compiled nothing new.
 See docs/analysis.md for what each layer proves and how to extend it.
 """
 from repro.analysis.base import Finding, format_findings
-from repro.analysis.guard import (GuardRecord, RecompileError, available,
-                                  cache_size, recompile_guard)
+from repro.analysis.guard import (GuardRecord, RecompileError, cache_size,
+                                  recompile_guard)
 
 __all__ = [
     "Finding", "format_findings",
-    "GuardRecord", "RecompileError", "available", "cache_size",
-    "recompile_guard",
+    "GuardRecord", "RecompileError", "cache_size", "recompile_guard",
 ]

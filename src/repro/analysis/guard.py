@@ -16,9 +16,7 @@ region of code triggered more compilations than it budgeted for:
 
 Budgets are *upper bounds* checked at context exit (``max_compiles=None``
 disables the check and just records); exact-count assertions use
-``g.compiles()``. Relies on jit's ``_cache_size()`` introspection — when a
-jax version drops it, ``available()`` turns False and the tests using the
-guard skip rather than fail (the conftest fixtures do this).
+``g.compiles()``. Relies on jit's ``_cache_size()`` introspection.
 
 Guarded entry points are *named* so tests don't import engine internals;
 ``GUARDED`` maps a stable name to a lazy import of the jitted callable.
@@ -79,18 +77,9 @@ def resolve(target: Union[str, Callable]) -> Callable:
                        f"have {sorted(GUARDED)}") from None
 
 
-def cache_size(target: Union[str, Callable]) -> Optional[int]:
-    """Compiled-program count of a jitted callable, or None when this jax
-    version does not expose jit cache introspection."""
-    fn = resolve(target)
-    probe = getattr(fn, "_cache_size", None)
-    if probe is None:
-        return None
-    return probe()
-
-
-def available(target: Union[str, Callable] = "sweep") -> bool:
-    return cache_size(target) is not None
+def cache_size(target: Union[str, Callable]) -> int:
+    """Compiled-program count of a jitted callable."""
+    return resolve(target)._cache_size()
 
 
 class RecompileError(AssertionError):
@@ -119,19 +108,12 @@ def recompile_guard(*targets: Union[str, Callable],
     ``max_compiles`` new programs across ``targets`` (default: none —
     everything must hit existing caches). Targets are ``GUARDED`` names or
     jitted callables; no targets means all ``GUARDED`` entry points.
-
-    Raises ``RuntimeError`` when jit cache introspection is unavailable —
-    call ``available()`` first (or use the conftest fixtures, which skip).
     """
     names = list(targets) if targets else sorted(GUARDED)
     resolved: List[Tuple[str, Callable, int]] = []
     for t in names:
         fn = resolve(t)
         before = cache_size(fn)
-        if before is None:
-            raise RuntimeError(
-                "jit._cache_size() not available in this jax version — "
-                "gate with repro.analysis.guard.available()")
         label = t if isinstance(t, str) else getattr(t, "__name__", str(t))
         resolved.append((label, fn, before))
     rec = GuardRecord(resolved)

@@ -21,8 +21,9 @@ Two kernels share the layout:
   grid ``(B,)``; per-sequence blocks q ``(1, H, D)``, banks
   ``(1, NB, S, P, Hkv, D)``, parity ``(1, NB/2, S, P, Hkv, D)``.
 * ``gather_pool_pallas`` — the SERVING pool gather (shared pool, per-batch
-  page table), grid ``(B, MP)``: one logical page reconstructed per step,
-  bit-exact vs ``ops.gather_pool_layer`` (the reference anchor), so the
+  page table), grid ``(B, MP)``: one logical page reconstructed per step
+  from pages the scalar-prefetched page table addresses, bit-exact vs
+  ``ops.gather_pool_layer`` (the reference anchor), so the
   ``ServeConfig(kernel="pallas")`` switch is token-identical by
   construction.
 """
@@ -33,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import resolve_interpret
 
@@ -47,9 +49,7 @@ def _kv_decode_kernel(q_ref, kb_ref, vb_ref, kp_ref, vp_ref, upar_ref,
     slen = slen_ref[0]
 
     def load_page(ref, b_, s_):
-        return pl.load(ref, (pl.dslice(0, 1), pl.dslice(b_, 1),
-                             pl.dslice(s_, 1), slice(None), slice(None),
-                             slice(None)))[0, 0, 0]
+        return ref[0, b_, s_]
 
     def step(t, carry):
         m, s, acc = carry
@@ -57,7 +57,7 @@ def _kv_decode_kernel(q_ref, kb_ref, vb_ref, kp_ref, vp_ref, upar_ref,
         slot = t // nb
         sib = bank ^ 1
         grp = bank // 2
-        use_par = pl.load(upar_ref, (pl.dslice(0, 1), pl.dslice(t, 1)))[0, 0] > 0
+        use_par = upar_ref[0, t] > 0
         k_dir = load_page(kb_ref, bank, slot)              # (P, Hkv, D) uint
         k_rec = load_page(kb_ref, sib, slot) ^ load_page(kp_ref, grp, slot)
         v_dir = load_page(vb_ref, bank, slot)
@@ -142,40 +142,22 @@ def coded_kv_decode_pallas(
 # Serving pool gather: pool-indirected page reconstruction
 # ---------------------------------------------------------------------------
 
-def _load_pool_page(ref, b_, s_):
-    return pl.load(ref, (pl.dslice(b_, 1), pl.dslice(s_, 1),
-                         slice(None), slice(None), slice(None)))[0, 0]
-
-
-def _pool_gather_kernel(pt_ref, up_ref, kb_ref, vb_ref, kp_ref, vp_ref,
-                        ko_ref, vo_ref, *, nb):
-    phys = pt_ref[0, 0]
-    alloc = phys >= 0
-    ph = jnp.maximum(phys, 0)
-    bank = ph % nb
-    slot = ph // nb
-    use_par = up_ref[0, 0] > 0
-    k_dir = _load_pool_page(kb_ref, bank, slot)            # (P, Hkv, D)
-    v_dir = _load_pool_page(vb_ref, bank, slot)
-    k_rec = _load_pool_page(kb_ref, bank ^ 1, slot) \
-        ^ _load_pool_page(kp_ref, bank // 2, slot)
-    v_rec = _load_pool_page(vb_ref, bank ^ 1, slot) \
-        ^ _load_pool_page(vp_ref, bank // 2, slot)
-    k = jnp.where(use_par, k_rec, k_dir)
-    v = jnp.where(use_par, v_rec, v_dir)
+def _pool_gather_kernel(pt_ref, up_ref, kd_ref, vd_ref, ks_ref, vs_ref,
+                        kp_ref, vp_ref, ko_ref, vo_ref, *, mp):
+    n = pl.program_id(0) * mp + pl.program_id(1)
+    alloc = pt_ref[n] >= 0
+    use_par = up_ref[n] > 0
+    k = jnp.where(use_par, ks_ref[0, 0] ^ kp_ref[0, 0], kd_ref[0, 0])
+    v = jnp.where(use_par, vs_ref[0, 0] ^ vp_ref[0, 0], vd_ref[0, 0])
     ko_ref[0, 0] = jnp.where(alloc, k, 0)
     vo_ref[0, 0] = jnp.where(alloc, v, 0)
 
 
-def _pool_gather_uncoded_kernel(pt_ref, kb_ref, vb_ref, ko_ref, vo_ref,
-                                *, nb):
-    phys = pt_ref[0, 0]
-    alloc = phys >= 0
-    ph = jnp.maximum(phys, 0)
-    bank = ph % nb
-    slot = ph // nb
-    ko_ref[0, 0] = jnp.where(alloc, _load_pool_page(kb_ref, bank, slot), 0)
-    vo_ref[0, 0] = jnp.where(alloc, _load_pool_page(vb_ref, bank, slot), 0)
+def _pool_gather_uncoded_kernel(pt_ref, kd_ref, vd_ref, ko_ref, vo_ref, *,
+                                mp):
+    alloc = pt_ref[pl.program_id(0) * mp + pl.program_id(1)] >= 0
+    ko_ref[0, 0] = jnp.where(alloc, kd_ref[0, 0], 0)
+    vo_ref[0, 0] = jnp.where(alloc, vd_ref[0, 0], 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -192,39 +174,51 @@ def gather_pool_pallas(
     """Pool-indirected coded page gather: (B, MP, P, Hkv, D) uint K/V.
 
     Grid ``(B, MP)`` — one logical page per step, reconstructed with the
-    planned direct or degraded (sibling ^ parity) read. Pure uint
-    select/XOR, so the result is bit-exact vs the reference
-    ``gather_pool_layer`` for any plan; unallocated pages read as zero.
-    The uncoded pool (NG == 0) compiles a kernel with no parity operands.
+    planned direct or degraded (sibling ^ parity) read. The page table and
+    the plan are scalar-prefetched, and each page operand's ``index_map``
+    resolves its block from them: the direct page ``(bank, slot)``, the
+    sibling ``(bank ^ 1, slot)`` and the parity page ``(bank // 2, slot)``
+    arrive as ``(1, 1, P, Hkv, D)`` blocks, so VMEM holds pages, never the
+    pool. Free (-1) entries fetch page 0 and are zeroed in the body. Pure
+    uint select/XOR, so the result is bit-exact vs the reference
+    ``gather_pool_layer`` for any plan. The uncoded pool (NG == 0)
+    compiles a kernel with no parity operands.
     """
     interpret = resolve_interpret(interpret)
-    nb, s_, pg, hkv, d = k_banks.shape
+    nb, _, pg, hkv, d = k_banks.shape
     b, mp = page_table.shape
     ng = k_par.shape[0]
-    grid = (b, mp)
-    bank_spec = pl.BlockSpec((nb, s_, pg, hkv, d),
-                             lambda i, p: (0, 0, 0, 0, 0))
-    tab_spec = pl.BlockSpec((1, 1), lambda i, p: (i, p))
-    out_spec = pl.BlockSpec((1, 1, pg, hkv, d), lambda i, p: (i, p, 0, 0, 0))
+    pt = page_table.astype(jnp.int32).reshape(b * mp)
+
+    def page_spec(which):
+        def index_map(i, p, pt_ref, *_):
+            ph = jnp.maximum(pt_ref[i * mp + p], 0)
+            bank, slot = ph % nb, ph // nb
+            return (which(bank), slot, 0, 0, 0)
+        return pl.BlockSpec((1, 1, pg, hkv, d), index_map)
+
+    direct = page_spec(lambda bank: bank)
+    sibling = page_spec(lambda bank: bank ^ 1)
+    parity = page_spec(lambda bank: bank // 2)
+    out_spec = pl.BlockSpec((1, 1, pg, hkv, d),
+                            lambda i, p, *_: (i, p, 0, 0, 0))
     out_shape = [jax.ShapeDtypeStruct((b, mp, pg, hkv, d), k_banks.dtype)] * 2
     if ng == 0:
         return pl.pallas_call(
-            functools.partial(_pool_gather_uncoded_kernel, nb=nb),
+            functools.partial(_pool_gather_uncoded_kernel, mp=mp),
             out_shape=out_shape,
-            grid=grid,
-            in_specs=[tab_spec, bank_spec, bank_spec],
-            out_specs=[out_spec, out_spec],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(b, mp),
+                in_specs=[direct, direct], out_specs=[out_spec, out_spec]),
             interpret=interpret,
-        )(page_table, k_banks, v_banks)
-    par_spec = pl.BlockSpec((ng, s_, pg, hkv, d),
-                            lambda i, p: (0, 0, 0, 0, 0))
+        )(pt, k_banks, v_banks)
     return pl.pallas_call(
-        functools.partial(_pool_gather_kernel, nb=nb),
+        functools.partial(_pool_gather_kernel, mp=mp),
         out_shape=out_shape,
-        grid=grid,
-        in_specs=[tab_spec, tab_spec, bank_spec, bank_spec,
-                  par_spec, par_spec],
-        out_specs=[out_spec, out_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, mp),
+            in_specs=[direct, direct, sibling, sibling, parity, parity],
+            out_specs=[out_spec, out_spec]),
         interpret=interpret,
-    )(page_table, use_parity.astype(jnp.int32), k_banks, v_banks,
-      k_par, v_par)
+    )(pt, use_parity.astype(jnp.int32).reshape(b * mp),
+      k_banks, v_banks, k_banks, v_banks, k_par, v_par)

@@ -27,7 +27,7 @@ def _encode_kernel(members_ref, banks_ref, out_ref):
     acc = None
     for mm in range(members_ref.shape[1]):
         m = members_ref[j, mm]
-        slab = pl.load(banks_ref, (pl.dslice(jnp.maximum(m, 0), 1), slice(None), slice(None)))
+        slab = banks_ref[pl.ds(jnp.maximum(m, 0), 1), :, :]
         slab = jnp.where(m >= 0, slab, jnp.zeros_like(slab))
         acc = slab if acc is None else acc ^ slab
     out_ref[...] = acc

@@ -67,16 +67,6 @@ _DTYPE_BYTES = {
 }
 
 
-def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: older
-    releases return a list with one dict per program, newer ones return the
-    dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def _nbytes(dtype: str, dims: str) -> int:
     n = 1
     for d in dims.split(","):
@@ -200,7 +190,7 @@ def cost_pass(cfg: ModelConfig, shape: ShapeSpec, mesh, *, fsdp: bool = True,
                              fsdp=fsdp, remat=remat, n_micro=n_micro,
                              kv_variant=kv_variant)
         compiled = lowered.compile()
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
         metrics[L] = {
             "flops": float(ca.get("flops", 0.0)),
@@ -269,7 +259,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     rec["memory"]["live_bytes"] = int(live)
     rec["fits_hbm_16g"] = bool(live < 16e9)
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
     coll_full = collective_bytes(compiled.as_text())
     rec["scan_hlo"] = {

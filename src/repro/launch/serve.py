@@ -17,6 +17,7 @@ import time
 import jax
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.obs import serve as obs_serve
 from repro.runtime.server import Request, ServeConfig, Server
@@ -48,10 +49,12 @@ def main():
                     help="parity rows recoded per step (default: all)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = lm.init_params(cfg, jax.random.key(0), max_seq=args.max_seq)
+    params = lm.init_serving_params(cfg, jax.random.key(0),
+                                    max_seq=args.max_seq)
     sc = ServeConfig(n_slots=args.slots, max_prompt=args.max_prompt,
                      max_seq=args.max_seq, max_new_tokens=args.max_new,
                      coded=not args.uncoded, telemetry=args.telemetry,

@@ -14,6 +14,7 @@ import argparse
 
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.optim.adamw import OptConfig
 from repro.runtime.trainer import FaultPlan, TrainConfig, Trainer
@@ -36,6 +37,7 @@ def main():
                     help="inject synthetic faults at these steps (recovery demo)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

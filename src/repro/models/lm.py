@@ -19,6 +19,8 @@ accurate dry-run cost analysis) and per-layer ``jax.checkpoint`` for train.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -53,11 +55,11 @@ def _cast_params(params, cd):
 def _stack(fn, key, n: int):
     """Stack n per-layer param trees on axis 0. n == 0 yields zero-length
     leading dims (NOT None) so scans/tree.maps stay total — hybrid probe
-    configs can have zero attention layers."""
-    ps = [fn(k) for k in jax.random.split(key, max(n, 1))]
-    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *ps)
-    if n == 0:
-        return jax.tree.map(lambda a: a[:0], stacked)
+    configs can have zero attention layers. ``vmap`` builds the stacked
+    leaves directly (the same values as one ``fn`` call per key): no
+    per-layer copies are held beside them, and under ``jit`` the program
+    is one op per leaf, not one per layer."""
+    stacked = jax.vmap(fn)(jax.random.split(key, max(n, 1)))
     return jax.tree.map(lambda a: a[:n], stacked)
 
 
@@ -143,6 +145,16 @@ def init_params(cfg: ModelConfig, key, max_seq: int = 2048) -> Params:
     else:
         raise ValueError(cfg.family)
     return params
+
+
+def init_serving_params(cfg: ModelConfig, key, max_seq: int = 2048) -> Params:
+    """Serving weights: built in the compute dtype (bf16, the dtype Qwen2.5's
+    published checkpoints ship in) inside one ``jit``, so only the result
+    is ever allocated on the device. The f32 training master copy would not
+    fit one chip's HBM beside the decode step for a 3B model."""
+    scfg = dataclasses.replace(cfg, param_dtype=cfg.compute_dtype)
+    return jax.jit(functools.partial(init_params, scfg,
+                                     max_seq=max_seq))(key)
 
 
 def abstract_params(cfg: ModelConfig, max_seq: int = 2048):

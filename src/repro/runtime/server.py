@@ -204,15 +204,18 @@ class Server:
             length=pool.length.at[i].set(0))
 
     # ----------------------------------------------------------------- step
-    def step(self):
+    def step(self) -> List[Request]:
+        """Admit, then decode one step; return the requests it finished."""
         self._admit()
-        self.step_decode()
+        return self.step_decode()
 
-    def step_decode(self):
+    def step_decode(self) -> List[Request]:
         """One batched decode step (no admission) — exposed so telemetry
-        conformance checks can observe the pool between admit and decode."""
+        conformance checks can observe the pool between admit and decode.
+        Returns the requests this step finished."""
+        finished: List[Request] = []
         if not any(s is not None for s in self.slots):
-            return
+            return finished
         self.tokens, self.cache = self.decode(self.params, self.tokens,
                                               self.cache)
         self.steps_run += 1
@@ -229,12 +232,15 @@ class Server:
                 self.log.finish(req.rid)
                 self.slots[i] = None
                 self._retire(i)
+                finished.append(req)
+        return finished
 
     def run_until_drained(self, max_steps: int = 10_000) -> List[Request]:
+        """Step until the queue and every slot are empty; return the
+        requests finished meanwhile, in the order they finished."""
         finished: List[Request] = []
-        seen: set = set()
         for _ in range(max_steps):
-            self.step()
+            finished += self.step()
             if not self.queue and all(s is None for s in self.slots):
                 break
         return finished

@@ -196,7 +196,10 @@ class TraceSource:
         inside this buffer, else INT32_MAX ("more data behind the buffer").
         """
         positions = np.asarray(positions, np.int64)
-        self._fill_to(int(positions.max()) + chunk_len)
+        # one column past the furthest stage: a stream that ends exactly at
+        # a stage's last column must be told so (stream_end), not left
+        # waiting for data a lazy iterator has not yet said it lacks
+        self._fill_to(int(positions.max()) + chunk_len + 1)
         self._trim(int(positions.min()))
         if self._buf is None:                       # empty stream
             if self.n_cores is None:

@@ -163,16 +163,13 @@ def sweep_compile_count():
     around ``run_points`` to assert the compile count of a grid."""
     from repro.analysis import guard
 
-    if not guard.available("sweep"):
-        # private jax API; don't fail unrelated tests on a jax upgrade
-        pytest.skip("jit._cache_size() not available in this jax version")
     return lambda: guard.cache_size("sweep")
 
 
 @pytest.fixture
 def compile_guard():
-    """The generalized recompile guard (``repro.analysis.recompile_guard``)
-    with the availability skip applied: yields the context-manager factory.
+    """The generalized recompile guard (``repro.analysis.recompile_guard``):
+    yields the context-manager factory.
 
         with compile_guard("kernels.xor_encode", max_compiles=1):
             ...   # region may compile at most one new program
@@ -181,6 +178,4 @@ def compile_guard():
     callables; ``g.compiles()``/``g.deltas()`` give exact counts."""
     from repro.analysis import guard
 
-    if not guard.available("sweep"):
-        pytest.skip("jit._cache_size() not available in this jax version")
     return guard.recompile_guard

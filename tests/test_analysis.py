@@ -229,8 +229,6 @@ def test_jaxpr_layer_clean_on_src():
 # ----------------------------------------------------------- recompile guard
 def test_recompile_guard_counts_and_fails():
     f = jax.jit(lambda x: x * 2)
-    if not guard.available(f):
-        pytest.skip("jit._cache_size() not available in this jax version")
     with guard.recompile_guard(f, max_compiles=1) as g:
         f(jnp.ones(4))
         f(jnp.ones(4))                      # cache hit

@@ -20,8 +20,7 @@ def test_secant_matches_full_unroll():
     """cost(L) extrapolated from L∈{1,2} == measured full unroll at L=4
     (whisper-tiny decoder is cost-linear in depth)."""
     from repro.configs.base import get_config
-    from repro.launch.dryrun import (_reconstruct, _with_layers,
-                                     cost_analysis_dict, lower_cell)
+    from repro.launch.dryrun import _reconstruct, _with_layers, lower_cell
     from repro.launch.mesh import make_debug_mesh
     from repro.launch.shapes import ShapeSpec
 
@@ -35,7 +34,7 @@ def test_secant_matches_full_unroll():
     for L in (1, 2, 4):
         pcfg = _with_layers(cfg, L)
         lowered = lower_cell(pcfg, shape, mesh, unroll=L, q_chunk=0)
-        costs[L] = float(cost_analysis_dict(lowered.compile())
+        costs[L] = float(lowered.compile().cost_analysis()
                          .get("flops", 0.0))
     want = costs[4]
     got = _reconstruct(dataclasses.replace(cfg, n_layers=4),
@@ -99,10 +98,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, jax
 from repro.configs.base import get_config
-from repro.launch.dryrun import cost_analysis_dict, lower_cell
+from repro.launch.dryrun import lower_cell
+from repro.launch.mesh import make_debug_mesh
 from repro.launch.shapes import ShapeSpec
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 for arch in ("qwen2.5-3b", "mamba2-2.7b"):
     cfg = dataclasses.replace(get_config(arch).reduced(),
                               d_model=256, n_heads=8, n_kv=4 if arch=="qwen2.5-3b" else 0,
@@ -110,7 +110,7 @@ for arch in ("qwen2.5-3b", "mamba2-2.7b"):
     shape = ShapeSpec("t", "train", 128, 8)
     lowered = lower_cell(cfg, shape, mesh, unroll=1, q_chunk=0)
     c = lowered.compile()
-    assert cost_analysis_dict(c).get("flops", 0) > 0
+    assert c.cost_analysis().get("flops", 0) > 0
     print(arch, "OK")
 print("SUBPROCESS_OK")
 """

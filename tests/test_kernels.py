@@ -1,6 +1,5 @@
 """Pallas kernel tests: shape/dtype sweeps against the pure-jnp oracles
 (interpret=True — the kernel body executes on CPU; BlockSpecs target TPU)."""
-import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -19,11 +18,8 @@ from repro.kernels.xor_gather import ref as g_ref
 
 
 def _no_recompiles(name, budget=1):
-    """Bound the kernel compiles of a region (no-op when this jax version
-    lacks jit cache introspection — the value assertions still run)."""
-    if anl_guard.available(name):
-        return anl_guard.recompile_guard(name, max_compiles=budget)
-    return contextlib.nullcontext()
+    """Bound the kernel compiles of a region."""
+    return anl_guard.recompile_guard(name, max_compiles=budget)
 
 
 # ------------------------------------------------------------- xor_encode

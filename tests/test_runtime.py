@@ -87,7 +87,8 @@ def test_server_continuous_batching():
     reqs = [Request(rid=i, prompt=[1 + i, 2 + i, 3 + i]) for i in range(5)]
     for r in reqs:
         srv.submit(r)
-    srv.run_until_drained()
+    finished = srv.run_until_drained()
+    assert sorted(r.rid for r in finished) == [r.rid for r in reqs]
     assert all(r.done for r in reqs)
     assert all(len(r.out) == sc.max_new_tokens for r in reqs)
     # more requests than slots => batching actually interleaved

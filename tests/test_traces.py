@@ -47,16 +47,21 @@ def _split(trace: Trace, cuts):
 
 
 # ------------------------------------------------------------ chunked replay
+@pytest.mark.parametrize("lazy", [False, True], ids=["trace", "lazy"])
 @pytest.mark.parametrize("chunk_len", [1, 3, 10, 14])
-def test_stream_replay_bit_identical(chunk_len):
+def test_stream_replay_bit_identical(chunk_len, lazy):
     """Any staging chunk length — including 1 and tails longer than the
-    trace — replays bit-identically to single-shot run()."""
+    trace — replays bit-identically to single-shot run(), from an in-memory
+    trace and from a lazy iterator whose length is unknown until it ends
+    (each stream's end must still reach the replay on time)."""
     sys_ = _SYS
-    rng = np.random.default_rng(5)
-    trace = rand_trace(rng, N_CORES, TLEN, sys_.p.n_data, N_ROWS)
-    single = sys_.run(trace, drain_bound(N_CORES, TLEN))
-    got = stream_replay(sys_, trace, chunk_len=chunk_len)
-    assert strip_windows(got) == single
+    for seed in (0, 5):
+        rng = np.random.default_rng(seed)
+        trace = rand_trace(rng, N_CORES, TLEN, sys_.p.n_data, N_ROWS)
+        single = sys_.run(trace, drain_bound(N_CORES, TLEN))
+        source = _split(trace, []) if lazy else trace
+        got = stream_replay(sys_, source, chunk_len=chunk_len)
+        assert strip_windows(got) == single, seed
 
 
 def test_stream_replay_source_splits_invisible(compile_guard):
