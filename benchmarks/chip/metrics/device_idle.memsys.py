@@ -1,0 +1,9 @@
+"""Share of the traced ``run_points`` call in which no operation ran on the
+device (%)."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
