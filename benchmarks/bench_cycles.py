@@ -32,7 +32,7 @@ import argparse
 import json
 import os
 
-from benchmarks.common import REPO_ROOT, Timer, emit, profile_trace, table
+from benchmarks.common import REPO_ROOT, Timer, emit, table
 from repro.sim.ramulator import simulate
 from repro.sweep import run_points
 from repro.sweep.engine import clear_caches
@@ -68,7 +68,7 @@ def load_baseline():
 
 
 def run(length: int = 48, n_rows: int = 128, smoke: bool = False,
-        min_frac: float = 0.3, profile: bool = False):
+        min_frac: float = 0.3):
     if smoke:
         length, n_rows = 16, 64
     baseline = load_baseline()
@@ -90,9 +90,8 @@ def run(length: int = 48, n_rows: int = 128, smoke: bool = False,
                      "sim_cycles/s": round(_sim_cycles(looped) / t_loop.s, 1)})
     with Timer() as t_cold:
         batched = run_points(pts, traces=traces)
-    with profile_trace("bench_cycles_warm", enabled=profile):
-        with Timer() as t_warm:
-            batched2 = run_points(pts, traces=traces)
+    with Timer() as t_warm:
+        batched2 = run_points(pts, traces=traces)
     assert batched == batched2, "batched path is nondeterministic"
     identical = looped is None or batched == looped
     warm_tput = _sim_cycles(batched) / t_warm.s
@@ -140,11 +139,8 @@ if __name__ == "__main__":
     ap.add_argument("--min-frac", type=float, default=0.3,
                     help="fail below this fraction of the checked-in "
                          "warm-batched baseline")
-    ap.add_argument("--profile", action="store_true",
-                    help="wrap the warm run in jax.profiler.trace "
-                         "(writes experiments/profiles/)")
     args = ap.parse_args()
     clear_caches()
     ok = run(length=args.length, n_rows=args.n_rows, smoke=args.smoke,
-             min_frac=args.min_frac, profile=args.profile)
+             min_frac=args.min_frac)
     raise SystemExit(0 if ok else 1)

@@ -23,11 +23,11 @@ import argparse
 
 import numpy as np
 
-from benchmarks.common import Timer, emit, profile_trace, table
+from benchmarks.common import Timer, emit, table
 
 
 def run(length: int = 2048, chunk_len: int = 256, n_cores: int = 8,
-        smoke: bool = False, floor: float = 0.25, profile: bool = False):
+        smoke: bool = False, floor: float = 0.25):
     if smoke:
         length, chunk_len = 128, 32
     from repro.core.codes import get_tables
@@ -57,9 +57,8 @@ def run(length: int = 2048, chunk_len: int = 256, n_cores: int = 8,
 
     with Timer() as t_scold:
         streamed = stream_replay(sys_, trace, chunk_len=chunk_len)
-    with profile_trace("bench_stream_warm", enabled=profile):
-        with Timer() as t_stream:
-            streamed = stream_replay(sys_, trace, chunk_len=chunk_len)
+    with Timer() as t_stream:
+        streamed = stream_replay(sys_, trace, chunk_len=chunk_len)
     rows.append({"path": f"streamed chunk={chunk_len} (warm)",
                  "wall_s": round(t_stream.s, 2),
                  "requests/s": round(n_requests / t_stream.s, 1)})
@@ -104,11 +103,7 @@ if __name__ == "__main__":
                     help="tiny trace, identity check only (CI)")
     ap.add_argument("--floor", type=float, default=0.25,
                     help="min streamed/single-shot throughput ratio")
-    ap.add_argument("--profile", action="store_true",
-                    help="wrap the warm streamed run in jax.profiler.trace "
-                         "(writes experiments/profiles/)")
     args = ap.parse_args()
     ok = run(length=args.length, chunk_len=args.chunk_len,
-             n_cores=args.n_cores, smoke=args.smoke, floor=args.floor,
-             profile=args.profile)
+             n_cores=args.n_cores, smoke=args.smoke, floor=args.floor)
     raise SystemExit(0 if ok else 1)

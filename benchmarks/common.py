@@ -7,13 +7,10 @@ Artifact contract (docs/observability.md):
   fails CI when a root ``BENCH_*.json`` lacks one;
 * root-level ``BENCH_*.json`` files keep a ``history`` list — one
   ``{ts, git_sha, headline}`` entry per emitting run, appended (never
-  overwritten) so the perf trajectory survives re-runs on one commit tree;
-* ``profile_trace`` wraps a benchmark's warm region in
-  ``jax.profiler.trace`` for the ``--profile`` flags.
+  overwritten) so the perf trajectory survives re-runs on one commit tree.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import sys
@@ -22,7 +19,6 @@ from typing import Any, Dict, List, Optional
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 ART_DIR = os.path.join(REPO_ROOT, "experiments", "bench")
-PROFILE_DIR = os.path.join(REPO_ROOT, "experiments", "profiles")
 HISTORY_CAP = 500   # root history entries kept (newest last)
 
 
@@ -100,23 +96,6 @@ def mirror_bench_to_root():
         name = os.path.splitext(os.path.basename(src))[0]
         merged.append(_write_root(name, blob))
     return merged
-
-
-@contextlib.contextmanager
-def profile_trace(name: str, enabled: bool = True):
-    """Wrap a benchmark region in ``jax.profiler.trace`` when ``enabled``.
-
-    Yields the profile directory (``experiments/profiles/<name>-<stamp>``)
-    or None when disabled — so call sites stay one ``with`` either way."""
-    if not enabled:
-        yield None
-        return
-    import jax
-    out = os.path.join(PROFILE_DIR, f"{name}-{time.strftime('%Y%m%d-%H%M%S')}")
-    os.makedirs(out, exist_ok=True)
-    with jax.profiler.trace(out):
-        yield out
-    print(f"profile written to {out}")
 
 
 def table(rows: List[Dict[str, Any]], cols: List[str]) -> str:

@@ -399,59 +399,68 @@ class CodedMemorySystem:
         # lets the sweep engine cut trailing dead cycles without changing
         # any observable statistic.
         was_done = st.done_cycle >= 0
-        st = self._arbiter(st, trace, rs_a, stream_end)
-        m = st.mem
-        if p.telemetry:
-            # post-arbiter occupancy is the per-cycle maximum (slots only
-            # free up in the serve step below)
-            m = m._replace(tele=m.tele._replace(
-                rq_hwm=jnp.maximum(m.tele.rq_hwm,
-                                   jnp.sum(m.rq_valid, axis=1, dtype=jnp.int32)),
-                wq_hwm=jnp.maximum(m.tele.wq_hwm,
-                                   jnp.sum(m.wq_valid, axis=1, dtype=jnp.int32)),
-            ))
-        n_cand = p.n_data * p.queue_depth
-        port_busy0 = jnp.zeros((p.n_ports + 1,), bool)
-        bank_ids = jnp.repeat(jnp.arange(p.n_data, dtype=jnp.int32), p.queue_depth)
-
-        # ---- fault injection (repro.faults): derive this cycle's fault
-        # predicates, count dead cycles, fail-fast-drop unservable queue
-        # entries, and seed the builders' port mask so a down bank's port
-        # reads permanently busy (and stuttering ports transiently busy).
-        # Ordering matters and is mirrored exactly by the oracle: drops land
-        # after the arbiter (the request was accepted and counted) and
-        # before the write-drain hysteresis reads queue occupancy.
-        if p.faults:
-            fs = m.fault
-            down = fplan.bank_down(fs, m.cycle)
-            rebuilding = fplan.bank_rebuilding(fs, m.cycle)
-            down_hard = down & ~rebuilding
-            stut = fplan.stutter_busy(fs, m.cycle)
-            # dead cycles are counted until the workload drains (afterwards
-            # a permanently-dead bank would count forever, breaking the
-            # quiescent fixed point the early-exit paths rely on)
-            dead_inc = (down & ~was_done).astype(jnp.uint32)
-            rq_v2, wq_v2, n_uns, n_lost = finject.drop_unservable(
-                p, t, down_hard, m.rq_row, m.rq_valid, m.wq_row, m.wq_valid,
-                m.fresh_loc, m.parity_valid, m.region_slot, rs_a)
-            fs = fs._replace(
-                dead_cycles=fs.dead_cycles + dead_inc,
-                unserved_reads=fs.unserved_reads + n_uns,
-                lost_writes=fs.lost_writes + n_lost)
-            m = m._replace(rq_valid=rq_v2, wq_valid=wq_v2, fault=fs)
+        with jax.named_scope("cycle.arbiter"):
+            st = self._arbiter(st, trace, rs_a, stream_end)
+            m = st.mem
             if p.telemetry:
+                # post-arbiter occupancy is the per-cycle maximum (slots
+                # only free up in the serve step below)
                 m = m._replace(tele=m.tele._replace(
-                    dead_cycles=m.tele.dead_cycles + dead_inc))
-            port_busy0 = port_busy0.at[: p.n_data].set(down)
-            port_busy0 = port_busy0.at[: p.n_ports].set(
-                port_busy0[: p.n_ports] | stut)
+                    rq_hwm=jnp.maximum(m.tele.rq_hwm, jnp.sum(
+                        m.rq_valid, axis=1, dtype=jnp.int32)),
+                    wq_hwm=jnp.maximum(m.tele.wq_hwm, jnp.sum(
+                        m.wq_valid, axis=1, dtype=jnp.int32)),
+                ))
+        # the access scheduler: fault masks, write-drain hysteresis, then
+        # (called further down) the read and write pattern builders
+        with jax.named_scope("cycle.patterns"):
+            n_cand = p.n_data * p.queue_depth
+            port_busy0 = jnp.zeros((p.n_ports + 1,), bool)
+            bank_ids = jnp.repeat(jnp.arange(p.n_data, dtype=jnp.int32),
+                                  p.queue_depth)
 
-        # write-drain hysteresis
-        wq_occ = jnp.max(jnp.sum(m.wq_valid, axis=1))
-        any_r = jnp.any(m.rq_valid)
-        any_w = jnp.any(m.wq_valid)
-        wm = jnp.where(m.write_mode, wq_occ > tn.wq_lo, wq_occ >= tn.wq_hi)
-        serve_writes = (wm | (~any_r & any_w)) & any_w
+            # ---- fault injection (repro.faults): derive this cycle's
+            # fault predicates, count dead cycles, fail-fast-drop
+            # unservable queue entries, and seed the builders' port mask so
+            # a down bank's port reads permanently busy (and stuttering
+            # ports transiently busy). Ordering matters and is mirrored
+            # exactly by the oracle: drops land after the arbiter (the
+            # request was accepted and counted) and before the write-drain
+            # hysteresis reads queue occupancy.
+            if p.faults:
+                fs = m.fault
+                down = fplan.bank_down(fs, m.cycle)
+                rebuilding = fplan.bank_rebuilding(fs, m.cycle)
+                down_hard = down & ~rebuilding
+                stut = fplan.stutter_busy(fs, m.cycle)
+                # dead cycles are counted until the workload drains
+                # (afterwards a permanently-dead bank would count forever,
+                # breaking the quiescent fixed point the early-exit paths
+                # rely on)
+                dead_inc = (down & ~was_done).astype(jnp.uint32)
+                rq_v2, wq_v2, n_uns, n_lost = finject.drop_unservable(
+                    p, t, down_hard, m.rq_row, m.rq_valid, m.wq_row,
+                    m.wq_valid, m.fresh_loc, m.parity_valid, m.region_slot,
+                    rs_a)
+                fs = fs._replace(
+                    dead_cycles=fs.dead_cycles + dead_inc,
+                    unserved_reads=fs.unserved_reads + n_uns,
+                    lost_writes=fs.lost_writes + n_lost)
+                m = m._replace(rq_valid=rq_v2, wq_valid=wq_v2, fault=fs)
+                if p.telemetry:
+                    m = m._replace(tele=m.tele._replace(
+                        dead_cycles=m.tele.dead_cycles + dead_inc))
+                port_busy0 = port_busy0.at[: p.n_data].set(down)
+                port_busy0 = port_busy0.at[: p.n_ports].set(
+                    port_busy0[: p.n_ports] | stut)
+
+            # write-drain hysteresis
+            wq_occ = jnp.max(jnp.sum(m.wq_valid, axis=1))
+            any_r = jnp.any(m.rq_valid)
+            any_w = jnp.any(m.wq_valid)
+            wm = jnp.where(m.write_mode, wq_occ > tn.wq_lo,
+                           wq_occ >= tn.wq_hi)
+            serve_writes = (wm | (~any_r & any_w)) & any_w
 
         def do_reads(m, active=True):
             cb = bank_ids
@@ -569,68 +578,72 @@ class CodedMemorySystem:
         # and select per point. The selected branch saw exactly the
         # candidates a ``cond`` would hand it, so results are bit-identical;
         # the discarded branch is discarded either way.
-        m_r, pb_r, out_r = do_reads(m, active=~serve_writes)
-        m_w, pb_w, out_w = do_writes(m, active=serve_writes)
-        pick = lambda w, r: jax.tree.map(                  # noqa: E731
-            lambda x, y: jnp.where(serve_writes, x, y), w, r)
-        m, port_busy, out = pick(m_w, m_r), pick(pb_w, pb_r), pick(out_w, out_r)
-        m = m._replace(write_mode=wm)
+        with jax.named_scope("cycle.patterns"):
+            m_r, pb_r, out_r = do_reads(m, active=~serve_writes)
+            m_w, pb_w, out_w = do_writes(m, active=serve_writes)
+            pick = lambda w, r: jax.tree.map(              # noqa: E731
+                lambda x, y: jnp.where(serve_writes, x, y), w, r)
+            m, port_busy = pick(m_w, m_r), pick(pb_w, pb_r)
+            out = pick(out_w, out_r)
+            m = m._replace(write_mode=wm)
 
         # recoding unit uses leftover ports. A REBUILDING bank's port is
         # granted back to it here (and only here): the builders saw it
         # busy, so the rebuild's restores/recomputes get the port the bank
         # cannot yet use for service. Stutter still applies.
-        if p.faults:
-            rc_pb = port_busy.at[: p.n_data].set(
-                jnp.where(rebuilding, stut[: p.n_data],
-                          port_busy[: p.n_data]))
-        else:
-            rc_pb = port_busy
-        rc = recode_step(
-            p, t, rc_pb, m.fresh_loc, m.parity_valid, m.parked_count,
-            m.rc_bank, m.rc_row, m.rc_valid, m.region_slot, m.banks_data,
-            m.parity_data, rs_a, down=down_hard if p.faults else None,
-        )
-        m = m._replace(
-            fresh_loc=rc.fresh_loc, parity_valid=rc.parity_valid,
-            parked_count=rc.parked_count, rc_valid=rc.rc_valid,
-            banks_data=rc.banks_data, parity_data=rc.parity_data,
-        )
-        if p.telemetry:
-            # ring entries still pending after the recode unit ran charge a
-            # recode-budget/port-starvation wait cycle to their bank
-            tele = m.tele
-            m = m._replace(tele=tele._replace(
-                recode_retired=tele.recode_retired
-                + rc.n_recoded.astype(jnp.uint32),
-                wait_cause=tele.wait_cause.at[
-                    jnp.where(m.rc_valid, jnp.maximum(m.rc_bank, 0),
-                              jnp.int32(p.n_data)),
-                    obs.WAIT_RECODE].add(1, mode="drop"),
-            ))
-        # online rebuild: sweep cells into the recode ring while any bank
-        # is rebuilding; latch ``rebuilt`` (the bank rejoins) on completion
-        if p.faults:
-            rb_bank, rb_row, rb_valid, fs2 = finject.rebuild_scan(
-                p, t, m.fault, m.cycle, rebuilding, down_hard, m.fresh_loc,
-                m.parity_valid, m.region_slot, m.rc_bank, m.rc_row,
-                m.rc_valid, rs_a, nr_a)
-            m = m._replace(rc_bank=rb_bank, rc_row=rb_row,
-                           rc_valid=rb_valid, fault=fs2)
+        with jax.named_scope("cycle.recode"):
+            if p.faults:
+                rc_pb = port_busy.at[: p.n_data].set(
+                    jnp.where(rebuilding, stut[: p.n_data],
+                              port_busy[: p.n_data]))
+            else:
+                rc_pb = port_busy
+            rc = recode_step(
+                p, t, rc_pb, m.fresh_loc, m.parity_valid, m.parked_count,
+                m.rc_bank, m.rc_row, m.rc_valid, m.region_slot, m.banks_data,
+                m.parity_data, rs_a, down=down_hard if p.faults else None,
+            )
+            m = m._replace(
+                fresh_loc=rc.fresh_loc, parity_valid=rc.parity_valid,
+                parked_count=rc.parked_count, rc_valid=rc.rc_valid,
+                banks_data=rc.banks_data, parity_data=rc.parity_data,
+            )
+            if p.telemetry:
+                # ring entries still pending after the recode unit ran charge a
+                # recode-budget/port-starvation wait cycle to their bank
+                tele = m.tele
+                m = m._replace(tele=tele._replace(
+                    recode_retired=tele.recode_retired
+                    + rc.n_recoded.astype(jnp.uint32),
+                    wait_cause=tele.wait_cause.at[
+                        jnp.where(m.rc_valid, jnp.maximum(m.rc_bank, 0),
+                                  jnp.int32(p.n_data)),
+                        obs.WAIT_RECODE].add(1, mode="drop"),
+                ))
+            # online rebuild: sweep cells into the recode ring while any bank
+            # is rebuilding; latch ``rebuilt`` (the bank rejoins) on completion
+            if p.faults:
+                rb_bank, rb_row, rb_valid, fs2 = finject.rebuild_scan(
+                    p, t, m.fault, m.cycle, rebuilding, down_hard, m.fresh_loc,
+                    m.parity_valid, m.region_slot, m.rc_bank, m.rc_row,
+                    m.rc_valid, rs_a, nr_a)
+                m = m._replace(rc_bank=rb_bank, rc_row=rb_row,
+                               rc_valid=rb_valid, fault=fs2)
         # dynamic coding unit
-        dy = dynamic_step(
-            p, t, tn, m.cycle, m.region_slot, m.slot_region, m.access_count,
-            m.parked_count, m.parity_valid, m.parity_data, m.banks_data,
-            m.enc_region, m.enc_remaining, m.enc_slot, m.switches,
-            quiesce=was_done,
-        )
-        m = m._replace(
-            region_slot=dy.region_slot, slot_region=dy.slot_region,
-            access_count=dy.access_count, parity_valid=dy.parity_valid,
-            parity_data=dy.parity_data, enc_region=dy.enc_region,
-            enc_remaining=dy.enc_remaining, enc_slot=dy.enc_slot,
-            switches=dy.switches,
-        )
+        with jax.named_scope("cycle.dynamic"):
+            dy = dynamic_step(
+                p, t, tn, m.cycle, m.region_slot, m.slot_region,
+                m.access_count, m.parked_count, m.parity_valid,
+                m.parity_data, m.banks_data, m.enc_region, m.enc_remaining,
+                m.enc_slot, m.switches, quiesce=was_done,
+            )
+            m = m._replace(
+                region_slot=dy.region_slot, slot_region=dy.slot_region,
+                access_count=dy.access_count, parity_valid=dy.parity_valid,
+                parity_data=dy.parity_data, enc_region=dy.enc_region,
+                enc_remaining=dy.enc_remaining, enc_slot=dy.enc_slot,
+                switches=dy.switches,
+            )
         # completion bookkeeping: a core is consumed once its pointer passes
         # its stream end (the full trace length in single-shot mode; the
         # staged request count for a chunk whose stream is exhausted;

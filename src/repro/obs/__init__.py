@@ -17,6 +17,8 @@ Layers (see docs/observability.md):
 * ``serve``    — serving metric planes for the coded KV page pool (bank
                  load/latency histograms, read provenance, recode backlog)
                  and host-side request lifecycle spans (ServeLog).
+* ``spans``    — ``span(name, **counts)``: ``repro:``-prefixed profiler
+                 spans of the sweep engine, counts as the span's stats.
 
 ``core/state.py`` imports ``repro.obs.planes``; everything else here pulls
 in the sweep layer, so the submodules load lazily to keep the core import

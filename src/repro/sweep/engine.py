@@ -36,6 +36,7 @@ from repro.core.state import TunableParams, make_params, make_tunables
 from repro.core.system import (CodedMemorySystem, SimResult, SimState, Trace,
                                quiescent, result_from_host)
 from repro.launch.mesh import make_sweep_mesh
+from repro.obs.spans import span
 from repro.sweep import workloads
 from repro.sweep.grid import (GridBatch, SweepPoint, batch_geometry_alloc,
                               partition, static_signature)
@@ -160,7 +161,8 @@ def _scan_batch(sys: CodedMemorySystem, st_b: SimState, trace_b: Trace,
     # early exit is bit-identical to running the bound out.
     def cond(carry):
         st, i = carry
-        return (i < n_cycles) & ~_all_quiescent(st)
+        with jax.named_scope("loop.quiescence"):
+            return (i < n_cycles) & ~_all_quiescent(st)
 
     def body(carry):
         st, i = carry
@@ -209,51 +211,63 @@ def run_batch(batch: GridBatch, traces: Optional[Sequence[Trace]] = None,
     telemetry-off ones (the planes ride the same device program; collecting
     them costs one extra host transfer of a few small arrays per point)."""
     pts = batch.points
-    # geometry indexing is traced only when this batch actually mixes
-    # (region_size, n_regions) geometries; a uniform batch (trace/seed/
-    # tunable/α sweeps at one r) compiles the static-indexing program —
-    # masking costs nothing unless it is used
-    traced = len({pt.derived_slots()[:2] for pt in pts}) > 1
-    sys = system_for(pts[0], geometry_alloc=batch_geometry_alloc(pts),
-                     traced_geometry=traced)
-    if traces is None:
-        traces = [workloads.build_trace(pt, index=i)
-                  for i, pt in zip(batch.indices, pts)]
-    for pt, tr in zip(pts, traces):
-        if tuple(tr.bank.shape) != (pt.n_cores, pt.length):
-            raise ValueError(
-                f"trace shape {tuple(tr.bank.shape)} does not match point "
-                f"geometry ({pt.n_cores}, {pt.length})")
-    trace_b = workloads.stack_traces(traces)
-    tn_b = stack_tunables(pts, sys.p.queue_depth)
-    priors_b = (_stack_priors(region_priors, len(pts))
-                if region_priors is not None else None)
-    fault_b = _stack_faults(pts, sys.p) if sys.p.faults else None
     pad = _pad_points(len(pts)) if shard else 0
-    if pad:
-        trace_b = _replicate_tail(trace_b, pad)
-        tn_b = _replicate_tail(tn_b, pad)
-        if priors_b is not None:
-            priors_b = _replicate_tail(priors_b, pad)
-        if fault_b is not None:
-            fault_b = _replicate_tail(fault_b, pad)
-    st_b = _batched_init(sys, tn_b, priors_b)
-    if fault_b is not None:
-        # install the per-point schedules over the vmapped init's no-fault
-        # default (vmap can't thread the host-side plans themselves)
-        st_b = st_b._replace(mem=st_b.mem._replace(fault=fault_b))
-    if shard:
-        st_b, trace_b, tn_b = _maybe_shard((st_b, trace_b, tn_b),
-                                           len(pts) + pad)
-    st = _scan_batch(sys, st_b, trace_b, tn_b, pts[0].resolved_cycles())
-    results = summarize_batch(st, n_points=len(pts))
-    if not collect_telemetry:
-        return results
-    from repro.obs.planes import snapshot
-    host = jax.device_get(st)
-    snaps = [snapshot(host, point=b) if host.mem.tele is not None else None
-             for b in range(len(pts))]
-    return results, snaps
+    with span("sweep.batch", points=len(pts), pad=pad):
+        # geometry indexing is traced only when this batch actually mixes
+        # (region_size, n_regions) geometries; a uniform batch (trace/seed/
+        # tunable/α sweeps at one r) compiles the static-indexing program —
+        # masking costs nothing unless it is used
+        traced = len({pt.derived_slots()[:2] for pt in pts}) > 1
+        sys = system_for(pts[0], geometry_alloc=batch_geometry_alloc(pts),
+                         traced_geometry=traced)
+        with span("sweep.stack", points=len(pts) + pad):
+            if traces is None:
+                traces = [workloads.build_trace(pt, index=i)
+                          for i, pt in zip(batch.indices, pts)]
+            for pt, tr in zip(pts, traces):
+                if tuple(tr.bank.shape) != (pt.n_cores, pt.length):
+                    raise ValueError(
+                        f"trace shape {tuple(tr.bank.shape)} does not match "
+                        f"point geometry ({pt.n_cores}, {pt.length})")
+            trace_b = workloads.stack_traces(traces)
+            tn_b = stack_tunables(pts, sys.p.queue_depth)
+            priors_b = (_stack_priors(region_priors, len(pts))
+                        if region_priors is not None else None)
+            fault_b = _stack_faults(pts, sys.p) if sys.p.faults else None
+            if pad:
+                trace_b = _replicate_tail(trace_b, pad)
+                tn_b = _replicate_tail(tn_b, pad)
+                if priors_b is not None:
+                    priors_b = _replicate_tail(priors_b, pad)
+                if fault_b is not None:
+                    fault_b = _replicate_tail(fault_b, pad)
+        with span("sweep.init", points=len(pts) + pad):
+            st_b = _batched_init(sys, tn_b, priors_b)
+            if fault_b is not None:
+                # install the per-point schedules over the vmapped init's
+                # no-fault default (vmap can't thread the host-side plans)
+                st_b = st_b._replace(mem=st_b.mem._replace(fault=fault_b))
+        if shard:
+            with span("sweep.shard"):
+                st_b, trace_b, tn_b = _maybe_shard((st_b, trace_b, tn_b),
+                                                   len(pts) + pad)
+        with span("sweep.dispatch"):
+            st = _scan_batch(sys, st_b, trace_b, tn_b,
+                             pts[0].resolved_cycles())
+        with span("sweep.wait"):
+            st = jax.block_until_ready(st)
+        with span("sweep.summarize", points=len(pts)) as s:
+            host = jax.device_get(st)
+            # the points step in lockstep, so every point's cycle counter
+            # holds the while-loop's trip count
+            s.set_metadata(trips=int(np.max(host.mem.cycle)))
+            results = summarize_batch(host, n_points=len(pts))
+        if not collect_telemetry:
+            return results
+        from repro.obs.planes import snapshot
+        snaps = [snapshot(host, point=b) if host.mem.tele is not None
+                 else None for b in range(len(pts))]
+        return results, snaps
 
 
 def run_points(points: Sequence[SweepPoint],
@@ -276,18 +290,21 @@ def run_points(points: Sequence[SweepPoint],
         raise ValueError("region_priors must align 1:1 with points")
     results: List[Optional[SimResult]] = [None] * len(points)
     snaps: List = [None] * len(points)
-    for batch in partition(points):
-        btraces = ([traces[i] for i in batch.indices]
-                   if traces is not None else None)
-        bpriors = ([region_priors[i] for i in batch.indices]
-                   if region_priors is not None else None)
-        out = run_batch(batch, btraces, shard, bpriors,
-                        collect_telemetry=collect_telemetry)
-        bres, bsnaps = out if collect_telemetry else (out, None)
-        for k, i in enumerate(batch.indices):
-            results[i] = bres[k]
-            if bsnaps is not None:
-                snaps[i] = bsnaps[k]
+    with span("sweep.call", points=len(points)) as s:
+        batches = partition(points)
+        s.set_metadata(partitions=len(batches))
+        for batch in batches:
+            btraces = ([traces[i] for i in batch.indices]
+                       if traces is not None else None)
+            bpriors = ([region_priors[i] for i in batch.indices]
+                       if region_priors is not None else None)
+            out = run_batch(batch, btraces, shard, bpriors,
+                            collect_telemetry=collect_telemetry)
+            bres, bsnaps = out if collect_telemetry else (out, None)
+            for k, i in enumerate(batch.indices):
+                results[i] = bres[k]
+                if bsnaps is not None:
+                    snaps[i] = bsnaps[k]
     if collect_telemetry:
         return results, snaps
     return results  # type: ignore[return-value]

@@ -11,7 +11,7 @@ Three contracts, in test order:
    derivation (conformance), including under forced queue-full stalls.
 3. **Artifacts carry provenance** — manifests have the promised fields, root
    BENCH blobs append (never overwrite) history, the mirror dedups, the
-   manifest CI check catches stripped blobs, and the timeline/report/profile
+   manifest CI check catches stripped blobs, and the timeline and report
    exporters produce non-empty, loadable artifacts.
 """
 import json
@@ -306,18 +306,3 @@ def test_stall_report_smoke(tmp_path):
     for prow, res in zip(blob["points"], out["results"]):
         assert prow["telemetry"]["derived"]["stall_total"] \
             == res.stall_cycles
-
-
-def test_profile_trace_writes_profile(bench_dirs, monkeypatch):
-    """--profile's context manager leaves a non-empty profile dir."""
-    import benchmarks.common as common
-    import jax.numpy as jnp
-    monkeypatch.setattr(common, "PROFILE_DIR", str(bench_dirs[1] / "prof"))
-    with common.profile_trace("unit", enabled=True) as out:
-        jnp.arange(8).sum().block_until_ready()
-    assert out is not None
-    files = [os.path.join(dp, f) for dp, _, fs in os.walk(out) for f in fs]
-    assert files, "profiler produced no files"
-    with common.profile_trace("unit", enabled=False) as out2:
-        pass
-    assert out2 is None
