@@ -154,6 +154,34 @@ def count_distinct_programs(points: Sequence) -> int:
     return len(seen)
 
 
+# ------------------------------------------------------------ loop bodies
+def while_body_primitives(closed_jaxpr, scope: str) -> List[Dict[str, int]]:
+    """Primitive counts of the body of every ``while`` traced under the
+    named scope ``scope``, nested calls and loops included, in trace
+    order. A batched index inside a loop body shows up here as a
+    ``gather`` or ``scatter``: one device op per trip that does not fuse."""
+    found: List[Dict[str, int]] = []
+
+    def count(jpr, into):
+        for e in jpr.eqns:
+            into[e.primitive.name] = into.get(e.primitive.name, 0) + 1
+            for sub in jax.core.jaxprs_in_params(e.params):
+                count(sub, into)
+
+    def visit(jpr):
+        for e in jpr.eqns:
+            if (e.primitive.name == "while"
+                    and scope in str(e.source_info.name_stack)):
+                prims: Dict[str, int] = {}
+                count(e.params["body_jaxpr"].jaxpr, prims)
+                found.append(prims)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                visit(sub)
+
+    visit(closed_jaxpr.jaxpr)
+    return found
+
+
 # --------------------------------------------------------- carry stability
 def lint_carry_stability(pt=None) -> List[Finding]:
     """``cycle_fn`` must map its carry to an identical-structure carry:

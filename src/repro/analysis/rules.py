@@ -83,8 +83,9 @@ ALWAYS_STATIC_CALLS = {"_concrete_int"}
 # (qualified as "func" or "Class.method"; "Class.*" and "*" are wildcards).
 TRACED_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/controller.py": {
-        "_walk_bounds", "build_read_pattern", "build_write_pattern",
-        "_rc_push"},
+        "_walk_bounds", "_Walk.*", "_first_min", "_pick", "_onehot",
+        "_count", "_take", "_cell", "build_read_pattern",
+        "build_write_pattern", "_rc_push"},
     "src/repro/core/recoding.py": {"recode_step"},
     "src/repro/core/dynamic.py": {
         "_encode_region_data", "priors_layout", "dynamic_step"},

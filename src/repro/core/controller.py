@@ -34,20 +34,30 @@ that neither ``vmap`` nor sharding can hide. The builders here implement
 the same greedy semantics with cost that tracks the work a cycle actually
 contains:
 
-  * **compacted trip count** — candidates are age-sorted with invalid slots
-    keyed to +inf, and the walk stops after the last valid position
+  * **compacted trip count** — candidates are ranked by age with invalid
+    slots keyed to +inf, and the walk stops after the last valid position
     (`lax.while_loop`). Idle queues cost zero iterations; the engine's
     post-drain cycles and the off-duty builder of each read/write cycle
     (see ``CodedMemorySystem.cycle_fn``) collapse to the fixed setup cost.
-  * **O(1) symbol set** — the chained-decode symbols materialized this
-    cycle live in an (n_data, n_rows) bit-matrix with scalar lookups: true
-    set semantics, no capacity. ``make_params`` still bounds ``max_syms``
-    from below (>= ``n_ports``) so that a capacity-bounded implementation
-    of the same semantics could never saturate — the per-cycle symbol
-    count is bounded by port claims.
-  * **hoisted candidate tables** — per-candidate geometry (freshness,
-    parity options, validity, sibling/port ids) is gathered once, outside
-    the walk; each iteration is ~30 scalar ops against it.
+  * **state keyed by candidate** — everything a trip reads or writes lives
+    in one per-candidate table (``_Walk``): the candidate's geometry
+    (parity options, sibling and port ids), and the state of exactly the
+    banks, ports and cells its own scoring looks at. The chained-decode
+    symbol set is one flag per (candidate, bank it may need) for the
+    candidate's row: true set semantics, no capacity. ``make_params``
+    still bounds ``max_syms`` from below (>= ``n_ports``) so that a
+    capacity-bounded implementation of the same semantics could never
+    saturate — the per-cycle symbol count is bounded by port claims.
+  * **lookups before the walk, writes after it** — the table is built
+    once per call, by one-hot lookups (``_take``, ``_cell``); a trip
+    picks its candidate's row with one masked reduction and updates every
+    row by compare-and-select, so the loop body holds no gather or
+    scatter. Under the sweep engine's ``vmap`` the trip counter is
+    batched, and every indexed read or write inside the loop would become
+    a batched gather or scatter: an op that does not fuse, whose launch a
+    trip pays each time. The write walk's write-only state
+    (``fresh_loc``, ``parity_valid``, ``parked_count``, the recode ring)
+    is recorded per candidate and applied once after it.
 
 The greedy semantics are genuinely sequential only across candidates that
 contend (same ports, or symbols on the same row of one parity group), so
@@ -72,9 +82,11 @@ the geometry.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.codes import MAX_OPTS, CodeTables
 from repro.core.state import MemParams
@@ -139,16 +151,138 @@ class WritePlan(NamedTuple):
 
 
 def _walk_bounds(cand_age, cand_valid):
-    """Age order + trip bound covering every valid candidate.
+    """Walk position of every candidate + trip bound covering every valid
+    candidate.
 
-    Invalid slots sort to the back via an +inf key; the walk only needs to
+    The walk visits candidates oldest first, ties in queue order (a stable
+    sort); invalid slots sort to the back via an +inf key. ``rank[c]`` is
+    candidate c's position, counted by comparison, so the tables stay in
+    queue order and no permutation is gathered. The walk only needs to
     reach the last position holding a valid candidate (invalid ones are
     no-ops in the body, so skipping the tail is unobservable)."""
     n = cand_age.shape[0]
-    order = jnp.argsort(jnp.where(cand_valid, cand_age, INT32_MAX))
-    last = jnp.max(jnp.where(cand_valid[order],
-                             jnp.arange(n, dtype=jnp.int32), -1))
-    return order, last + 1
+    key = jnp.where(cand_valid, cand_age, INT32_MAX)
+    idx = jnp.arange(n, dtype=jnp.int32)
+    before = ((key[None, :] < key[:, None])
+              | ((key[None, :] == key[:, None])
+                 & (idx[None, :] < idx[:, None])))
+    rank = jnp.sum(before, axis=1, dtype=jnp.int32)
+    return rank, jnp.max(jnp.where(cand_valid, rank, -1)) + 1
+
+
+class _Walk:
+    """Per-candidate fields of a greedy walk, side by side in one (N, C)
+    int32 table.
+
+    A trip reads its candidate's whole row with one masked reduction
+    (``row``) and writes the walk's state back with elementwise selects
+    over the table (``cols``, ``keys``), so its loop body holds no gather
+    or scatter and compiles to a few fused kernels however many fields it
+    tracks. ``keys`` holds, per column of a field given a key array, the
+    bank or port that column tracks (-1 elsewhere): an update to one bank
+    or port is a compare against it."""
+
+    def __init__(self, fields, keys):
+        n = next(iter(fields.values())).shape[0]
+        self.spans, cols, kcols, o = {}, [], [], 0
+        for name, a in fields.items():
+            w = math.prod(a.shape[1:])
+            self.spans[name] = (o, w, a.shape[1:], a.dtype)
+            cols.append(a.reshape(n, w).astype(jnp.int32))
+            kcols.append(jnp.broadcast_to(keys.get(name, -1),
+                                          a.shape).reshape(n, w))
+            o += w
+        self.table = jnp.concatenate(cols, axis=1)
+        self.keys = jnp.concatenate(kcols, axis=1)
+        self._col = np.arange(o)   # a static layout: masks are constants
+
+    def get(self, x, name):
+        """Field ``name`` of the table (N, C) or of one row (C,)."""
+        o, w, shape, dtype = self.spans[name]
+        return x[..., o:o + w].reshape(x.shape[:-1] + shape).astype(dtype)
+
+    def row(self, table, sel):
+        """The fields of the row ``sel`` (a one-hot mask over the
+        candidates) picks, by a masked reduction: no gather, even where the
+        trip counter is batched."""
+        r = jnp.sum(jnp.where(sel[:, None], table, 0), axis=0)
+        return {name: self.get(r, name) for name in self.spans}
+
+    def cols(self, *names):
+        """(C,) mask of the columns of fields ``names``."""
+        m = np.zeros(self._col.shape, bool)
+        for name in names:
+            o, w, _, _ = self.spans[name]
+            m |= (self._col >= o) & (self._col < o + w)
+        return m
+
+    def spread(self, name, values):
+        """(C,) vector holding ``values`` in the columns of ``name``."""
+        o = self.spans[name][0]
+        out = jnp.zeros(self._col.shape, jnp.int32)
+        for j, v in enumerate(values):
+            out = jnp.where(self._col == o + j, v, out)
+        return out
+
+
+def _first_min(scores):
+    """(index, score) of the first smallest of a list of scalars: argmin
+    unrolled into selects, so it fuses with the work around it."""
+    act, best = jnp.int32(0), scores[0]
+    for a, s in enumerate(scores[1:], 1):
+        take = s < best
+        act = jnp.where(take, a, act)
+        best = jnp.where(take, s, best)
+    return act, best
+
+
+def _pick(values, k):
+    """``values[k]`` for a static-length sequence of scalars and a traced
+    ``k``, unrolled into selects."""
+    out = values[0]
+    for j in range(1, len(values)):
+        out = jnp.where(k == j, values[j], out)
+    return out
+
+
+# Indexing by traced indices compiles to gathers and scatters, which a TPU
+# runs element by element: on a v5e a batched gather of 3,200 elements took
+# 25-38 us, and a scatter sorts its keys first. The builders index their
+# small tables by one-hot instead: compare and select along the table's
+# rows, or a contraction of 0/1 matrices with small whole numbers, exact in
+# float32.
+def _onehot(idx, n):
+    """(..., n) float32 one-hot rows of ``idx`` (all zero out of range)."""
+    return (idx[..., None] == jnp.arange(n)).astype(jnp.float32)
+
+
+def _count(spec, *operands):
+    """``einsum`` of one-hots and small whole numbers, exact in float32."""
+    import jax
+
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
+
+
+def _take(table, idx):
+    """``table[idx]`` for a small table and integer ``idx`` of any shape,
+    as indexing does it (a negative index counts from the end, the rest
+    clamp), by compare and select over the table's rows."""
+    size = table.shape[0]
+    idx = jnp.clip(jnp.where(idx < 0, idx + size, idx), 0, size - 1)
+    hit = idx[..., None] == jnp.arange(size)
+    hit = hit.reshape(hit.shape + (1,) * (table.ndim - 1))
+    return jnp.sum(jnp.where(hit, table, 0), axis=idx.ndim).astype(
+        table.dtype)
+
+
+def _cell(table, rows, cols):
+    """``table[rows, cols]`` for a 2-D table of small whole numbers and
+    in-range indices: the columns by contraction, then the row by
+    compare and select."""
+    at_cols = _count("...r,xr->...x", _onehot(cols, table.shape[1]),
+                     table.astype(jnp.float32))
+    return jnp.sum(_onehot(rows, table.shape[0]) * at_cols,
+                   axis=-1).astype(table.dtype)
 
 
 def build_read_pattern(
@@ -169,106 +303,125 @@ def build_read_pattern(
     n = cand_bank.shape[0]
     rs = p.region_size
     rs_a = rs if rs_active is None else rs_active
-    order, n_trips = _walk_bounds(cand_age, cand_valid)
+    rank, n_trips = _walk_bounds(cand_age, cand_valid)
     nop = jnp.int32(p.n_ports)
+    oob = jnp.int32(p.n_data)
+    K = MAX_OPTS
 
-    # ---- per-candidate tables, gathered once (read state is loop-invariant)
+    # ---- per-candidate tables, looked up once (read state is loop-invariant)
     b = jnp.maximum(cand_bank, 0)
     i = jnp.maximum(cand_row, 0)
-    fl = fresh_loc[b, i]
-    fresh_in_bank = fl == 0
-    slot = region_slot[i // rs_a]
+    fl = _cell(fresh_loc, b, i)
+    slot = _take(region_slot, i // rs_a)
     coded = slot >= 0
     pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    hold_port = t.par_port[jnp.maximum(fl - 1, 0)]
-    # a negative hold_port (scheme with no parities) points the REDIRECT
-    # gather/claim at the dummy sink slot
+    hold_port = _take(t.par_port, jnp.maximum(fl - 1, 0))
+    # a negative port id (scheme with no parities) points the claim at the
+    # dummy sink slot, where the lookup of the padded -1 lands
     hold_idx = jnp.where(hold_port < 0, nop, hold_port)
-    optj = t.opt_parity[b]                    # (N, K)
+    optj = _take(t.opt_parity, b)             # (N, K)
     optjj = jnp.maximum(optj, 0)
-    opt_pv = (optj >= 0) & coded[:, None] & parity_valid[optjj, pr[:, None]]
-    opt_pport = t.par_port[optjj]
-    s0 = t.opt_sibs[b][:, :, 0]
-    s1 = t.opt_sibs[b][:, :, 1]
-    s0c = jnp.maximum(s0, 0)
-    s1c = jnp.maximum(s1, 0)
-    may_serve = cand_valid & fresh_in_bank
-    can_rd = cand_valid & (fl > 0)
-    opt_may = may_serve[:, None] & opt_pv
-
-    served0 = jnp.zeros((n,), bool)
-    mode0 = jnp.full((n,), MODE_UNSERVED, jnp.int32)
-    sym0 = jnp.zeros((p.n_data, p.n_rows), bool)   # materialized this cycle
+    opt_pv = ((optj >= 0) & coded[:, None]
+              & _cell(parity_valid, optjj, pr[:, None]))
+    opt_pport = _take(t.par_port, optjj)
+    opt_pport = jnp.where(opt_pport < 0, nop, opt_pport)
+    sibs = _take(t.opt_sibs, b)
+    s0, s1 = sibs[:, :, 0], sibs[:, :, 1]
+    may_serve = cand_valid & (fl == 0)
+    # The banks and ports a candidate's scoring looks up; the walk keeps
+    # their state per candidate. ``have``: the bank of each column has the
+    # candidate's row materialized this cycle (the chained-decode symbol
+    # set); ``busy``: the port of each column is claimed. ``claim`` records
+    # the ports each candidate claimed, for ``port_busy`` after the walk.
+    sym_bank = jnp.concatenate([b[:, None], s0, s1], axis=1)      # (N, 1+2K)
+    port_ix = jnp.concatenate([b[:, None], jnp.maximum(s0, 0),
+                               jnp.maximum(s1, 0), opt_pport,
+                               hold_idx[:, None]], axis=1)        # (N, 2+3K)
+    w = _Walk(dict(
+        b=b, i=i, may=may_serve, can_rd=cand_valid & (fl > 0), hold=hold_idx,
+        opt_may=may_serve[:, None] & opt_pv, pport=opt_pport, s0=s0, s1=s1,
+        have=jnp.zeros(sym_bank.shape, bool),
+        busy=_take(port_busy, port_ix),
+        mode=jnp.full((n,), MODE_UNSERVED, jnp.int32),
+        claim=jnp.full((n, 4), nop, jnp.int32),
+    ), keys=dict(have=sym_bank, busy=port_ix))
 
     def cond(carry):
         return carry[0] < n_trips
 
     def body(carry):
-        k, port_busy, sym, served, mode = carry
-        c = order[k]
-        bc = b[c]
-        ic = i[c]
+        k, table = carry
+        sel = rank == k
+        c = w.row(table, sel)
+        h, bz = c["have"], c["busy"]
+        s0r, s1r = c["s0"], c["s1"]                    # (K,)
 
         # --- score every action ------------------------------------------
-        f_sym = may_serve[c] & sym[bc, ic] & bool(p.coalesce)
-        f_dir = may_serve[c] & ~port_busy[bc]
-        s0r, s1r = s0[c], s1[c]                  # (K,)
-        s0cr, s1cr = s0c[c], s1c[c]
-        sa0 = sym[s0cr, ic] & (s0r >= 0)
-        sa1 = sym[s1cr, ic] & (s1r >= 0)
-        ok0 = (s0r < 0) | sa0 | ~port_busy[s0cr]
-        ok1 = (s1r < 0) | sa1 | ~port_busy[s1cr]
-        need0 = (s0r >= 0) & ~sa0
-        need1 = (s1r >= 0) & ~sa1
-        feas = opt_may[c] & ~port_busy[opt_pport[c]] & ok0 & ok1
-        cost = 1 + need0.astype(jnp.int32) + need1.astype(jnp.int32)
-        f_rd = can_rd[c] & ~port_busy[hold_idx[c]]
-        scores = jnp.concatenate([
-            jnp.where(f_sym, 0, INF_SCORE)[None],
-            jnp.where(f_dir, 3, INF_SCORE)[None],
-            jnp.where(feas, 2 * cost, INF_SCORE),
-            jnp.where(f_rd, 2, INF_SCORE)[None],
-        ])
-        act = jnp.argmin(scores).astype(jnp.int32)
-        found = scores[act] < INF_SCORE
+        f_sym = c["may"] & h[0] & bool(p.coalesce)
+        f_dir = c["may"] & ~bz[0]
+        scores = [jnp.where(f_sym, 0, INF_SCORE),
+                  jnp.where(f_dir, 3, INF_SCORE)]
+        need0, need1 = [], []
+        for j in range(K):
+            sa0 = h[1 + j] & (s0r[j] >= 0)
+            sa1 = h[1 + K + j] & (s1r[j] >= 0)
+            ok0 = (s0r[j] < 0) | sa0 | ~bz[1 + j]
+            ok1 = (s1r[j] < 0) | sa1 | ~bz[1 + K + j]
+            need0.append((s0r[j] >= 0) & ~sa0)
+            need1.append((s1r[j] >= 0) & ~sa1)
+            feas = c["opt_may"][j] & ~bz[1 + 2 * K + j] & ok0 & ok1
+            cost = (1 + need0[j].astype(jnp.int32)
+                    + need1[j].astype(jnp.int32))
+            scores.append(jnp.where(feas, 2 * cost, INF_SCORE))
+        f_rd = c["can_rd"] & ~bz[1 + 3 * K]
+        act, best = _first_min(scores + [jnp.where(f_rd, 2, INF_SCORE)])
+        found = best < INF_SCORE
 
         is_dir = found & (act == 1)
-        is_opt = found & (act >= 2) & (act < 2 + MAX_OPTS)
-        is_rd = found & (act == 2 + MAX_OPTS)
-        k_sel = jnp.clip(act - 2, 0, MAX_OPTS - 1)
-        need0_sel = need0[k_sel]
-        need1_sel = need1[k_sel]
-        sib0 = s0cr[k_sel]
-        sib1 = s1cr[k_sel]
+        is_opt = found & (act >= 2) & (act < 2 + K)
+        is_rd = found & (act == 2 + K)
+        k_sel = jnp.clip(act - 2, 0, K - 1)
+        need0_sel = is_opt & _pick(need0, k_sel)
+        need1_sel = is_opt & _pick(need1, k_sel)
+        sib0 = _pick([jnp.maximum(s0r[j], 0) for j in range(K)], k_sel)
+        sib1 = _pick([jnp.maximum(s1r[j], 0) for j in range(K)], k_sel)
 
-        # --- claim ports (the nop scatters mark the sink, as the ref does)
-        p_dir = jnp.where(is_dir, bc, nop)
-        p_par = jnp.where(is_opt, opt_pport[c, k_sel],
-                          jnp.where(is_rd, hold_idx[c], nop))
-        p_s0 = jnp.where(is_opt & need0_sel, sib0, nop)
-        p_s1 = jnp.where(is_opt & need1_sel, sib1, nop)
-        port_busy = (port_busy.at[p_dir].set(True).at[p_par].set(True)
-                     .at[p_s0].set(True).at[p_s1].set(True))
+        # --- claim ports (every trip's masked claims land on the sink slot,
+        # as the ref's do) and materialize symbols (true set semantics)
+        p_dir = jnp.where(is_dir, c["b"], nop)
+        p_par = jnp.where(is_opt, _pick([c["pport"][j] for j in range(K)],
+                                        k_sel),
+                          jnp.where(is_rd, c["hold"], nop))
+        p_s0 = jnp.where(need0_sel, sib0, nop)
+        p_s1 = jnp.where(need1_sel, sib1, nop)
+        x_b = jnp.where(is_dir | is_opt, c["b"], oob)
+        x_s0 = jnp.where(need0_sel, sib0, oob)
+        x_s1 = jnp.where(need1_sel, sib1, oob)
+        key = w.keys
+        same_row = (w.get(table, "i") == c["i"])[:, None]
+        hit = ((w.cols("have") & same_row
+                & ((key == x_b) | (key == x_s0) | (key == x_s1)))
+               | (w.cols("busy")
+                  & ((key == p_dir) | (key == p_par) | (key == p_s0)
+                     | (key == p_s1) | (key == nop))))
+        table = jnp.where(hit, 1, table)
+        table = jnp.where(
+            w.cols("mode", "claim") & sel[:, None],
+            w.spread("mode", [jnp.where(found, act, MODE_UNSERVED)])
+            + w.spread("claim", [p_dir, p_par, p_s0, p_s1]), table)
+        return k + 1, table
 
-        # --- materialize symbols (true set semantics, see module docstring)
-        oob = jnp.int32(p.n_data)
-        sym = sym.at[jnp.where(is_dir | is_opt, bc, oob), ic].set(
-            True, mode="drop")
-        sym = sym.at[jnp.where(is_opt & need0_sel, sib0, oob), ic].set(
-            True, mode="drop")
-        sym = sym.at[jnp.where(is_opt & need1_sel, sib1, oob), ic].set(
-            True, mode="drop")
-
-        served = served.at[c].set(found)
-        mode = mode.at[c].set(jnp.where(found, act, MODE_UNSERVED))
-        return k + 1, port_busy, sym, served, mode
-
-    carry = (jnp.int32(0), port_busy, sym0, served0, mode0)
-    _, port_busy, _, served, mode = jax.lax.while_loop(cond, body, carry)
-    # the masked no-op claims land on the sink slot; mark it busy even when
-    # the walk never reaches a valid candidate, so its state is
+    _, table = jax.lax.while_loop(cond, body, (jnp.int32(0), w.table))
+    # the claims land once, after the walk; the sink is marked busy even
+    # when the walk never reaches a valid candidate, so its state is
     # deterministic for downstream consumers
-    port_busy = port_busy.at[p.n_ports].set(True)
+    ports = jnp.arange(port_busy.shape[0], dtype=jnp.int32)
+    port_busy = (port_busy
+                 | jnp.any(w.get(table, "claim")[:, :, None] == ports,
+                           axis=(0, 1))
+                 | (ports == nop))
+    mode = w.get(table, "mode")
+    served = mode != MODE_UNSERVED
     n_served = jnp.sum(served).astype(jnp.int32)
     n_degraded = jnp.sum(
         served & ((mode == MODE_FROM_SYM) | ((mode >= MODE_OPT0) & (mode < MODE_REDIRECT)))
@@ -299,24 +452,24 @@ def build_write_pattern(
     n = cand_bank.shape[0]
     rs = p.region_size
     rs_a = rs if rs_active is None else rs_active
-    order, n_trips = _walk_bounds(cand_age, cand_valid)
+    rank, n_trips = _walk_bounds(cand_age, cand_valid)
     nop = jnp.int32(p.n_ports)
+    K = MAX_OPTS
 
-    # ---- per-candidate tables, gathered once ---------------------------
+    # ---- per-candidate tables, looked up once ---------------------------
     b = jnp.maximum(cand_bank, 0)
     i = jnp.maximum(cand_row, 0)
     region = i // rs_a
-    slot = region_slot[region]
+    slot = _take(region_slot, region)
     coded = slot >= 0
     pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    optj = t.opt_parity[b]                    # (N, K)
+    optj = _take(t.opt_parity, b)             # (N, K)
     optjj = jnp.maximum(optj, 0)
-    opt_pport = t.par_port[optjj]
-    mem = t.par_members[optjj]                # (N, K, MAX_SIBS+1)
+    opt_pport = _take(t.par_port, optjj)
+    opt_pport = jnp.where(opt_pport < 0, nop, opt_pport)
+    mem = _take(t.par_members, optjj)         # (N, K, MAX_SIBS+1)
     memc = jnp.maximum(mem, 0)
-    park_possible = cand_valid[:, None] & (optj >= 0) & coded[:, None]
-    need_rc_dir = coded & (t.opt_n[b] > 0)
-    park_base = 2 + jnp.arange(MAX_OPTS, dtype=jnp.int32)
+    park_base = 2 + jnp.arange(K, dtype=jnp.int32)
     # ---- degraded-write mode (``down`` = currently-down data banks).
     # A candidate is *sticky* when its own bank is down or any parity
     # option covering it has a down member: its park stays parked (no
@@ -328,97 +481,147 @@ def build_write_pattern(
     # (d) a direct write (which invalidates EVERY covering parity row) —
     # strictly last for a sticky-but-alive bank. Sticky parks also waive
     # the recode-queue-space requirement (they don't enqueue).
-    if down is not None:
+    if down is None:
+        sticky = jnp.zeros((n,), bool)
+        dir_score = jnp.ones((n,), jnp.int32)
+        park_score = jnp.broadcast_to(park_base, (n, K))
+    else:
         opt_down = jnp.any((mem >= 0) & (mem != b[:, None, None])
-                           & down[memc], axis=2)             # (N, K)
-        sticky = down[b] | jnp.any((optj >= 0) & coded[:, None] & opt_down,
-                                   axis=1)
+                           & _take(down, memc), axis=2)      # (N, K)
+        sticky = _take(down, b) | jnp.any(
+            (optj >= 0) & coded[:, None] & opt_down, axis=1)
         dir_score = jnp.where(sticky, 2 + 2 * MAX_OPTS + 2, 1)
-        park_shift = jnp.where(opt_down, MAX_OPTS + 2, 0)
-
-    served0 = jnp.zeros((n,), bool)
-    mode0 = jnp.full((n,), WMODE_UNSERVED, jnp.int32)
+        park_score = park_base + jnp.where(opt_down, MAX_OPTS + 2, 0)
+    # The walk's state, per candidate. ``fl``: freshness of its own cell
+    # (column 0) and of its options' parity-group members at its row; a
+    # write updates every copy of its cell, and copies of one cell stay
+    # equal, so one scatter of column 0 writes ``fresh_loc`` back after the
+    # walk. ``busy``: the port of each column is claimed. The recode ring
+    # only fills during the walk, the t-th push into the t-th free slot;
+    # ``pend``: the candidate's cell is in the ring. ``delta`` records each
+    # candidate's change of its region's parked count, ``push`` the ordinal
+    # of its push (-1: none).
+    fl_bank = jnp.concatenate([b[:, None], mem.reshape(n, -1)], axis=1)
+    port_ix = jnp.concatenate([b[:, None], opt_pport], axis=1)   # (N, 1+K)
+    free = ~rc_valid
+    n_free = jnp.sum(free).astype(jnp.int32)
+    w = _Walk(dict(
+        b=b, i=i, valid=cand_valid, dir_score=dir_score, sticky=sticky,
+        need_rc_dir=coded & (_take(t.opt_n, b) > 0),
+        park_possible=cand_valid[:, None] & (optj >= 0) & coded[:, None],
+        pport=opt_pport, optjj=optjj, park_score=park_score, mem=mem,
+        busy=_take(port_busy, port_ix),
+        fl=_cell(fresh_loc, jnp.maximum(fl_bank, 0), i[:, None]),
+        pend=jnp.any(rc_valid & (rc_bank == b[:, None])
+                     & (rc_row == i[:, None]), axis=1),
+        mode=jnp.full((n,), WMODE_UNSERVED, jnp.int32),
+        delta=jnp.zeros((n,), jnp.int32), push=jnp.full((n,), -1, jnp.int32),
+    ), keys=dict(busy=port_ix, fl=fl_bank, pend=b))
 
     def cond(carry):
         return carry[0] < n_trips
 
     def body(carry):
-        (k, port_busy, served, mode, fresh_loc, parity_valid, parked_count,
-         rc_bank, rc_row, rc_valid, dropped) = carry
-        c = order[k]
-        bc = b[c]
-        ic = i[c]
-        flc = fresh_loc[bc, ic]
-        rc_space = jnp.any(~rc_valid)
+        k, table, n_ins, dropped = carry
+        sel = rank == k
+        c = w.row(table, sel)
+        bz, flr, mem_c, optjj_c = c["busy"], c["fl"], c["mem"], c["optjj"]
+        bc, ic, flc = c["b"], c["i"], flr[0]
+        rc_space = n_ins < n_free
 
         # --- score direct + park options ---------------------------------
-        f_dir = cand_valid[c] & ~port_busy[bc]
-        occ = jnp.any(
-            (mem[c] >= 0) & (mem[c] != bc)
-            & (fresh_loc[memc[c], ic] == optjj[c][:, None] + 1), axis=1)
-        if down is None:
-            park_feas = (park_possible[c] & ~port_busy[opt_pport[c]] & ~occ
-                         & rc_space)
-            scores = jnp.concatenate([
-                jnp.where(f_dir, 1, INF_SCORE)[None],
-                jnp.where(park_feas, park_base, INF_SCORE),
-            ])
-        else:
-            park_feas = (park_possible[c] & ~port_busy[opt_pport[c]] & ~occ
-                         & (rc_space | sticky[c]))
-            scores = jnp.concatenate([
-                jnp.where(f_dir, dir_score[c], INF_SCORE)[None],
-                jnp.where(park_feas, park_base + park_shift[c], INF_SCORE),
-            ])
-        act = jnp.argmin(scores).astype(jnp.int32)
-        found = scores[act] < INF_SCORE
+        f_dir = c["valid"] & ~bz[0]
+        scores = [jnp.where(f_dir, c["dir_score"], INF_SCORE)]
+        for j in range(K):
+            occ = jnp.bool_(False)
+            for s in range(mem_c.shape[1]):
+                occ = occ | ((mem_c[j, s] >= 0) & (mem_c[j, s] != bc)
+                             & (flr[1 + j * mem_c.shape[1] + s]
+                                == optjj_c[j] + 1))
+            park_feas = (c["park_possible"][j] & ~bz[1 + j] & ~occ
+                         & (rc_space | c["sticky"]))
+            scores.append(jnp.where(park_feas, c["park_score"][j], INF_SCORE))
+        act, best = _first_min(scores)
+        found = best < INF_SCORE
         is_dir = found & (act == 0)
         is_park = found & (act >= 1)
-        k_sel = jnp.clip(act - 1, 0, MAX_OPTS - 1)
-        j_sel = optjj[c, k_sel]
-
-        port_busy = port_busy.at[jnp.where(is_dir, bc, nop)].set(True)
-        port_busy = port_busy.at[
-            jnp.where(is_park, opt_pport[c, k_sel], nop)].set(True)
+        k_sel = jnp.clip(act - 1, 0, K - 1)
+        j_sel = _pick([optjj_c[j] for j in range(K)], k_sel)
+        p_claim = jnp.where(is_dir, bc, jnp.where(
+            is_park, _pick([c["pport"][j] for j in range(K)], k_sel), nop))
 
         # --- freshness bookkeeping ---------------------------------------
         was_parked = flc > 0
         new_fl = jnp.where(is_dir, 0, jnp.where(is_park, j_sel + 1, flc))
-        fresh_loc = fresh_loc.at[bc, ic].set(new_fl)
-        delta = (
-            is_park.astype(jnp.int32) * (~was_parked).astype(jnp.int32)
-            - is_dir.astype(jnp.int32) * was_parked.astype(jnp.int32)
-        )
-        parked_count = parked_count.at[region[c]].add(delta)
-        # parity invalidation
-        inv = ((optj[c] >= 0) & coded[c]
-               & (is_dir | (is_park & (optjj[c] == j_sel))))
-        parity_valid = parity_valid.at[
-            jnp.where(inv, optjj[c], parity_valid.shape[0]), pr[c]].set(
-                False, mode="drop")
+        d = (is_park.astype(jnp.int32) * (~was_parked).astype(jnp.int32)
+             - is_dir.astype(jnp.int32) * was_parked.astype(jnp.int32))
         # recode request so freshness is eventually restored (a sticky park
         # stays parked — the rebuild sweep enqueues it once its down
         # parity-group member is recovering, see repro.faults.inject)
-        if down is None:
-            need_rc = (is_dir & need_rc_dir[c]) | is_park
-        else:
-            need_rc = (is_dir & need_rc_dir[c]) | (is_park & ~sticky[c])
-        rc_bank, rc_row, rc_valid, ok = _rc_push(
-            rc_bank, rc_row, rc_valid, bc, ic, need_rc)
-        dropped = dropped + (need_rc & ~ok).astype(jnp.int32)
+        need_rc = (is_dir & c["need_rc_dir"]) | (is_park & ~c["sticky"])
+        dup = c["pend"]
+        push = need_rc & ~dup & rc_space
+        dropped = dropped + (need_rc & ~dup & ~rc_space).astype(jnp.int32)
 
-        served = served.at[c].set(found)
-        mode = mode.at[c].set(jnp.where(found, act, WMODE_UNSERVED))
-        return (k + 1, port_busy, served, mode, fresh_loc, parity_valid,
-                parked_count, rc_bank, rc_row, rc_valid, dropped)
+        key = w.keys
+        cell = (w.get(table, "i") == ic)[:, None] & (key == bc)
+        table = jnp.where(
+            w.cols("busy") & ((key == p_claim) | (key == nop))
+            | (w.cols("pend") & cell & push), 1, table)
+        table = jnp.where(w.cols("fl") & cell, new_fl, table)
+        table = jnp.where(
+            w.cols("mode", "delta", "push") & sel[:, None],
+            w.spread("mode", [jnp.where(found, act, WMODE_UNSERVED)])
+            + w.spread("delta", [d])
+            + w.spread("push", [jnp.where(push, n_ins, -1)]), table)
+        return k + 1, table, n_ins + push.astype(jnp.int32), dropped
 
-    carry = (jnp.int32(0), port_busy, served0, mode0, fresh_loc,
-             parity_valid, parked_count, rc_bank, rc_row, rc_valid,
-             jnp.int32(0))
-    out = jax.lax.while_loop(cond, body, carry)
-    (_, port_busy, served, mode, fresh_loc, parity_valid, parked_count,
-     rc_bank, rc_row, rc_valid, dropped) = out
-    port_busy = port_busy.at[p.n_ports].set(True)   # deterministic sink
+    carry = (jnp.int32(0), w.table, jnp.int32(0), jnp.int32(0))
+    _, table, _, dropped = jax.lax.while_loop(cond, body, carry)
+    mode_w = w.get(table, "mode")
+
+    # ---- apply the walk's writes once, after it --------------------------
+    is_dir = mode_w == WMODE_DIRECT
+    is_park = mode_w >= WMODE_PARK0
+    opt_sel = (jnp.arange(K, dtype=jnp.int32)
+               == (mode_w - WMODE_PARK0)[:, None])                 # (N, K)
+    j_sel = jnp.sum(jnp.where(opt_sel, optjj, 0), axis=1)
+    p_park = jnp.sum(jnp.where(opt_sel, opt_pport, 0), axis=1)
+    claim = jnp.where(is_dir, b, jnp.where(is_park, p_park, nop))
+    ports = jnp.arange(port_busy.shape[0], dtype=jnp.int32)
+    port_busy = (port_busy | jnp.any(claim[:, None] == ports, axis=0)
+                 | (ports == nop))                  # deterministic sink
+    # every candidate's copy of its own cell writes it back (copies of one
+    # cell are equal: the sum over a cell's copies is their count times it)
+    on_bank = _onehot(b, p.n_data)                             # (N, n_data)
+    on_row = _onehot(i, fresh_loc.shape[1])                    # (N, n_rows)
+    copies = _count("nx,nr->xr", on_bank, on_row)
+    fl_sum = _count("nx,nr->xr", on_bank * w.get(table, "fl")[:, :1], on_row)
+    fresh_loc = jnp.where(copies > 0, fl_sum / jnp.maximum(copies, 1),
+                          fresh_loc).astype(jnp.int32)
+    parked_count = parked_count + jnp.sum(
+        jnp.where(region[:, None] == jnp.arange(parked_count.shape[0]),
+                  w.get(table, "delta")[:, None], 0), axis=0)
+    # parity invalidation
+    inv = ((optj >= 0) & coded[:, None]
+           & (is_dir[:, None]
+              | (is_park[:, None] & (optjj == j_sel[:, None]))))
+    stale = _count("nj,nr->jr",
+                   jnp.sum(_onehot(jnp.where(inv, optjj, -1),
+                                   parity_valid.shape[0]), axis=1),
+                   _onehot(pr, parity_valid.shape[1]))
+    parity_valid = parity_valid & (stale == 0)
+    # the t-th push of the walk fills the t-th free slot of the ring
+    slot_rank = jnp.where(free, jnp.cumsum(free) - 1, -2)
+    hit = slot_rank[:, None] == w.get(table, "push")[None, :]   # (cap, N)
+    filled = jnp.any(hit, axis=1)
+    rc_bank = jnp.where(filled, jnp.sum(jnp.where(hit, b, 0), axis=1),
+                        rc_bank)
+    rc_row = jnp.where(filled, jnp.sum(jnp.where(hit, i, 0), axis=1), rc_row)
+    rc_valid = rc_valid | filled
+
+    mode = mode_w
+    served = mode != WMODE_UNSERVED
     n_served = jnp.sum(served).astype(jnp.int32)
     n_parked = jnp.sum(served & (mode >= WMODE_PARK0)).astype(jnp.int32)
     return WritePlan(served, mode, port_busy, fresh_loc, parity_valid,
@@ -427,14 +630,15 @@ def build_write_pattern(
 
 
 def _rc_push(rc_bank, rc_row, rc_valid, b, i, do):
-    """Push (b, i) into the recode ring unless present; returns ok flag."""
+    """Push (b, i) into the recode ring unless present; returns ok flag.
+    The first free slot is taken by a one-hot select, not a scatter."""
     dup = jnp.any(rc_valid & (rc_bank == b) & (rc_row == i))
     free = ~rc_valid
     has_free = jnp.any(free)
-    idx = jnp.argmax(free)  # first free slot
-    do_ins = do & ~dup & has_free
-    rc_bank = rc_bank.at[idx].set(jnp.where(do_ins, b, rc_bank[idx]))
-    rc_row = rc_row.at[idx].set(jnp.where(do_ins, i, rc_row[idx]))
-    rc_valid = rc_valid.at[idx].set(jnp.where(do_ins, True, rc_valid[idx]))
+    first = jnp.arange(free.shape[0]) == jnp.argmax(free)  # first free slot
+    put = first & do & ~dup & has_free
+    rc_bank = jnp.where(put, b, rc_bank)
+    rc_row = jnp.where(put, i, rc_row)
+    rc_valid = rc_valid | put
     ok = dup | has_free
     return rc_bank, rc_row, rc_valid, ok

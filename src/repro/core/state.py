@@ -62,7 +62,7 @@ class MemParams(NamedTuple):
     n_active: int         # slots usable for coded regions (0 when α < r)
     queue_depth: int
     recode_cap: int
-    max_syms: int         # symbol bit-matrix capacity bound; must cover
+    max_syms: int         # symbol-set capacity bound; must cover
                           # n_ports (enforced by ``make_params``) so the
                           # per-cycle symbol set can never saturate
     recode_budget: int    # max recode entries retired per cycle
@@ -219,12 +219,12 @@ def make_params(
     faults: bool = False,
 ) -> MemParams:
     if max_syms < tables.n_ports:
-        # the builders' O(1) symbol bit-matrix has true set semantics; the
-        # scheduling contract (plans equal the sequential golden model's)
-        # additionally requires that a capacity-bounded symbol list could
-        # never saturate, which holds when max_syms covers the per-cycle
-        # port-claim bound. Reject configurations below it instead of
-        # silently changing chained-decode behaviour.
+        # the builders' symbol set (per-candidate flags) has true set
+        # semantics; the scheduling contract (plans equal the sequential
+        # golden model's) additionally requires that a capacity-bounded
+        # symbol list could never saturate, which holds when max_syms
+        # covers the per-cycle port-claim bound. Reject configurations
+        # below it instead of silently changing chained-decode behaviour.
         raise ValueError(
             f"max_syms={max_syms} < n_ports={tables.n_ports}: the symbol "
             "capacity must cover the per-cycle port-claim bound (see "

@@ -193,6 +193,66 @@ def test_plan_conformance_random_states(scheme):
         check_plan_conformance(scheme, seed)
 
 
+def _batch_states(scheme, seed, n_points=8, n=24):
+    """``n_points`` random states, each with another count of valid
+    candidates (none to all), duplicate (bank, row) candidates, candidates
+    sharing a row across banks, and a full recode ring at every third
+    point."""
+    n_rows = 16
+    t, p, jt, om = _geom(scheme)
+    rng = np.random.default_rng(seed)
+    pts = []
+    for j, n_valid in enumerate(np.linspace(0, n, n_points).astype(int)):
+        fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(rng, t, p,
+                                                            n_rows)
+        if j % 3 == 1:
+            rcv = np.ones_like(rcv)
+            rcb = rng.integers(0, p.n_data, rcv.size).astype(np.int32)
+            rcr = rng.integers(0, n_rows, rcv.size).astype(np.int32)
+        cb, ci, ca, _, pb = _rand_cands(rng, p, n_rows, n)
+        cb[4:8], ci[4:8] = cb[0:4], ci[0:4]           # duplicate cells
+        ci[8:12] = ci[0]                               # one row, many banks
+        cv = np.zeros(n, bool)
+        cv[rng.permutation(n)[:n_valid]] = True
+        down = rng.random(p.n_data) < 0.25
+        pts.append((cb, ci, ca, cv, pb, fresh, pv, rslot, parked, rcb, rcr,
+                    rcv, down))
+    return p, jt, om, pts
+
+
+@pytest.mark.parametrize("degraded", [False, True], ids=["all-up", "down"])
+@pytest.mark.parametrize("scheme", SCHEMES)
+def test_batched_plan_conformance(scheme, degraded):
+    """The builders vmapped over a batch of states, as the sweep engine
+    runs them: the walk's trip counter is batched, and the walk runs as
+    many trips as the fullest point needs. Every point's plan equals the
+    golden model's, field by field; ``degraded`` gives each point its own
+    down banks (the write builder's ``down=``)."""
+    p, jt, om, pts = _batch_states(scheme, seed=11 + degraded)
+    cols = [jnp.asarray(np.stack(a)) for a in zip(*pts)]
+    read = jax.jit(jax.vmap(functools.partial(ctl.build_read_pattern, p, jt)))
+    got_r = read(*cols[:8])
+
+    def write(*args):
+        return ctl.build_write_pattern(p, jt, *args[:12],
+                                       down=args[12] if degraded else None)
+
+    got_w = jax.jit(jax.vmap(write))(*cols)
+    for j, (cb, ci, ca, cv, pb, fresh, pv, rslot, parked, rcb, rcr, rcv,
+            down) in enumerate(pts):
+        label = f"{scheme} point {j} ({int(cv.sum())} valid)"
+        _assert_plans_equal(
+            jax.tree.map(lambda x: x[j], got_r),
+            build_read_plan(om, cb, ci, ca, cv, pb, fresh, pv, rslot),
+            f"ReadPlan {label}")
+        _assert_plans_equal(
+            jax.tree.map(lambda x: x[j], got_w),
+            build_write_plan(om, cb, ci, ca, cv, pb, fresh, pv, rslot,
+                             parked, rcb, rcr, rcv,
+                             down=down if degraded else None),
+            f"WritePlan {label}")
+
+
 @pytest.mark.parametrize("scheme", ["scheme_i", "scheme_iii"])
 def test_recode_conformance_random_states(scheme):
     for seed in range(6):

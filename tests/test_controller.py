@@ -1,6 +1,7 @@
 """Pattern-builder unit tests: the paper's worked examples (§III-B, Fig 3,
 Fig 12, Fig 14) plus structural invariants (port exclusivity)."""
 import jax.numpy as jnp
+import pytest
 
 from repro.core import controller as ctl
 from repro.core.codes import get_tables
@@ -126,3 +127,41 @@ def test_write_capacity_scheme_i_group():
     )
     assert int(plan.n_served) == 8
     assert int(plan.n_parked) == 4
+
+
+@pytest.mark.parametrize("faults", [(), (("bank", 0, 2, 5),)],
+                         ids=["no-faults", "bank-fault"])
+@pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])
+def test_walk_bodies_hold_no_gather_or_scatter(scheme, faults):
+    """Under the sweep engine's vmap every index inside a walk body is
+    batched, and a batched index compiles to a gather or a scatter: one
+    device op per walk trip that does not fuse. The read and write walks
+    of the paper's deployment (8 banks, 8 cores, queue depth 10, 512 rows,
+    alpha 0.25, r 0.05) keep their state per candidate and hold none."""
+    import jax
+
+    from repro.analysis.jaxpr import while_body_primitives
+    from repro.configs.paper_memsys import MemSysConfig
+    from repro.sweep import engine, workloads
+    from repro.sweep.grid import SweepPoint
+
+    cfg = MemSysConfig()
+    pts = [SweepPoint(scheme=scheme, n_rows=cfg.n_rows, alpha=0.25, r=cfg.r,
+                      n_data=cfg.n_data, n_banks=cfg.n_data,
+                      n_cores=cfg.n_cores, queue_depth=cfg.queue_depth,
+                      select_period=cfg.select_period, length=16,
+                      faults=faults, seed=s) for s in range(2)]
+    sys = engine.system_for(pts[0])
+    tn = engine.stack_tunables(pts, sys.p.queue_depth)
+    st = engine._batched_init(sys, tn)
+    if sys.p.faults:
+        st = st._replace(mem=st.mem._replace(
+            fault=engine._stack_faults(pts, sys.p)))
+    trace = workloads.stack_traces([workloads.build_trace(pt) for pt in pts])
+    jpr = jax.make_jaxpr(jax.vmap(sys.cycle_fn))(st, trace, tn)
+    walks = while_body_primitives(jpr, "cycle.patterns")
+    assert len(walks) == 2                     # the read walk, the write walk
+    for prims in walks:
+        bad = {k: v for k, v in prims.items()
+               if k == "gather" or k.startswith("scatter")}
+        assert not bad, bad
