@@ -182,6 +182,99 @@ def while_body_primitives(closed_jaxpr, scope: str) -> List[Dict[str, int]]:
     return found
 
 
+# ----------------------------------------------------------- row-wide work
+# Primitives that may output a whole bank: in-place updates, and the
+# wrappers that carry the state (a ``while`` whose predicate is batched is
+# checked on its own, below).
+ROW_WIDE_EXEMPT = ("dynamic_update_slice", "while", "pjit", "jit")
+
+# Row-wide equations that are truly needed, by (function, primitive), with
+# the reason. A function is the innermost frame of the program's source.
+ROW_WIDE_ALLOWED: Dict[Tuple[str, str], str] = {
+    ("_on", "while"): (
+        "core/dynamic.py: a loop of at most one trip that runs on the "
+        "cycles where some point's region encode completes or a region is "
+        "evicted; batched, it selects the parity state it carries once per "
+        "such cycle, not every cycle"),
+}
+
+
+def _frame(eqn) -> str:
+    from jax._src import source_info_util
+
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
+    return fr.function_name.rsplit(".", 1)[-1] if fr else "?"
+
+
+def row_wide_equations(closed_jaxpr, widths) -> List[Tuple[str, str, tuple]]:
+    """``(function, primitive, shape)`` of every equation, sub-jaxprs
+    included, that outputs an array a whole bank wide, other than scatters
+    and ``ROW_WIDE_EXEMPT``; and of every ``while`` whose predicate is
+    batched and which carries such an array (its lowering selects each
+    carried array per trip). ``widths`` holds a bank's widths: a whole
+    number is a dimension, a tuple a run of dimensions (a table kept in
+    rows of lanes, ``core.state.bank_table``)."""
+    dims = {w for w in widths if isinstance(w, int)}
+    runs = [tuple(w) for w in widths if not isinstance(w, int)]
+    found: List[Tuple[str, str, tuple]] = []
+
+    def is_wide(shape):
+        return bool(set(shape) & dims) or any(
+            shape[k:k + len(r)] == r for r in runs
+            for k in range(len(shape) - len(r) + 1))
+
+    def wide(avals):
+        return next((a.shape for a in avals
+                     if is_wide(tuple(getattr(a, "shape", ())))), None)
+
+    def visit(jpr):
+        for e in jpr.eqns:
+            for sub in jax.core.jaxprs_in_params(e.params):
+                visit(sub)
+            name = e.primitive.name
+            if name == "while":
+                shape = wide([v.aval for v in e.outvars])
+                batched = e.params["cond_jaxpr"].out_avals[0].shape != ()
+                if batched and shape is not None:
+                    found.append((_frame(e), name, shape))
+            elif not (name.startswith("scatter") or name in ROW_WIDE_EXEMPT):
+                shape = wide([v.aval for v in e.outvars])
+                if shape is not None:
+                    found.append((_frame(e), name, shape))
+
+    visit(closed_jaxpr.jaxpr)
+    return found
+
+
+def lint_row_wide(pt=None, batch: int = 2) -> List[Finding]:
+    """A simulated cycle's device work must follow its requests, not the
+    memory it holds: no equation of the batched ``cycle_fn`` (as the sweep
+    engine runs it, under ``vmap``) may output an array a whole bank wide
+    (a dimension of ``n_rows``) or a whole parity bank wide (``n_slots x
+    region_size``), except in-place updates, the loops that carry the
+    state and ``ROW_WIDE_ALLOWED``. Checked at 8,192 rows, where the
+    pattern builders' lookups pick gathers (``core/controller.py``)."""
+    from repro.sweep import engine
+    from repro.sweep.grid import SweepPoint
+
+    pt = pt if pt is not None else SweepPoint(n_rows=8192, length=8,
+                                              alpha=0.25, r=0.05)
+    sys = engine.system_for(pt)
+    inputs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((batch,) + a.shape, a.dtype),
+        _point_program_inputs(pt, sys))
+    jpr = jax.make_jaxpr(jax.vmap(sys.cycle_fn))(*inputs)
+    rows, par_rows = sys.p.n_rows, sys.p.n_slots * sys.p.region_size
+    m = inputs[0].mem
+    widths = (rows, par_rows, m.banks_data.shape[2:], m.parity_data.shape[2:])
+    return [Finding("jaxpr-row-wide", f"cycle_fn[{fn}]",
+                    f"{prim} outputs {shape}, a whole bank wide (rows "
+                    f"{rows}, parity rows {par_rows}): a cycle's work "
+                    "grows with the memory, not with its requests")
+            for fn, prim, shape in row_wide_equations(jpr, widths)
+            if (fn, prim) not in ROW_WIDE_ALLOWED]
+
+
 # --------------------------------------------------------- carry stability
 def lint_carry_stability(pt=None) -> List[Finding]:
     """``cycle_fn`` must map its carry to an identical-structure carry:
@@ -403,5 +496,6 @@ def run(strict: bool = False,
     out = lint_signature_classes(pts)
     out += lint_carry_stability()
     out += lint_flag_identity()
+    out += lint_row_wide()
     out += lint_serve_step()
     return out

@@ -84,13 +84,16 @@ ALWAYS_STATIC_CALLS = {"_concrete_int"}
 TRACED_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/controller.py": {
         "_walk_bounds", "_Walk.*", "_first_min", "_pick", "_onehot",
-        "_count", "_take", "_cell", "build_read_pattern",
+        "_count", "_take", "_col", "_cell", "_put", "build_read_pattern",
         "build_write_pattern", "_rc_push"},
     "src/repro/core/recoding.py": {"recode_step"},
     "src/repro/core/dynamic.py": {
-        "_encode_region_data", "priors_layout", "dynamic_step"},
+        "_on", "_encode_region_data", "_set_slot_valid", "priors_layout",
+        "dynamic_step"},
     "src/repro/core/state.py": {
-        "active_geometry", "wide_zero", "wide_add", "init_state"},
+        "bank_table", "cells", "set_cells", "_lane_rows", "columns",
+        "set_columns", "active_geometry", "wide_zero", "wide_add",
+        "init_state"},
     "src/repro/core/system.py": {
         "quiescent", "CodedMemorySystem._arbiter",
         "CodedMemorySystem._read_values", "CodedMemorySystem._commit_writes",
@@ -116,7 +119,7 @@ HOST_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/controller.py": {"jtables"},
     "src/repro/core/state.py": {
         "make_tunables", "wide_total", "derive_geometry", "make_params",
-        "_concrete_int"},
+        "_concrete_int", "bank_view"},
     "src/repro/core/system.py": {
         "drain_bound", "result_from_host", "CodedMemorySystem.__init__",
         "CodedMemorySystem.init", "CodedMemorySystem.run",

@@ -49,7 +49,9 @@ contains:
     capacity-bounded implementation of the same semantics could never
     saturate — the per-cycle symbol count is bounded by port claims.
   * **lookups before the walk, writes after it** — the table is built
-    once per call, by one-hot lookups (``_take``, ``_cell``); a trip
+    once per call, by one-hot lookups (``_take``, ``_cell``; a gather
+    where the state keeps a bank in rows of lanes, see
+    ``state.bank_table``); a trip
     picks its candidate's row with one masked reduction and updates every
     row by compare-and-select, so the loop body holds no gather or
     scatter. Under the sweep engine's ``vmap`` the trip counter is
@@ -57,15 +59,15 @@ contains:
     a batched gather or scatter: an op that does not fuse, whose launch a
     trip pays each time. The write walk's write-only state
     (``fresh_loc``, ``parity_valid``, ``parked_count``, the recode ring)
-    is recorded per candidate and applied once after it.
+    is recorded per candidate and applied once after it (``_put``).
 
 The greedy semantics are genuinely sequential only across candidates that
 contend (same ports, or symbols on the same row of one parity group), so
 serving decisions cannot simply be computed independently — but everything
 *around* that chain is vectorized: the core arbiter ranks cores per
-destination queue and scatters once, the write datapath commits via an
-age-rank scatter-max, and the ReCoding unit retires ring entries in
-budget-bounded parallel rounds (see ``system.py`` / ``recoding.py``).
+destination queue and scatters once, the write datapath commits the
+youngest write of each cell, and the ReCoding unit retires ring entries
+in budget-bounded parallel rounds (see ``system.py`` / ``recoding.py``).
 
 Correctness contract: plans are **bit-identical** to the pure-NumPy golden
 model in ``repro.oracle`` — an independent, sequential re-derivation of
@@ -89,7 +91,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codes import MAX_OPTS, CodeTables
-from repro.core.state import MemParams
+from repro.core.state import MemParams, cells, set_cells
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 INF_SCORE = jnp.int32(1 << 30)
@@ -247,10 +249,33 @@ def _pick(values, k):
 
 # Indexing by traced indices compiles to gathers and scatters, which a TPU
 # runs element by element: on a v5e a batched gather of 3,200 elements took
-# 25-38 us, and a scatter sorts its keys first. The builders index their
-# small tables by one-hot instead: compare and select along the table's
-# rows, or a contraction of 0/1 matrices with small whole numbers, exact in
-# float32.
+# 25-38 us, and a scatter sorts its keys first. A one-hot lookup instead
+# compares every index with every column, or contracts 0/1 matrices with
+# small whole numbers (exact in float32): work that grows with the table's
+# width. The small tables (queues, ports, code tables, region maps) are
+# always indexed by one-hot. The tables a bank wide (``fresh_loc``,
+# ``parity_valid``, the data) are indexed by one-hot up to
+# ``state.ONEHOT_MAX_COLS`` columns; wider, the state keeps them in rows of
+# 128 lanes (``state.bank_table``) and they are indexed by gather and
+# scatter, where a one-hot would cost a cycle what the memory holds, not
+# what its requests touch. Measured on one TPU v5e: a loop trip of 40
+# points, each looking up (or writing) 80 cells of an (8, W) int32 table,
+# in microseconds:
+#
+#       W     cell: one-hot / gather   write: one-hot / scatter
+#     512              9.8 / 49.3               14.3 / 26.5
+#   2,048             20.5 / 48.0               25.3 / 28.3
+#   8,192             64.1 / 46.8               73.2 / 36.1
+#  65,536            463.1 / 49.1              498.5 / 504.4
+#
+# The one-hot's cost is the table's width; the scatter's 504 us at 65,536
+# is the relayout of the whole (8, W) array that a TPU scatter makes, which
+# the rows of 128 lanes avoid (``core/state.py``). From a table in rows of
+# lanes a gather costs what it reads (same loop, 65,536 columns: 80 cells
+# a point 52 us, 640 cells 396 us), so ``_cell`` gathers the fewer cells
+# of its two ways.
+
+
 def _onehot(idx, n):
     """(..., n) float32 one-hot rows of ``idx`` (all zero out of range)."""
     return (idx[..., None] == jnp.arange(n)).astype(jnp.float32)
@@ -275,14 +300,60 @@ def _take(table, idx):
         table.dtype)
 
 
-def _cell(table, rows, cols):
-    """``table[rows, cols]`` for a 2-D table of small whole numbers and
-    in-range indices: the columns by contraction, then the row by
-    compare and select."""
-    at_cols = _count("...r,xr->...x", _onehot(cols, table.shape[1]),
-                     table.astype(jnp.float32))
-    return jnp.sum(_onehot(rows, table.shape[0]) * at_cols,
+def _col(table, cols, small=True):
+    """``table[:, cols]`` of a stored bank table with the table's rows
+    last: (..., banks), for in-range ``cols``. By gather from a table in
+    rows of lanes, else by one-hot: a contraction where the values are
+    ``small`` whole numbers, else compare and select."""
+    # static: the stored form and a python flag
+    if table.ndim == 3:
+        # one cell a gathered element: a gather whose window spans the
+        # banks has the compiler lay the whole table out again first
+        return cells(table, jnp.arange(table.shape[0]), cols[..., None])
+    hit = _onehot(cols, table.shape[1])
+    if small:  # analysis: tracer-branch
+        return _count("...r,xr->...x", hit,
+                      table.astype(jnp.float32)).astype(table.dtype)
+    return jnp.sum(jnp.where(hit[..., None, :] > 0, table, 0),
                    axis=-1).astype(table.dtype)
+
+
+def _cell(table, rows, cols, small=True):
+    """``table[rows, cols]`` of a stored bank table for in-range indices:
+    the columns by ``_col`` and the row by compare and select, or, from a
+    table in rows of lanes where that gathers fewer cells, by gather."""
+    # static: the cells each way would gather, counted from the shapes
+    n_cells = math.prod(jnp.broadcast_shapes(rows.shape, cols.shape))
+    by_cell = table.ndim == 3 and n_cells <= cols.size * table.shape[0]
+    if by_cell:  # analysis: tracer-branch
+        return cells(table, rows, cols)
+    at_cols = _col(table, cols, small)
+    hit = rows[..., None] == jnp.arange(table.shape[0])
+    return jnp.sum(jnp.where(hit, at_cols, 0), axis=-1).astype(table.dtype)
+
+
+def _put(table, rows, cols, vals, mask, small=True):
+    """``table`` with ``table[rows, cols] = vals`` where ``mask`` (indices
+    in range; a cell written more than once gets equal values). By
+    scatter into a table in rows of lanes, else by one-hot: a
+    contraction where the values are ``small`` whole numbers, else
+    compare and select."""
+    rows, cols, vals, mask = (jnp.broadcast_to(a, mask.shape).reshape(-1)
+                              for a in (rows, cols, vals, mask))
+    if table.ndim == 3:
+        return set_cells(table, jnp.where(mask, rows, table.shape[0]), cols,
+                         vals.astype(table.dtype))
+    on_row = _onehot(jnp.where(mask, rows, -1), table.shape[0])
+    on_col = _onehot(cols, table.shape[1])
+    if small:  # analysis: tracer-branch
+        copies = _count("nx,nr->xr", on_row, on_col)
+        total = _count("nx,nr->xr", on_row * vals[:, None], on_col)
+        return jnp.where(copies > 0, total / jnp.maximum(copies, 1),
+                         table).astype(table.dtype)
+    hit = (on_row[:, :, None] * on_col[:, None, :]) > 0        # (n, x, r)
+    new = jnp.max(jnp.where(hit, vals[:, None, None], jnp.iinfo(
+        jnp.int32).min), axis=0)
+    return jnp.where(jnp.any(hit, axis=0), new, table).astype(table.dtype)
 
 
 def build_read_pattern(
@@ -592,13 +663,9 @@ def build_write_pattern(
     port_busy = (port_busy | jnp.any(claim[:, None] == ports, axis=0)
                  | (ports == nop))                  # deterministic sink
     # every candidate's copy of its own cell writes it back (copies of one
-    # cell are equal: the sum over a cell's copies is their count times it)
-    on_bank = _onehot(b, p.n_data)                             # (N, n_data)
-    on_row = _onehot(i, fresh_loc.shape[1])                    # (N, n_rows)
-    copies = _count("nx,nr->xr", on_bank, on_row)
-    fl_sum = _count("nx,nr->xr", on_bank * w.get(table, "fl")[:, :1], on_row)
-    fresh_loc = jnp.where(copies > 0, fl_sum / jnp.maximum(copies, 1),
-                          fresh_loc).astype(jnp.int32)
+    # cell are equal)
+    fresh_loc = _put(fresh_loc, b, i, w.get(table, "fl")[:, 0],
+                     jnp.ones((n,), bool))
     parked_count = parked_count + jnp.sum(
         jnp.where(region[:, None] == jnp.arange(parked_count.shape[0]),
                   w.get(table, "delta")[:, None], 0), axis=0)
@@ -606,11 +673,7 @@ def build_write_pattern(
     inv = ((optj >= 0) & coded[:, None]
            & (is_dir[:, None]
               | (is_park[:, None] & (optjj == j_sel[:, None]))))
-    stale = _count("nj,nr->jr",
-                   jnp.sum(_onehot(jnp.where(inv, optjj, -1),
-                                   parity_valid.shape[0]), axis=1),
-                   _onehot(pr, parity_valid.shape[1]))
-    parity_valid = parity_valid & (stale == 0)
+    parity_valid = _put(parity_valid, optjj, pr[:, None], False, inv)
     # the t-th push of the walk fills the t-th free slot of the ring
     slot_rank = jnp.where(free, jnp.cumsum(free) - 1, -2)
     hit = slot_rank[:, None] == w.get(table, "push")[None, :]   # (cap, N)

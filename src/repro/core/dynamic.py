@@ -32,7 +32,8 @@ import jax.numpy as jnp
 
 from repro.core.codes import MAX_SIBS
 from repro.core.controller import JTables
-from repro.core.state import MemParams, TunableParams, active_geometry
+from repro.core.state import (MemParams, TunableParams, active_geometry,
+                              columns, set_columns)
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -49,6 +50,16 @@ class DynOut(NamedTuple):
     switches: jnp.ndarray
 
 
+def _on(pred, fn, x):
+    """``fn(x)`` where ``pred`` holds, else ``x``: a loop of at most one
+    trip. Under the sweep engine's ``vmap`` a ``cond`` would run ``fn``
+    for every point on every cycle and select; the loop runs it only on
+    the cycles where some point's ``pred`` holds (a point whose ``pred``
+    is false keeps ``x``)."""
+    return jax.lax.while_loop(lambda c: ~c[0], lambda c: (True, fn(c[1])),
+                              (~pred, x))[1]
+
+
 def _encode_region_data(
     p: MemParams, t: JTables, banks_data: jnp.ndarray, parity_data: jnp.ndarray,
     region: jnp.ndarray, slot: jnp.ndarray, rs_a: jnp.ndarray,
@@ -57,18 +68,33 @@ def _encode_region_data(
 
     ``rs_a`` is the point's traced region size; slot stride stays the
     allocated ``p.region_size``, and padded lanes (offset ≥ rs_a) write 0
-    into parity rows that no read/recode ever addresses."""
+    into parity rows that no read/recode ever addresses. Reads and writes
+    one region's rows: rows past the bank's end (the last region's tail)
+    read the bank's last row."""
     rs = p.region_size
     off = jnp.arange(rs)
-    rows = jnp.clip(region * rs_a + off, 0, p.n_rows - 1)  # (rs,)
+    start = region * rs_a
+    lo = jnp.clip(start, 0, p.n_rows - rs)      # the window inside the bank
+    win = columns(banks_data, lo, rs)
+    tail = jnp.broadcast_to(win[:, -1:], win.shape)
+    rows = jax.lax.dynamic_slice(jnp.concatenate([win, tail], axis=1),
+                                 (0, start - lo), (p.n_data, rs))
     vals = jnp.zeros((p.n_parities, rs), jnp.int32)
     for mm in range(MAX_SIBS + 1):
         m = t.par_members[:, mm]  # (n_par,)
-        gathered = banks_data[jnp.maximum(m, 0)][:, rows]  # (n_par, rs)
-        vals = vals ^ jnp.where((m >= 0)[:, None], gathered, 0)
+        vals = vals ^ jnp.where((m >= 0)[:, None], rows[jnp.maximum(m, 0)], 0)
     vals = jnp.where((off < rs_a)[None, :], vals, 0)
     start = jnp.maximum(slot, 0) * rs
-    return jax.lax.dynamic_update_slice(parity_data, vals, (0, start))
+    return set_columns(parity_data, vals, start)
+
+
+def _set_slot_valid(p: MemParams, parity_valid, slot, valid):
+    """``parity_valid`` with ``slot``'s rows set to ``valid`` (broadcast
+    to the slot's rows of every parity)."""
+    rs = p.region_size
+    start = jnp.maximum(slot, 0) * rs
+    return set_columns(parity_valid,
+                       jnp.broadcast_to(valid, (p.n_parities, rs)), start)
 
 
 def priors_layout(p: MemParams, tn, priors):
@@ -146,17 +172,22 @@ def dynamic_step(
     in_flight = enc_region >= 0
     enc_remaining = jnp.where(in_flight, enc_remaining - 1, 0)
     complete = in_flight & (enc_remaining <= 0)
-    # completion: install mapping, write parity data, validate rows
-    parity_data = jnp.where(
-        complete,
-        _encode_region_data(p, t, banks_data, parity_data, enc_region,
-                            enc_slot, rs_a),
-        parity_data,
-    )
-    off = jnp.arange(rs)
-    slot_rows = jnp.maximum(enc_slot, 0) * rs + off
-    pv_rows = jnp.zeros_like(parity_valid).at[:, slot_rows].set((off < rs_a))
-    parity_valid = jnp.where(complete, parity_valid | pv_rows, parity_valid)
+    # completion: install mapping, write parity data, validate rows. The
+    # loop carries the region (the encode leaves none in flight), so the
+    # region's read depends on the loop and the compiler cannot hoist it
+    # out, to run on every cycle.
+    def encode(c):
+        pd, pv, region = c
+        start = jnp.maximum(enc_slot, 0) * rs
+        old = columns(pv, start, rs)
+        return (_encode_region_data(p, t, banks_data, pd, region, enc_slot,
+                                    rs_a),
+                _set_slot_valid(p, pv, enc_slot,
+                                old | (jnp.arange(rs) < rs_a)),
+                jnp.int32(-1))
+
+    parity_data, parity_valid, _ = _on(
+        complete, encode, (parity_data, parity_valid, enc_region))
     region_slot = region_slot.at[jnp.maximum(enc_region, 0)].set(
         jnp.where(complete, enc_slot, region_slot[jnp.maximum(enc_region, 0)])
     )
@@ -200,9 +231,9 @@ def dynamic_step(
     # eviction: clear victim's slot + validity (whole allocated stride —
     # padded rows are invalid anyway)
     vslot = jnp.maximum(region_slot[victim], 0)
-    vrows = vslot * rs + jnp.arange(rs)
-    pv_clear = jnp.ones_like(parity_valid).at[:, vrows].set(False)
-    parity_valid = jnp.where(start_evict, parity_valid & pv_clear, parity_valid)
+    parity_valid = _on(start_evict,
+                       lambda pv: _set_slot_valid(p, pv, vslot, False),
+                       parity_valid)
     region_slot = region_slot.at[victim].set(
         jnp.where(start_evict, -1, region_slot[victim])
     )

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codes import MAX_OPTS, MAX_SIBS
-from repro.core.controller import JTables
+from repro.core.controller import JTables, _cell, _col, _put, _take
 from repro.core.state import MemParams
 
 
@@ -69,6 +69,16 @@ def recode_step(
     the golden model's (``repro.oracle.recode_step``) by
     tests/test_conformance.py; an empty or workless ring costs one trip.
 
+    The walk carries only what its decisions read, per entry: the
+    freshness of every bank at the entry's row and the validity of each
+    of its options' parities at its parity row, looked up once before it
+    (``_col``, ``_cell``). A
+    retirement updates the copies of the cells it writes, by compare and
+    select, and records itself; the data it moves (the restored row, the
+    recomputed parities, which decide nothing) and the state it leaves are
+    written once after the walk, in retirement order. So the loop body
+    holds no gather or scatter and carries nothing a bank wide.
+
     ``down`` (fault injection, repro.faults): hard-down data banks. A
     parity recompute that would read a hard-down member is *blocked* (the
     bank's stored rows are unreadable) — on a parked retire the blocked
@@ -82,46 +92,52 @@ def recode_step(
     rs = p.region_size
     rs_a = rs if rs_active is None else rs_active
     cap = rc_valid.shape[0]
+    n_rec = p.recode_budget
     b = jnp.maximum(rc_bank, 0)                 # (E,)
     i = jnp.maximum(rc_row, 0)
     region = i // rs_a
-    slot = region_slot[region]
+    slot = _take(region_slot, region)
     coded = slot >= 0
     pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    optj = t.opt_parity[b]                      # (E, K)
+    optj = _take(t.opt_parity, b)               # (E, K)
     optjj = jnp.maximum(optj, 0)
-    opt_pport = t.par_port[optjj]
-    mem = t.par_members[optjj]                  # (E, K, MAX_SIBS+1)
+    opt_pport = _take(t.par_port, optjj)
+    mem = _take(t.par_members, optjj)           # (E, K, MAX_SIBS+1)
     memc = jnp.maximum(mem, 0)
     epos = jnp.arange(cap, dtype=jnp.int32)
+    banks = jnp.arange(p.n_data, dtype=jnp.int32)
+    pars = jnp.arange(p.n_parities, dtype=jnp.int32)
+    ports = jnp.arange(port_busy.shape[0], dtype=jnp.int32)
     nsink = jnp.int32(p.n_ports)     # masked-index slot: never busy/claimed
-    oob_j = jnp.int32(parity_valid.shape[0])
+    on_b = b[:, None] == banks                                  # (E, n_data)
+    on_mem = memc[..., None] == banks                     # (E, K, S, n_data)
+    on_opt = optjj[..., None] == pars                        # (E, K, n_par)
     if down is not None:
         # fault-blocking is loop-invariant: down membership doesn't change
         # within a cycle
         blocked_f = jnp.any((mem >= 0) & (mem != b[:, None, None])
-                            & down[memc], axis=2)            # (E, K)
-        self_down = down[b]                                  # (E,)
+                            & _take(down, memc), axis=2)     # (E, K)
+        self_down = _take(down, b)                           # (E,)
 
     def cond(carry):
         cursor, budget = carry[0], carry[1]
         return (budget > 0) & (cursor < cap)
 
     def body(carry):
-        (cursor, budget, port_busy, fresh_loc, parity_valid, parked_count,
-         rc_valid, banks_data, parity_data) = carry
+        (cursor, budget, port_busy, fl_row, pv, rc_valid, rec_e,
+         rec_do, rec_inv, rec_fl) = carry
         # ---- per-entry work set under the current state ------------------
-        fl = fresh_loc[b, i]
+        fl = jnp.sum(jnp.where(on_b, fl_row, 0), axis=1)
         parked = fl > 0
         holder = jnp.maximum(fl - 1, 0)
+        mem_fl = jnp.sum(jnp.where(on_mem, fl_row[:, None, None, :], 0),
+                         axis=3)                               # (E, K, S)
         blocked = jnp.any(
             (mem >= 0) & (mem != b[:, None, None])
-            & (fresh_loc[memc, i[:, None, None]] == optjj[:, :, None] + 1),
-            axis=2)                                              # (E, K)
+            & (mem_fl == optjj[:, :, None] + 1), axis=2)         # (E, K)
         if down is not None:
             blocked = blocked | blocked_f
-        need = (optj >= 0) & coded[:, None] & (
-            ~parity_valid[optjj, pr[:, None]] | parked[:, None])
+        need = (optj >= 0) & coded[:, None] & (~pv | parked[:, None])
         recompute = need & ~blocked
         blocked_l = need & blocked
         has_work = parked | jnp.any(recompute, axis=1)
@@ -132,64 +148,120 @@ def recode_step(
         moot = pending & ~(coded & has_work)
 
         # needed ports as an (E, 2 + K + K*(MAX_SIBS+1)) index matrix;
-        # masked entries point at the never-busy sink gather slot
+        # masked entries point at the never-busy sink slot
         rc_k = recompute & work[:, None]
         needed_idx = jnp.concatenate([
             jnp.where(work, b, nsink)[:, None],
-            jnp.where(work & parked, t.par_port[holder], nsink)[:, None],
+            jnp.where(work & parked, _take(t.par_port, holder),
+                      nsink)[:, None],
             jnp.where(rc_k, opt_pport, nsink),
             jnp.where(rc_k[:, :, None] & (mem >= 0), memc,
                       nsink).reshape(cap, -1),
         ], axis=1)
-        pb_ext = jnp.concatenate([port_busy[: p.n_ports],
-                                  jnp.zeros((1,), bool)])
-        tf = work & ~jnp.any(pb_ext[needed_idx], axis=1)
+        busy = port_busy & (ports < p.n_ports)
+        tf = work & ~jnp.any((needed_idx[..., None] == ports) & busy,
+                             axis=(1, 2))
 
         # ---- retire the first feasible entry past the cursor -------------
         any_tf = jnp.any(tf)
         e = jnp.argmax(tf).astype(jnp.int32)     # first True (0 if none)
+        sel = (epos == e) & any_tf
         seg_end = jnp.where(any_tf, e, cap)
         # moot entries the scan walked past are dropped (budget still > 0
         # at their turn — cond guarantees it, and nothing in the segment
         # between two retirements mutates their inputs)
-        rc_valid = rc_valid & ~(moot & (epos < seg_end))
-        rc_valid = rc_valid.at[e].set(jnp.where(any_tf, False, rc_valid[e]))
+        rc_valid = rc_valid & ~(moot & (epos < seg_end)) & ~sel
 
-        idxs = needed_idx[e]
-        port_busy = port_busy.at[
-            jnp.where(any_tf & (idxs < p.n_ports), idxs,
-                      p.n_ports + 1)].set(True, mode="drop")
-        eb, ei, epr = b[e], i[e], pr[e]
-        e_parked = parked[e]
-        restored = jnp.where(any_tf & e_parked,
-                             parity_data[holder[e], epr], banks_data[eb, ei])
-        banks_data = banks_data.at[eb, ei].set(restored)
-        fresh_loc = fresh_loc.at[eb, ei].set(
-            jnp.where(any_tf, 0, fresh_loc[eb, ei]))
-        parked_count = parked_count.at[region[e]].add(
-            -(any_tf & e_parked).astype(jnp.int32))
-        do_k = recompute[e] & any_tf                       # (K,)
-        inv_k = blocked_l[e] & any_tf & e_parked
-        val = jnp.zeros((MAX_OPTS,), jnp.int32)
-        for mm in range(MAX_SIBS + 1):
-            mv = mem[e, :, mm]
-            val = val ^ jnp.where(mv >= 0, banks_data[memc[e, :, mm], ei], 0)
-        parity_data = parity_data.at[
-            jnp.where(do_k, optjj[e], oob_j), epr].set(val, mode="drop")
-        parity_valid = parity_valid.at[
-            jnp.where(do_k | inv_k, optjj[e], oob_j), epr].set(
-                do_k, mode="drop")
+        def of_e(x):
+            """Entry ``e``'s row of a per-entry array (zeros if none)."""
+            s = sel.reshape(sel.shape + (1,) * (x.ndim - 1))
+            return jnp.sum(jnp.where(s, x, 0), axis=0).astype(x.dtype)
+
+        port_busy = port_busy | (jnp.any(
+            of_e(needed_idx)[:, None] == ports, axis=0)
+            & (ports < p.n_ports) & any_tf)
+        do_k = of_e(recompute)                             # (K,)
+        inv_k = of_e(blocked_l) & of_e(parked)
+        # the cells it writes, in every entry's copies: its own freshness,
+        # and the validity of the parities it recomputes or invalidates
+        fl_row = jnp.where((i[:, None] == of_e(i)) & (banks == of_e(b))
+                           & any_tf, 0, fl_row)
+        on_k = of_e(on_opt)                                # (K, n_par)
+        wrote = jnp.any(on_k & (do_k | inv_k)[:, None], axis=0)
+        now = jnp.any(on_k & do_k[:, None], axis=0)
+        pv = jnp.where((pr == of_e(pr))[:, None]
+                       & jnp.any(on_opt & wrote, axis=2),
+                       jnp.any(on_opt & now, axis=2), pv)
+        at = (jnp.arange(n_rec) == p.recode_budget - budget) & any_tf
+        rec_e = jnp.where(at, e, rec_e)
+        rec_do = jnp.where(at[:, None], do_k, rec_do)
+        rec_inv = jnp.where(at[:, None], inv_k, rec_inv)
+        rec_fl = jnp.where(at, of_e(fl), rec_fl)
 
         cursor = jnp.where(any_tf, e, jnp.int32(cap))
         budget = budget - any_tf.astype(jnp.int32)
-        return (cursor, budget, port_busy, fresh_loc, parity_valid,
-                parked_count, rc_valid, banks_data, parity_data)
+        return (cursor, budget, port_busy, fl_row, pv, rc_valid, rec_e,
+                rec_do, rec_inv, rec_fl)
 
-    carry = (jnp.int32(-1), jnp.int32(p.recode_budget), port_busy, fresh_loc,
-             parity_valid, parked_count, rc_valid, banks_data, parity_data)
-    out = jax.lax.while_loop(cond, body, carry)
-    (_, budget, port_busy, fresh_loc, parity_valid, parked_count, rc_valid,
-     banks_data, parity_data) = out
+    no_k = jnp.zeros((n_rec, MAX_OPTS), bool)
+    carry = (jnp.int32(-1), jnp.int32(p.recode_budget), port_busy,
+             _col(fresh_loc, i), _cell(parity_valid, optjj, pr[:, None]),
+             rc_valid,
+             jnp.full((n_rec,), -1, jnp.int32), no_k, no_k,
+             jnp.zeros((n_rec,), jnp.int32))
+    (_, budget, port_busy, _, _, rc_valid, rec_e, rec_do, rec_inv,
+     rec_fl) = jax.lax.while_loop(cond, body, carry)
+
+    # ---- the retirements' writes, in retirement order ---------------------
+    on_rec = rec_e[:, None] == epos                            # (R, E)
+
+    def of_rec(x):
+        """Each retirement's row of a per-entry array."""
+        s = on_rec.reshape(on_rec.shape + (1,) * (x.ndim - 1))
+        return jnp.sum(jnp.where(s, x[None], 0), axis=1).astype(x.dtype)
+
+    done = rec_e >= 0
+    rb, ri, rpr, rj, rmem = (of_rec(x) for x in (b, i, pr, optjj, mem))
+    r_parked = done & (rec_fl > 0)
+    # a parked row comes back from its parity row, which no earlier
+    # retirement this cycle recomputed (the parked value blocks it)
+    restored = _cell(parity_data, jnp.maximum(rec_fl - 1, 0), rpr,
+                     small=False)
+    # each member's value as the retirement saw it: restored by an earlier
+    # (or this) retirement of the same cell, else as stored
+    earlier = jnp.arange(n_rec)[None, :] <= jnp.arange(n_rec)[:, None]
+    by = (earlier[:, :, None] & r_parked[None, :, None]
+          & (rb[None, :, None] == banks)
+          & (ri[None, :, None] == ri[:, None, None]))       # (R, R, n_data)
+    seen = jnp.where(jnp.any(by, axis=1),
+                     jnp.sum(jnp.where(by, restored[None, :, None], 0),
+                             axis=1),
+                     _col(banks_data, ri, small=False))      # (R, n_data)
+    at_mem = jnp.maximum(rmem, 0)[..., None] == banks       # (R, K, S, n)
+    member = jnp.sum(jnp.where(at_mem, seen[:, None, None, :], 0), axis=3)
+    val = jnp.zeros(rj.shape, jnp.int32)
+    for mm in range(MAX_SIBS + 1):
+        val = val ^ jnp.where(rmem[:, :, mm] >= 0, member[:, :, mm], 0)
+
+    def last(mask):
+        """``mask`` less the writes a later retirement's write in ``mask``
+        to the same parity cell overrides."""
+        cell = ((rj[:, :, None, None] == rj[None, None])
+                & (rpr[:, None, None, None] == rpr[None, None, :, None]))
+        later = jnp.arange(n_rec)[None, :] > jnp.arange(n_rec)[:, None]
+        return mask & ~jnp.any(cell & later[:, None, :, None]
+                               & mask[None, None], axis=(2, 3))
+
+    rows = rpr[:, None]
+    parity_data = _put(parity_data, rj, rows, val, last(rec_do), small=False)
+    parity_valid = _put(parity_valid, rj, rows, rec_do,
+                        last(rec_do | rec_inv))
+    banks_data = _put(banks_data, rb, ri, restored, r_parked, small=False)
+    fresh_loc = _put(fresh_loc, rb, ri, jnp.int32(0), done)
+    parked_count = parked_count - jnp.sum(
+        r_parked[:, None]
+        & (of_rec(region)[:, None] == jnp.arange(parked_count.shape[0])),
+        axis=0, dtype=jnp.int32)
     return RecodeOut(port_busy, fresh_loc, parity_valid, parked_count,
                      rc_valid, banks_data, parity_data,
                      jnp.int32(p.recode_budget) - budget)

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -280,8 +281,90 @@ def make_params(
     )
 
 
+# ------------------------------------------------------------ bank tables
+# The tables a bank wide (``fresh_loc``, ``parity_valid``, the data) are
+# logically (banks, width). Up to ``ONEHOT_MAX_COLS`` columns the state
+# keeps them so and the controller indexes them by one-hot (see the note
+# in ``core/controller.py``). Wider, it keeps them in rows of ``LANES``:
+# (banks, ceil(width / LANES), LANES), the tail padded. A TPU scatter
+# addresses its operand as one flat run of elements; a (banks, width) array
+# tiled (8, 128) is not one, so the compiler relays the whole array out and
+# back around each scatter into it (at 65,536 rows, ~1 ms or more of a
+# cycle on a v5e), while rows of 128 lanes already are one. The helpers
+# below index either form; which one a table is follows from its shape.
+ONEHOT_MAX_COLS = 2048
+LANES = 128
+
+
+def bank_table(x):
+    """The stored form of a logical (banks, width) table."""
+    n, width = x.shape
+    # static: a shape against a module constant
+    if width <= ONEHOT_MAX_COLS:  # analysis: tracer-branch
+        return x
+    return jnp.pad(x, ((0, 0), (0, -width % LANES))).reshape(n, -1, LANES)
+
+
+def bank_view(x, width: int):
+    """The logical (banks, ``width``) table of a stored one (NumPy or
+    JAX)."""
+    return x.reshape(x.shape[0], -1)[:, :width]
+
+
+def cells(table, rows, cols):
+    """``table[rows, cols]`` of a stored table, by gather."""
+    if table.ndim == 3:
+        return table[rows, cols // LANES, cols % LANES]
+    return table[rows, cols]
+
+
+def set_cells(table, rows, cols, vals):
+    """``table`` with ``table[rows, cols] = vals`` by scatter; a row out of
+    range drops its write."""
+    if table.ndim == 3:
+        return table.at[rows, cols // LANES, cols % LANES].set(
+            vals, mode="drop")
+    return table.at[rows, cols].set(vals, mode="drop")
+
+
+def _lane_rows(table, start, size):
+    """The rows of ``LANES`` that hold columns ``start .. start + size`` (in
+    range) of a table in rows of ``LANES``: (the (banks, k) row indices, the
+    offset of ``start`` in the first row). Whole rows of lanes are what a
+    gather or scatter here addresses: a window that spans the banks has the
+    compiler lay the whole table out again first."""
+    n, t, _ = table.shape
+    k = min(-(-size // LANES) + 1, t)
+    first = jnp.minimum(start // LANES, t - k)
+    rows = first + jnp.arange(k)
+    return (jnp.arange(n)[:, None], rows[None, :]), start - first * LANES
+
+
+def columns(table, start, size: int):
+    """``table[:, start:start + size]`` of a stored table, for an in-range
+    window: reads those columns' rows alone."""
+    if table.ndim == 3:
+        at, off = _lane_rows(table, start, size)
+        win = table[at].reshape(table.shape[0], -1)
+        return jax.lax.dynamic_slice(win, (0, off), (table.shape[0], size))
+    return jax.lax.dynamic_slice(table, (0, start), (table.shape[0], size))
+
+
+def set_columns(table, vals, start):
+    """``table`` with columns ``start ..`` (in range) set to ``vals``:
+    writes those columns' rows alone."""
+    if table.ndim == 3:
+        at, off = _lane_rows(table, start, vals.shape[1])
+        rows = table[at]                              # (banks, k, LANES)
+        win = jax.lax.dynamic_update_slice(
+            rows.reshape(table.shape[0], -1), vals, (0, off))
+        return table.at[at].set(win.reshape(rows.shape))
+    return jax.lax.dynamic_update_slice(table, vals, (0, start))
+
+
 class MemState(NamedTuple):
-    """Dynamic controller state (all jnp arrays; a scan carry)."""
+    """Dynamic controller state (all jnp arrays; a scan carry). The tables
+    a bank wide are stored as ``bank_table`` gives them."""
 
     # freshness / code status
     fresh_loc: jnp.ndarray      # (n_data, L) int32
@@ -429,8 +512,8 @@ def init_state(p: MemParams, tn: Optional[TunableParams] = None,
         parity_valid = jnp.zeros((p.n_parities, n_slot_rows), bool)
     z = jnp.int32(0)
     return MemState(
-        fresh_loc=jnp.zeros((p.n_data, p.n_rows), jnp.int32),
-        parity_valid=parity_valid,
+        fresh_loc=bank_table(jnp.zeros((p.n_data, p.n_rows), jnp.int32)),
+        parity_valid=bank_table(parity_valid),
         region_slot=region_slot,
         slot_region=slot_region,
         access_count=jnp.zeros((p.n_regions,), jnp.int32),
@@ -451,9 +534,10 @@ def init_state(p: MemParams, tn: Optional[TunableParams] = None,
         wq_data=jnp.zeros((p.n_data, p.queue_depth), jnp.int32),
         write_mode=jnp.array(False),
         cycle=z,
-        banks_data=jnp.zeros((p.n_data, p.n_rows), jnp.int32),
-        parity_data=jnp.zeros((p.n_parities, n_slot_rows), jnp.int32),
-        golden=jnp.zeros((p.n_data, p.n_rows), jnp.int32),
+        banks_data=bank_table(jnp.zeros((p.n_data, p.n_rows), jnp.int32)),
+        parity_data=bank_table(
+            jnp.zeros((p.n_parities, n_slot_rows), jnp.int32)),
+        golden=bank_table(jnp.zeros((p.n_data, p.n_rows), jnp.int32)),
         served_reads=z,
         served_writes=z,
         degraded_reads=z,

@@ -34,8 +34,8 @@ from repro.core.codes import MAX_OPTS, MAX_SIBS, CodeTables
 from repro.core.dynamic import dynamic_step
 from repro.core.recoding import recode_step
 from repro.core.state import (MemParams, MemState, TunableParams,
-                              active_geometry, init_state, make_tunables,
-                              wide_add, wide_total)
+                              active_geometry, cells, init_state,
+                              make_tunables, set_cells, wide_add, wide_total)
 from repro.faults import inject as finject
 from repro.faults import plan as fplan
 from repro.obs import planes as obs
@@ -322,16 +322,17 @@ class CodedMemorySystem:
         i = jnp.maximum(ci, 0)
         slot = m.region_slot[i // rs_a]
         pr = jnp.maximum(slot, 0) * rs + i % rs_a
-        direct_val = m.banks_data[b, i]
-        fl = m.fresh_loc[b, i]
+        direct_val = cells(m.banks_data, b, i)
+        fl = cells(m.fresh_loc, b, i)
         holder = jnp.maximum(fl - 1, 0)
-        redirect_val = m.parity_data[holder, pr]
+        redirect_val = cells(m.parity_data, holder, pr)
         k = jnp.clip(plan.mode - ctl.MODE_OPT0, 0, MAX_OPTS - 1)
         j = jnp.maximum(t.opt_parity[b, k], 0)
-        dec = m.parity_data[j, pr]
+        dec = cells(m.parity_data, j, pr)
         for mm in range(MAX_SIBS):
             s = t.opt_sibs[b, k, mm]
-            dec = dec ^ jnp.where(s >= 0, m.banks_data[jnp.maximum(s, 0), i], 0)
+            dec = dec ^ jnp.where(
+                s >= 0, cells(m.banks_data, jnp.maximum(s, 0), i), 0)
         val = jnp.where(
             plan.mode == ctl.MODE_REDIRECT, redirect_val,
             jnp.where((plan.mode >= ctl.MODE_OPT0) & (plan.mode < ctl.MODE_REDIRECT),
@@ -344,10 +345,11 @@ class CodedMemorySystem:
                        cv, cd, rs_a):
         """Commit served write payloads in age order (last write wins).
 
-        Vectorized: rather than walking candidates in a fori_loop, the
-        age-order position of each candidate is scatter-maxed into its target
-        cell; only the positionally-latest (youngest) served write per cell
-        lands — the same value the sequential walk leaves behind.
+        Vectorized: rather than walking candidates in a fori_loop, each
+        candidate's age-order position is compared with those of the
+        candidates writing the same cell; only the positionally-latest
+        (youngest) served write per cell lands — the same value the
+        sequential walk leaves behind.
         """
         p, t = self.p, self.t
         rs = p.region_size
@@ -366,20 +368,22 @@ class CodedMemorySystem:
         oob_b = jnp.int32(p.n_data)
         oob_j = jnp.int32(m.parity_data.shape[0])
 
-        def winners(mask, rows, cols, shape, oob):
-            best = jnp.full(shape, -1, jnp.int32).at[
-                jnp.where(mask, rows, oob), cols].max(pos, mode="drop")
-            return mask & (best[rows, cols] == pos)
+        def winners(mask, rows, cols):
+            # no later write in ``mask`` lands on the same cell: compared
+            # among the candidates, so the work follows them, not the banks
+            later = ((rows[None, :] == rows[:, None])
+                     & (cols[None, :] == cols[:, None])
+                     & mask[None, :] & (pos[None, :] > pos[:, None]))
+            return mask & ~jnp.any(later, axis=1)
 
-        win_d = winners(is_dir, b, i, m.banks_data.shape, oob_b)
-        banks_data = m.banks_data.at[
-            jnp.where(win_d, b, oob_b), i].set(cd, mode="drop")
-        win_p = winners(is_park, j, pr, m.parity_data.shape, oob_j)
-        parity_data = m.parity_data.at[
-            jnp.where(win_p, j, oob_j), pr].set(cd, mode="drop")
-        win_g = winners(plan.served, b, i, m.golden.shape, oob_b)
-        golden = m.golden.at[
-            jnp.where(win_g, b, oob_b), i].set(cd, mode="drop")
+        win_d = winners(is_dir, b, i)
+        banks_data = set_cells(m.banks_data, jnp.where(win_d, b, oob_b), i,
+                               cd)
+        win_p = winners(is_park, j, pr)
+        parity_data = set_cells(m.parity_data, jnp.where(win_p, j, oob_j),
+                                pr, cd)
+        win_g = winners(plan.served, b, i)
+        golden = set_cells(m.golden, jnp.where(win_g, b, oob_b), i, cd)
         return banks_data, parity_data, golden
 
     # ------------------------------------------------------------- one cycle
@@ -573,18 +577,18 @@ class CodedMemorySystem:
 
         # Under vmap, ``lax.cond`` would evaluate both branches for every
         # point anyway — at the full cost of each builder's walk over loaded
-        # queues. Instead run both branches with the off-duty builder's
-        # candidates masked invalid (its compacted walk exits immediately)
-        # and select per point. The selected branch saw exactly the
-        # candidates a ``cond`` would hand it, so results are bit-identical;
-        # the discarded branch is discarded either way.
+        # queues. Instead run both branches, one after the other, with the
+        # off-duty builder's candidates masked invalid: its compacted walk
+        # exits at once and it leaves the state exactly as it found it (no
+        # candidate is served, so every update is a no-op), so the state
+        # needs no per-point choice between the branches. Only the ports
+        # each claimed and the cycle's read outputs are picked.
         with jax.named_scope("cycle.patterns"):
-            m_r, pb_r, out_r = do_reads(m, active=~serve_writes)
-            m_w, pb_w, out_w = do_writes(m, active=serve_writes)
+            m, pb_r, out_r = do_reads(m, active=~serve_writes)
+            m, pb_w, out_w = do_writes(m, active=serve_writes)
             pick = lambda w, r: jax.tree.map(              # noqa: E731
                 lambda x, y: jnp.where(serve_writes, x, y), w, r)
-            m, port_busy = pick(m_w, m_r), pick(pb_w, pb_r)
-            out = pick(out_w, out_r)
+            port_busy, out = pick(pb_w, pb_r), pick(out_w, out_r)
             m = m._replace(write_mode=wm)
 
         # recoding unit uses leftover ports. A REBUILDING bank's port is

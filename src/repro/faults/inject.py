@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.controller import _rc_push
+from repro.core.state import cells
 from repro.faults.plan import FaultState, NEVER
 
 
@@ -56,14 +57,15 @@ def drop_unservable(p, t, down_hard, rq_row, rq_valid, wq_row, wq_valid,
         pr = jnp.maximum(slot, 0) * rs + i % rs_active
         optj = t.opt_parity[cb]                              # (N, K)
         optjj = jnp.maximum(optj, 0)
-        opt_ok = (optj >= 0) & coded[:, None] & parity_valid[optjj, pr[:, None]]
+        opt_ok = ((optj >= 0) & coded[:, None]
+                  & cells(parity_valid, optjj, pr[:, None]))
         sibs = t.opt_sibs[cb]                                # (N, K, S)
         sib_dead = jnp.any((sibs >= 0) & down_hard[jnp.maximum(sibs, 0)],
                            axis=2)
         return valid.reshape(-1), i, coded, opt_ok & ~sib_dead
 
     rv, ri, _, viable = read_side(rq_row, rq_valid)
-    drop_r = (rv & down_hard[cb] & (fresh_loc[cb, ri] == 0)
+    drop_r = (rv & down_hard[cb] & (cells(fresh_loc, cb, ri) == 0)
               & ~jnp.any(viable, axis=1))
 
     wv = wq_valid.reshape(-1)
@@ -115,8 +117,8 @@ def rebuild_scan(p, t, fault: FaultState, cycle, rebuilding, down_hard,
         pr = jnp.maximum(slot, 0) * rs + i % rs_active
         optj = t.opt_parity[x]
         stale = jnp.any((optj >= 0) & coded
-                        & ~parity_valid[jnp.maximum(optj, 0), pr])
-        need = in_range & in_geom & ((fresh_loc[x, i] > 0) | stale)
+                        & ~cells(parity_valid, jnp.maximum(optj, 0), pr))
+        need = in_range & in_geom & ((cells(fresh_loc, x, i) > 0) | stale)
         rc_bank, rc_row, rc_valid, ok = _rc_push(
             rc_bank, rc_row, rc_valid, x, i, need)
         advance = in_range & (~need | ok)
@@ -125,7 +127,8 @@ def rebuild_scan(p, t, fault: FaultState, cycle, rebuilding, down_hard,
     ptr, rc_bank, rc_row, rc_valid = jax.lax.fori_loop(
         0, p.recode_budget, body, (ptr, rc_bank, rc_row, rc_valid))
 
-    pending_park = jnp.any(jnp.any(fresh_loc > 0, axis=1) & ~down_hard)
+    pending_park = jnp.any(jnp.any(fresh_loc > 0, axis=tuple(
+        range(1, fresh_loc.ndim))) & ~down_hard)
     complete = (ptr >= total) & ~jnp.any(rc_valid) & ~pending_park
     rebuilt = fault.rebuilt | (rebuilding & complete)
     return rc_bank, rc_row, rc_valid, fault._replace(

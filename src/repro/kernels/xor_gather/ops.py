@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.core.codes import MAX_OPTS, CodeTables
 from repro.core.controller import MODE_OPT0, MODE_REDIRECT, ReadPlan
+from repro.core.state import cells
 from repro.kernels.common import uint_view_dtype
 from repro.kernels.xor_gather.kernel import gather_decode_pallas
 
@@ -40,7 +41,7 @@ def plan_columns(
     is_opt = (plan.mode >= MODE_OPT0) & (plan.mode < MODE_REDIRECT)
     is_rd = plan.mode == MODE_REDIRECT
     j_opt = opt_parity[b, k]
-    j_rd = jnp.maximum(fresh_loc[b, i] - 1, 0)
+    j_rd = jnp.maximum(cells(fresh_loc, b, i) - 1, 0)
     par = jnp.where(is_opt, j_opt, jnp.where(is_rd, j_rd, 0))
     slot = region_slot[i // region_size]
     prow = jnp.maximum(slot, 0) * region_size + i % region_size

@@ -241,12 +241,14 @@ def run_batch(batch: GridBatch, traces: Optional[Sequence[Trace]] = None,
                     priors_b = _replicate_tail(priors_b, pad)
                 if fault_b is not None:
                     fault_b = _replicate_tail(fault_b, pad)
-        with span("sweep.init", points=len(pts) + pad):
+        with span("sweep.init", points=len(pts) + pad) as s:
             st_b = _batched_init(sys, tn_b, priors_b)
             if fault_b is not None:
                 # install the per-point schedules over the vmapped init's
                 # no-fault default (vmap can't thread the host-side plans)
                 st_b = st_b._replace(mem=st_b.mem._replace(fault=fault_b))
+            s.set_metadata(state_bytes=sum(
+                x.nbytes for x in jax.tree.leaves(st_b)))
         if shard:
             with span("sweep.shard"):
                 st_b, trace_b, tn_b = _maybe_shard((st_b, trace_b, tn_b),

@@ -21,3 +21,23 @@ def drifting_carry(carry, x):
 
 def stable_carry(carry, x):
     return carry + x.sum().astype(carry.dtype), x.max()
+
+
+# ------------------------------------------------ row-wide work (per cycle)
+def whole_bank_select(bank, rows, vals, pred):
+    # a per-point choice between two versions of the whole bank
+    return jnp.where(pred, bank.at[rows].set(vals), bank)
+
+
+def in_place_update(bank, rows, vals):
+    return bank.at[rows].set(vals)
+
+
+def loop_carrying_the_bank(bank, n):
+    # batched over points with their own ``n``, the loop selects every
+    # carried row on every trip
+    import jax
+
+    return jax.lax.while_loop(
+        lambda c: c[0] < n, lambda c: (c[0] + 1, c[1].at[c[0]].add(1)),
+        (0, bank))[1]

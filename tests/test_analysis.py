@@ -221,6 +221,55 @@ def test_pooled_serve_step_lint_clean():
     assert jaxpr.lint_serve_step() == []
 
 
+def test_row_wide_rule_flags_fixtures():
+    """Batched, a whole-bank select and a loop whose trip count differs by
+    point are row-wide; an in-place update, and a loop all points step
+    together, are not."""
+    mod = _load_fixture_module("bad_jaxpr")
+    bank = jax.ShapeDtypeStruct((2, 512), jnp.int32)
+    rows = jax.ShapeDtypeStruct((2, 4), jnp.int32)
+    pred = jax.ShapeDtypeStruct((2,), bool)
+    n = jax.ShapeDtypeStruct((2,), jnp.int32)
+
+    def found(fn, *args, in_axes=0):
+        jpr = jax.make_jaxpr(jax.vmap(fn, in_axes=in_axes))(*args)
+        return [(f, p) for f, p, _ in jaxpr.row_wide_equations(jpr, (512,))]
+
+    assert ("whole_bank_select", "select_n") in found(
+        mod.whole_bank_select, bank, rows, rows, pred)
+    assert found(mod.in_place_update, bank, rows, rows) == []
+    assert found(mod.loop_carrying_the_bank, bank, n) == [
+        ("loop_carrying_the_bank", "while")]
+    assert found(mod.loop_carrying_the_bank, bank, 3,
+                 in_axes=(0, None)) == []
+    # a bank kept in rows of lanes: (points, banks, rows / 128, 128)
+    laned = jax.ShapeDtypeStruct((2, 8, 4, 128), jnp.int32)
+    val = jax.ShapeDtypeStruct((2,), jnp.int32)
+    jpr = jax.make_jaxpr(jax.vmap(mod.whole_bank_select))(laned, rows, val,
+                                                            pred)
+    assert ("whole_bank_select", "select_n") in [
+        (f, p) for f, p, _ in jaxpr.row_wide_equations(jpr, ((4, 128),))]
+
+
+def test_row_wide_rule_flags_one_hot_lookups_of_a_bank(monkeypatch):
+    """The builders' one-hot lookups over a whole bank (all of them, as
+    before the gather path existed: the state keeps every bank table
+    (banks, rows) wide) are what the rule flags; the program as it is
+    passes."""
+    from repro.core import state
+    from repro.sweep import engine
+
+    assert jaxpr.lint_row_wide() == []
+    monkeypatch.setattr(state, "ONEHOT_MAX_COLS", 1 << 30)
+    engine.clear_caches()
+    try:
+        flagged = {f.location for f in jaxpr.lint_row_wide()}
+    finally:
+        engine.clear_caches()
+    assert {"cycle_fn[_onehot]", "cycle_fn[_count]"} <= flagged
+    assert all(f.startswith("cycle_fn[") for f in flagged)
+
+
 @pytest.mark.slow
 def test_jaxpr_layer_clean_on_src():
     assert jaxpr.run() == []

@@ -30,7 +30,7 @@ from conftest import assert_state_matches_oracle, oracle_twin, rand_trace
 from repro.core import controller as ctl
 from repro.core.codes import get_tables
 from repro.core.recoding import recode_step as jax_recode_step
-from repro.core.state import make_params, make_tunables
+from repro.core.state import ONEHOT_MAX_COLS, make_params, make_tunables
 from repro.core.system import CodedMemorySystem, drain_bound
 from repro import oracle
 from repro.oracle import (OracleMemorySystem, OracleParams, build_read_plan,
@@ -295,6 +295,83 @@ def test_full_workload_conformance(scheme, alpha, r):
     equals the golden model's, write-heavy mixes included."""
     check_workload_conformance(scheme, alpha, r, seed=7)
     check_workload_conformance(scheme, alpha, r, seed=8, write_frac=0.7)
+
+
+# ------------------------------------------------- banks of 8,192 rows
+# Above ``state.ONEHOT_MAX_COLS`` columns the state keeps a bank's tables
+# in rows of 128 lanes, the builders, the ReCoding unit and the commit look
+# their cells up by gather and write them by scatter, and the dynamic unit
+# encodes one region's rows: the same contract holds there. r = 0.05 makes regions of 410 rows, so a region
+# encode spans 6 cycles.
+WIDE_ROWS = 8192
+TRACE_KINDS = ("banded", "split", "ramp", "uniform", "zipf")
+
+
+@pytest.mark.parametrize("scheme,alpha", [("scheme_i", 0.25),
+                                          ("uncoded", 0.25)])
+def test_wide_bank_state_conformance(scheme, alpha):
+    """Every state leaf of a full simulation over 8,192-row banks equals
+    the golden model's. The traffic spreads over 8 regions, more than the
+    5 parity slots, so regions are selected, encoded over 6 cycles and
+    evicted, and rows park and recode."""
+    sys_ = _system(scheme, n_rows=WIDE_ROWS, alpha=alpha, r=0.05)
+    assert sys_.p.n_rows > ONEHOT_MAX_COLS
+    assert sys_.p.n_slots * sys_.p.region_size > ONEHOT_MAX_COLS
+    m0 = sys_.init().mem
+    assert {x.ndim for x in (m0.fresh_loc, m0.parity_valid, m0.banks_data,
+                             m0.parity_data, m0.golden)} == {3}
+    om = oracle_twin(sys_)
+    trace = rand_trace(np.random.default_rng(11), 4, 160, sys_.p.n_data,
+                       8 * sys_.p.region_size, write_frac=0.6)
+    st, _ = sys_._run(sys_.init(), trace, 560)
+    ost = om.run(trace, 560)
+    assert_state_matches_oracle(st, ost, f"{scheme} {WIDE_ROWS} rows")
+    res = sys_.summarize(st)
+    assert res.completed
+    if scheme != "uncoded":
+        assert res.switches > sys_.p.n_slots and res.parked_writes > 0
+
+
+def test_mixed_bank_table_forms_state_conformance():
+    """At 4,096 rows the data banks are kept in rows of lanes while the
+    parity tables (5 slots of 205 rows) stay (banks, rows): the two forms
+    in one program give every state leaf of the golden model."""
+    sys_ = _system("scheme_i", n_rows=4096, alpha=0.25, r=0.05)
+    m0 = sys_.init().mem
+    assert (m0.banks_data.ndim, m0.parity_data.ndim) == (3, 2)
+    om = oracle_twin(sys_)
+    trace = rand_trace(np.random.default_rng(12), 4, 120, sys_.p.n_data,
+                       8 * sys_.p.region_size, write_frac=0.6)
+    st, _ = sys_._run(sys_.init(), trace, 420)
+    assert_state_matches_oracle(st, om.run(trace, 420), "4096 rows")
+    assert sys_.summarize(st).switches > sys_.p.n_slots
+
+
+@pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])
+def test_wide_bank_sweep_matches_oracle(scheme):
+    """The sweep engine's batched program over 8,192-row banks, on the five
+    trace kinds at alpha 0.25, gives every result field of the golden
+    model."""
+    from repro.sweep import SweepPoint, run_points
+    from repro.sweep.workloads import build_trace
+    from repro.traces.stream import strip_windows
+
+    base = SweepPoint(scheme=scheme, alpha=0.25, r=0.05, n_rows=WIDE_ROWS,
+                      length=192, select_period=64)
+    pts = [base.replace(trace=k, seed=5) for k in TRACE_KINDS]
+    got = run_points(pts)
+    for pt, res in zip(pts, got):
+        op = OracleParams.derive(pt.n_rows, pt.alpha, pt.r,
+                                 n_data=pt.n_data, recode_cap=pt.recode_cap,
+                                 select_period=pt.select_period,
+                                 wq_hi=pt.wq_hi, wq_lo=pt.wq_lo,
+                                 queue_depth=pt.queue_depth)
+        om = OracleMemorySystem(scheme, op, n_cores=pt.n_cores)
+        ost = om.run(build_trace(pt), pt.resolved_cycles(),
+                     stop_when_quiescent=True)
+        assert strip_windows(res) == om.result(ost), pt.trace
+    if scheme != "uncoded":
+        assert all(r.switches > 0 for r in got)
 
 
 def test_per_cycle_datapath_conformance():

@@ -101,6 +101,23 @@ def test_run_points_writes_every_sweep_span(profiled):
             assert trips >= last + 1
 
 
+def test_init_counts_the_state_it_puts_on_the_device(profiled):
+    """``sweep.init`` counts the bytes of the batched simulator state:
+    every leaf of the vmapped init, as large as the memory simulated."""
+    _, _, _, spans = profiled
+    inits = [s[3] for s in spans if s[0] == "sweep.init"]
+    for batch, stats in zip(partition(POINTS), inits):
+        sys_ = engine.system_for(batch.points[0])
+        tn_b = engine.stack_tunables(batch.points, sys_.p.queue_depth)
+        st_b = jax.eval_shape(
+            lambda tn, s=sys_: engine._batched_init(s, tn), tn_b)
+        want = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(st_b))
+        assert stats == {"points": len(batch.points), "state_bytes": want}
+        # the three bank-wide int32 arrays alone: fresh_loc, banks, golden
+        n = len(batch.points)
+        assert want > 3 * 4 * n * sys_.p.n_data * SMALL_N_ROWS
+
+
 def test_profiling_changes_no_result_and_compiles_nothing(profiled):
     off, on, seen, _ = profiled
     assert on == off
