@@ -33,6 +33,7 @@ enough for the fast CI tier. The runtime complement is
 from __future__ import annotations
 
 import hashlib
+import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -206,22 +207,28 @@ def _frame(eqn) -> str:
     return fr.function_name.rsplit(".", 1)[-1] if fr else "?"
 
 
-def row_wide_equations(closed_jaxpr, widths) -> List[Tuple[str, str, tuple]]:
-    """``(function, primitive, shape)`` of every equation, sub-jaxprs
-    included, that outputs an array a whole bank wide, other than scatters
-    and ``ROW_WIDE_EXEMPT``; and of every ``while`` whose predicate is
-    batched and which carries such an array (its lowering selects each
-    carried array per trip). ``widths`` holds a bank's widths: a whole
-    number is a dimension, a tuple a run of dimensions (a table kept in
-    rows of lanes, ``core.state.bank_table``)."""
+def _wide(widths):
+    """Predicate on shapes: a whole bank wide. ``widths`` holds a bank's
+    widths: a whole number is a dimension, a tuple a run of dimensions (a
+    table kept in rows of lanes, ``core.state.bank_table``)."""
     dims = {w for w in widths if isinstance(w, int)}
     runs = [tuple(w) for w in widths if not isinstance(w, int)]
-    found: List[Tuple[str, str, tuple]] = []
 
     def is_wide(shape):
         return bool(set(shape) & dims) or any(
             shape[k:k + len(r)] == r for r in runs
             for k in range(len(shape) - len(r) + 1))
+    return is_wide
+
+
+def row_wide_equations(closed_jaxpr, widths) -> List[Tuple[str, str, tuple]]:
+    """``(function, primitive, shape)`` of every equation, sub-jaxprs
+    included, that outputs an array a whole bank wide, other than scatters
+    and ``ROW_WIDE_EXEMPT``; and of every ``while`` whose predicate is
+    batched and which carries such an array (its lowering selects each
+    carried array per trip). ``widths`` as for ``_wide``."""
+    is_wide = _wide(widths)
+    found: List[Tuple[str, str, tuple]] = []
 
     def wide(avals):
         return next((a.shape for a in avals
@@ -246,14 +253,43 @@ def row_wide_equations(closed_jaxpr, widths) -> List[Tuple[str, str, tuple]]:
     return found
 
 
-def lint_row_wide(pt=None, batch: int = 2) -> List[Finding]:
-    """A simulated cycle's device work must follow its requests, not the
-    memory it holds: no equation of the batched ``cycle_fn`` (as the sweep
-    engine runs it, under ``vmap``) may output an array a whole bank wide
-    (a dimension of ``n_rows``) or a whole parity bank wide (``n_slots x
-    region_size``), except in-place updates, the loops that carry the
-    state and ``ROW_WIDE_ALLOWED``. Checked at 8,192 rows, where the
-    pattern builders' lookups pick gathers (``core/controller.py``)."""
+def wide_scatters(closed_jaxpr, widths,
+                  scope: str = "") -> List[Tuple[tuple, str, int]]:
+    """``(shape, dtype, updates a point)`` of every scatter, sub-jaxprs
+    included, traced under the named scope ``scope`` into an array a whole
+    bank wide (``widths`` as for ``_wide``). The updates a point are the
+    index tuples the scatter is given for one point of a batch, each a
+    cell (or a window of cells) written or dropped: a TPU scatter costs
+    each of them, dropped or not."""
+    is_wide = _wide(widths)
+    found: List[Tuple[tuple, str, int]] = []
+
+    def visit(jpr):
+        for e in jpr.eqns:
+            for sub in jax.core.jaxprs_in_params(e.params):
+                visit(sub)
+            if not (e.primitive.name.startswith("scatter")
+                    and scope in str(e.source_info.name_stack)):
+                continue
+            operand, indices = e.invars[0].aval, e.invars[1].aval
+            if not is_wide(operand.shape):
+                continue
+            # the last dimension of the indices holds one index tuple
+            batch = e.params["dimension_numbers"].scatter_indices_batching_dims
+            per_point = [n for k, n in enumerate(indices.shape[:-1])
+                         if k not in batch]
+            found.append((operand.shape, str(operand.dtype),
+                          math.prod(per_point)))
+
+    visit(closed_jaxpr.jaxpr)
+    return found
+
+
+def batched_cycle_jaxpr(pt=None, batch: int = 2):
+    """(the jaxpr of ``cycle_fn`` as the sweep engine runs it, under
+    ``vmap`` over ``batch`` points; a bank's widths for ``_wide``; the
+    system) for ``pt``, by default 8,192-row banks, where the pattern
+    builders' lookups pick gathers (``core/controller.py``)."""
     from repro.sweep import engine
     from repro.sweep.grid import SweepPoint
 
@@ -264,9 +300,22 @@ def lint_row_wide(pt=None, batch: int = 2) -> List[Finding]:
         lambda a: jax.ShapeDtypeStruct((batch,) + a.shape, a.dtype),
         _point_program_inputs(pt, sys))
     jpr = jax.make_jaxpr(jax.vmap(sys.cycle_fn))(*inputs)
-    rows, par_rows = sys.p.n_rows, sys.p.n_slots * sys.p.region_size
     m = inputs[0].mem
-    widths = (rows, par_rows, m.banks_data.shape[2:], m.parity_data.shape[2:])
+    widths = (sys.p.n_rows, sys.p.n_slots * sys.p.region_size,
+              m.banks_data.shape[2:], m.parity_data.shape[2:])
+    return jpr, widths, sys
+
+
+def lint_row_wide(pt=None, batch: int = 2) -> List[Finding]:
+    """A simulated cycle's device work must follow its requests, not the
+    memory it holds: no equation of the batched ``cycle_fn`` (as the sweep
+    engine runs it, under ``vmap``) may output an array a whole bank wide
+    (a dimension of ``n_rows``) or a whole parity bank wide (``n_slots x
+    region_size``), except in-place updates, the loops that carry the
+    state and ``ROW_WIDE_ALLOWED``. Checked at 8,192 rows, where the
+    pattern builders' lookups pick gathers (``core/controller.py``)."""
+    jpr, widths, sys = batched_cycle_jaxpr(pt, batch)
+    rows, par_rows = widths[:2]
     return [Finding("jaxpr-row-wide", f"cycle_fn[{fn}]",
                     f"{prim} outputs {shape}, a whole bank wide (rows "
                     f"{rows}, parity rows {par_rows}): a cycle's work "

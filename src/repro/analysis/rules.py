@@ -84,7 +84,8 @@ ALWAYS_STATIC_CALLS = {"_concrete_int"}
 TRACED_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/controller.py": {
         "_walk_bounds", "_Walk.*", "_first_min", "_pick", "_onehot",
-        "_count", "_take", "_col", "_cell", "_put", "build_read_pattern",
+        "_count", "_take", "_col", "_cell", "_put", "_compact",
+        "build_read_pattern",
         "build_write_pattern", "_rc_push"},
     "src/repro/core/recoding.py": {"recode_step"},
     "src/repro/core/dynamic.py": {
@@ -116,7 +117,7 @@ TRACED_FUNCTIONS: Dict[str, Set[str]] = {
 HOST_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/__init__.py": {"*"},
     "src/repro/core/codes.py": {"*"},
-    "src/repro/core/controller.py": {"jtables"},
+    "src/repro/core/controller.py": {"jtables", "write_slots"},
     "src/repro/core/state.py": {
         "make_tunables", "wide_total", "derive_geometry", "make_params",
         "_concrete_int", "bank_view"},

@@ -59,7 +59,10 @@ contains:
     a batched gather or scatter: an op that does not fuse, whose launch a
     trip pays each time. The write walk's write-only state
     (``fresh_loc``, ``parity_valid``, ``parked_count``, the recode ring)
-    is recorded per candidate and applied once after it (``_put``).
+    is recorded per candidate and applied once after it (``_put``); into
+    a table kept in rows of lanes, the cells it writes are first
+    compacted into as many slots as the cycle has ports (``write_slots``,
+    ``_compact``).
 
 The greedy semantics are genuinely sequential only across candidates that
 contend (same ports, or symbols on the same row of one parity group), so
@@ -150,6 +153,41 @@ class WritePlan(NamedTuple):
     n_served: jnp.ndarray
     n_parked: jnp.ndarray
     n_rc_dropped: jnp.ndarray  # () int32 — recode requests lost to a full ring
+
+
+class WriteSlots(NamedTuple):
+    """The most cells one cycle's write datapath writes into each table:
+    the static number of slots its writes into a table kept in rows of
+    lanes are compacted into before they are scattered (``_compact``)."""
+
+    banks_data: int
+    parity_data: int
+    golden: int
+    fresh_loc: int
+    parity_valid: int
+
+
+def write_slots(p: MemParams) -> WriteSlots:
+    """Each table's slot count, from the ports. A served write claims one
+    port, and the write builder claims a port at most once a cycle (its
+    ``busy`` flags; a down or stuttering port is busy from the start, so
+    faults only lower the count). So a cycle serves at most ``n_ports``
+    writes: at most ``n_data`` direct ones, each on its data bank's port,
+    and at most ``n_ports - n_data`` parked ones, each on its physical
+    parity bank's port.
+
+    * ``banks_data``: the direct writes.
+    * ``parity_data``: the parked writes (at least one slot, which an
+      uncoded scheme leaves empty).
+    * ``golden``, ``fresh_loc``: every served write; ``fresh_loc`` changes
+      at no other cell.
+    * ``parity_valid``: a direct write invalidates at most ``MAX_OPTS``
+      parity cells (its bank's options at its row), a parked one the one
+      cell it parks in."""
+    n_par_ports = max(p.n_ports - p.n_data, 1)
+    return WriteSlots(banks_data=p.n_data, parity_data=n_par_ports,
+                      golden=p.n_ports, fresh_loc=p.n_ports,
+                      parity_valid=p.n_data * MAX_OPTS + n_par_ports)
 
 
 def _walk_bounds(cand_age, cand_valid):
@@ -354,6 +392,30 @@ def _put(table, rows, cols, vals, mask, small=True):
     new = jnp.max(jnp.where(hit, vals[:, None, None], jnp.iinfo(
         jnp.int32).min), axis=0)
     return jnp.where(jnp.any(hit, axis=0), new, table).astype(table.dtype)
+
+
+def _compact(table, n_slots, mask, *cols):
+    """The writes into ``table`` where ``mask`` holds, as (mask, each of
+    ``cols``) to write from. Into a table kept in rows of lanes they are
+    compacted, first to last, into ``n_slots`` slots: its scatter costs
+    each update it is given, kept or dropped. An entry past the last slot
+    is left out: the caller proves that there are none. A rank from a
+    cumulative sum and compare and select over the (slots, entries) grid,
+    since an index gather or scatter here would be such an op itself. A
+    table indexed by one-hot, whose write costs its width, takes them as
+    they are: there, on a v5e, the compactions cost a 512-row cycle more
+    than the smaller writes saved."""
+    # static: the stored form of the table
+    if table.ndim != 3:
+        return (mask,) + cols
+    rank = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    on = mask & (rank == jnp.arange(n_slots, dtype=jnp.int32)[:, None])
+
+    def of_slot(x):
+        s = on.reshape(on.shape + (1,) * (x.ndim - 1))
+        return jnp.sum(jnp.where(s, x[None], 0), axis=1).astype(x.dtype)
+
+    return (jnp.any(on, axis=1),) + tuple(of_slot(x) for x in cols)
 
 
 def build_read_pattern(
@@ -662,10 +724,14 @@ def build_write_pattern(
     ports = jnp.arange(port_busy.shape[0], dtype=jnp.int32)
     port_busy = (port_busy | jnp.any(claim[:, None] == ports, axis=0)
                  | (ports == nop))                  # deterministic sink
-    # every candidate's copy of its own cell writes it back (copies of one
-    # cell are equal)
-    fresh_loc = _put(fresh_loc, b, i, w.get(table, "fl")[:, 0],
-                     jnp.ones((n,), bool))
+    # a cell's freshness changes only where a write is served, and every
+    # copy of a cell is equal: the served candidates write theirs back,
+    # from as many slots as ``write_slots`` proves a cycle serves
+    slots = write_slots(p)
+    served = mode_w != WMODE_UNSERVED
+    ok, b_s, i_s, fl_s = _compact(fresh_loc, slots.fresh_loc, served, b, i,
+                                  w.get(table, "fl")[:, 0])
+    fresh_loc = _put(fresh_loc, b_s, i_s, fl_s, ok)
     parked_count = parked_count + jnp.sum(
         jnp.where(region[:, None] == jnp.arange(parked_count.shape[0]),
                   w.get(table, "delta")[:, None], 0), axis=0)
@@ -673,7 +739,11 @@ def build_write_pattern(
     inv = ((optj >= 0) & coded[:, None]
            & (is_dir[:, None]
               | (is_park[:, None] & (optjj == j_sel[:, None]))))
-    parity_valid = _put(parity_valid, optjj, pr[:, None], False, inv)
+    ok, j_s, pr_s = _compact(parity_valid, slots.parity_valid,
+                             inv.reshape(-1), optjj.reshape(-1),
+                             jnp.broadcast_to(pr[:, None], inv.shape)
+                             .reshape(-1))
+    parity_valid = _put(parity_valid, j_s, pr_s, False, ok)
     # the t-th push of the walk fills the t-th free slot of the ring
     slot_rank = jnp.where(free, jnp.cumsum(free) - 1, -2)
     hit = slot_rank[:, None] == w.get(table, "push")[None, :]   # (cap, N)
@@ -683,11 +753,9 @@ def build_write_pattern(
     rc_row = jnp.where(filled, jnp.sum(jnp.where(hit, i, 0), axis=1), rc_row)
     rc_valid = rc_valid | filled
 
-    mode = mode_w
-    served = mode != WMODE_UNSERVED
     n_served = jnp.sum(served).astype(jnp.int32)
-    n_parked = jnp.sum(served & (mode >= WMODE_PARK0)).astype(jnp.int32)
-    return WritePlan(served, mode, port_busy, fresh_loc, parity_valid,
+    n_parked = jnp.sum(is_park).astype(jnp.int32)
+    return WritePlan(served, mode_w, port_busy, fresh_loc, parity_valid,
                      parked_count, rc_bank, rc_row, rc_valid, n_served,
                      n_parked, dropped)
 

@@ -40,8 +40,6 @@ from repro.faults import inject as finject
 from repro.faults import plan as fplan
 from repro.obs import planes as obs
 
-INT32_MAX = jnp.iinfo(jnp.int32).max
-
 
 class Trace(NamedTuple):
     """Per-core request streams. Invalid entries are idle cycles."""
@@ -342,49 +340,50 @@ class CodedMemorySystem:
 
     # ------------------------------------------------------- write datapath
     def _commit_writes(self, m: MemState, plan: ctl.WritePlan, cb, ci_, ca,
-                       cv, cd, rs_a):
+                       cd, rs_a):
         """Commit served write payloads in age order (last write wins).
 
         Vectorized: rather than walking candidates in a fori_loop, each
-        candidate's age-order position is compared with those of the
-        candidates writing the same cell; only the positionally-latest
-        (youngest) served write per cell lands — the same value the
-        sequential walk leaves behind.
+        served candidate's age order is compared with that of the served
+        candidates writing the same cell; only the youngest served write
+        per cell lands — the same value the sequential walk leaves behind.
+        Into a table kept in rows of lanes, the cells that land are
+        compacted into as many slots as ``ctl.write_slots`` proves a cycle
+        writes, so its scatter is given those and not every candidate of
+        the queues.
         """
         p, t = self.p, self.t
         rs = p.region_size
         b = jnp.maximum(cb, 0)
         i = jnp.maximum(ci_, 0)
-        n = cb.shape[0]
-        order = jnp.argsort(jnp.where(cv, ca, INT32_MAX))
-        pos = jnp.zeros((n,), jnp.int32).at[order].set(
-            jnp.arange(n, dtype=jnp.int32))
+        idx = jnp.arange(cb.shape[0], dtype=jnp.int32)
+        # served candidates are valid: age order, ties in queue order
+        younger = ((ca[None, :] > ca[:, None])
+                   | ((ca[None, :] == ca[:, None])
+                      & (idx[None, :] > idx[:, None])))
         slot = m.region_slot[i // rs_a]
         pr = jnp.maximum(slot, 0) * rs + i % rs_a
         kk = jnp.clip(plan.mode - ctl.WMODE_PARK0, 0, MAX_OPTS - 1)
         j = jnp.maximum(t.opt_parity[b, kk], 0)
         is_dir = plan.served & (plan.mode == ctl.WMODE_DIRECT)
         is_park = plan.served & (plan.mode >= ctl.WMODE_PARK0)
-        oob_b = jnp.int32(p.n_data)
-        oob_j = jnp.int32(m.parity_data.shape[0])
+        slots = ctl.write_slots(p)
 
-        def winners(mask, rows, cols):
-            # no later write in ``mask`` lands on the same cell: compared
+        def commit(table, n_slots, mask, rows, cols):
+            # no younger write in ``mask`` lands on the same cell: compared
             # among the candidates, so the work follows them, not the banks
             later = ((rows[None, :] == rows[:, None])
                      & (cols[None, :] == cols[:, None])
-                     & mask[None, :] & (pos[None, :] > pos[:, None]))
-            return mask & ~jnp.any(later, axis=1)
+                     & mask[None, :] & younger)
+            win = mask & ~jnp.any(later, axis=1)
+            ok, r_s, c_s, v_s = ctl._compact(table, n_slots, win, rows, cols,
+                                             cd)
+            return set_cells(table, jnp.where(ok, r_s, table.shape[0]), c_s,
+                             v_s)
 
-        win_d = winners(is_dir, b, i)
-        banks_data = set_cells(m.banks_data, jnp.where(win_d, b, oob_b), i,
-                               cd)
-        win_p = winners(is_park, j, pr)
-        parity_data = set_cells(m.parity_data, jnp.where(win_p, j, oob_j),
-                                pr, cd)
-        win_g = winners(plan.served, b, i)
-        golden = set_cells(m.golden, jnp.where(win_g, b, oob_b), i, cd)
-        return banks_data, parity_data, golden
+        return (commit(m.banks_data, slots.banks_data, is_dir, b, i),
+                commit(m.parity_data, slots.parity_data, is_park, j, pr),
+                commit(m.golden, slots.golden, plan.served, b, i))
 
     # ------------------------------------------------------------- one cycle
     @functools.partial(jax.jit, static_argnums=0)
@@ -539,7 +538,7 @@ class CodedMemorySystem:
                 rs_a, down=down if p.faults else None,
             )
             banks_data, parity_data, golden = self._commit_writes(
-                m, plan, cb, ci_, ca, cv, cd, rs_a)
+                m, plan, cb, ci_, ca, cd, rs_a)
             lat = jnp.sum(jnp.where(plan.served, m.cycle - ca, 0))
             tele = m.tele
             if p.telemetry:

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codes import get_tables
+from repro.core.controller import write_slots
 from repro.core.state import TunableParams, make_params, make_tunables
 from repro.core.system import (CodedMemorySystem, SimResult, SimState, Trace,
                                quiescent, result_from_host)
@@ -262,7 +263,13 @@ def run_batch(batch: GridBatch, traces: Optional[Sequence[Trace]] = None,
             host = jax.device_get(st)
             # the points step in lockstep, so every point's cycle counter
             # holds the while-loop's trip count
-            s.set_metadata(trips=int(np.max(host.mem.cycle)))
+            trips = int(np.max(host.mem.cycle))
+            # the cells a cycle's write datapath has room for (one a port)
+            # beside the writes a point served on average in a trip
+            writes = int(np.sum(host.mem.served_writes[:len(pts)]))
+            s.set_metadata(trips=trips,
+                           write_slots=write_slots(sys.p).golden,
+                           writes_per_trip=writes / max(len(pts) * trips, 1))
             results = summarize_batch(host, n_points=len(pts))
         if not collect_telemetry:
             return results

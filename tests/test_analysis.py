@@ -270,6 +270,32 @@ def test_row_wide_rule_flags_one_hot_lookups_of_a_bank(monkeypatch):
     assert all(f.startswith("cycle_fn[") for f in flagged)
 
 
+def test_write_datapath_scatters_follow_the_ports():
+    """In the batched ``cycle_fn`` at 65,536 rows (every bank table kept in
+    rows of lanes), each scatter of the write datapath into a table a bank
+    or a parity bank wide is given at most its table's slot count of cells
+    a point, from the ports (``write_slots``), not one for every candidate
+    of the queues (80)."""
+    from repro.core.controller import write_slots
+    from repro.sweep.grid import SweepPoint
+
+    jpr, widths, sys = jaxpr.batched_cycle_jaxpr(
+        SweepPoint(n_rows=65536, length=8, alpha=0.25, r=0.05))
+    p, slots = sys.p, write_slots(sys.p)
+    m = sys.init().mem
+    found = sorted((shape[1:], dtype, n) for shape, dtype, n in
+                   jaxpr.wide_scatters(jpr, widths, scope="cycle.patterns"))
+    bank, par = m.banks_data.shape, m.parity_data.shape
+    assert found == sorted([
+        (bank, "int32", slots.banks_data), (bank, "int32", slots.golden),
+        (bank, "int32", slots.fresh_loc),
+        (par, "int32", slots.parity_data), (par, "bool", slots.parity_valid),
+    ])
+    assert (slots.banks_data, slots.parity_data, slots.golden) == (
+        p.n_data, p.n_ports - p.n_data, p.n_ports)
+    assert max(n for _, _, n in found) < p.n_data * p.queue_depth
+
+
 @pytest.mark.slow
 def test_jaxpr_layer_clean_on_src():
     assert jaxpr.run() == []

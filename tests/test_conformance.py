@@ -347,6 +347,106 @@ def test_mixed_bank_table_forms_state_conformance():
     assert sys_.summarize(st).switches > sys_.p.n_slots
 
 
+# ------------------------------------------------ write slots
+# The write datapath compacts the cells a cycle writes into each table
+# into ``ctl.write_slots(p)`` slots before it scatters them; a cell past
+# the last slot would be lost. The golden model's own plans count, cycle
+# by cycle, how many distinct cells each table is written at.
+def _count_written_cells(om, peaks):
+    """Wrap ``om``'s commit so that every write cycle raises ``peaks[table]``
+    to the distinct cells written into ``table`` that cycle."""
+    commit = om._commit_writes
+    p = om.p
+
+    def counting(st, plan, cb, ci, ca, cv, cd):
+        cells = {name: set() for name in ctl.WriteSlots._fields}
+        for c in np.flatnonzero(plan.served):
+            b, i, mode = max(int(cb[c]), 0), max(int(ci[c]), 0), int(
+                plan.mode[c])
+            slot = int(st.region_slot[i // p.rs_active])
+            pr = max(slot, 0) * p.region_size + i % p.rs_active
+            cells["golden"].add((b, i))
+            cells["fresh_loc"].add((b, i))
+            if mode == ctl.WMODE_DIRECT:
+                cells["banks_data"].add((b, i))
+                if slot >= 0:
+                    cells["parity_valid"].update(
+                        (j, pr) for j, _ in om.options[b])
+            else:
+                j = om.options[b][mode - ctl.WMODE_PARK0][0]
+                cells["parity_data"].add((j, pr))
+                cells["parity_valid"].add((j, pr))
+        # freshness changes at no cell but the served ones
+        moved = set(zip(*np.nonzero(plan.fresh_loc != st.fresh_loc)))
+        assert moved <= cells["fresh_loc"]
+        for name, written in cells.items():
+            peaks[name] = max(peaks.get(name, 0), len(written))
+        commit(st, plan, cb, ci, ca, cv, cd)
+
+    om._commit_writes = counting
+
+
+def _hot_write_storm(rng, n_cores, length, n_data, hot_rows):
+    """Write-heavy requests of every core onto a few hot rows of every
+    bank, so that several cores write one cell and one cycle serves
+    several writes to it."""
+    from repro.core.system import Trace
+    shape = (n_cores, length)
+    return Trace(
+        bank=jnp.asarray(rng.integers(0, n_data, shape), jnp.int32),
+        row=jnp.asarray(rng.choice(hot_rows, shape), jnp.int32),
+        is_write=jnp.asarray(rng.random(shape) < 0.85),
+        data=jnp.asarray(rng.integers(1, 1 << 20, shape), jnp.int32),
+        valid=jnp.ones(shape, bool))
+
+
+@pytest.mark.parametrize("scheme,n_rows,dead_bank", [
+    ("scheme_i", 512, False),
+    ("scheme_i", 512, True),
+    ("scheme_i", WIDE_ROWS, False),
+    ("scheme_ii", WIDE_ROWS, False),
+    ("scheme_iii", WIDE_ROWS, False),
+    ("uncoded", WIDE_ROWS, False),
+    ("scheme_i", WIDE_ROWS, True),
+])
+def test_write_slots_hold_every_written_cell(scheme, n_rows, dead_bank):
+    """Under write storms onto hot cells, no cycle writes more distinct
+    cells into a table than its slot count, and every state leaf equals
+    the golden model's. At 8,192 rows the tables are kept in rows of
+    lanes, so the datapath writes from the slots; at 512 they are indexed
+    by one-hot and written from every candidate. ``dead_bank``: bank 2
+    fails at cycle 12 and never recovers, so its writes park."""
+    from repro.faults import FaultPlan
+
+    t = get_tables(scheme)
+    p = make_params(t, n_rows=n_rows, alpha=1.0, r=0.25, faults=dead_bank)
+    assert p.coalesce == (scheme != "uncoded")
+    sys_ = CodedMemorySystem(t, p, n_cores=8, tunables=make_tunables(
+        queue_depth=p.queue_depth, select_period=16))
+    plan = (FaultPlan(p.n_data, p.n_ports, bank_faults=((2, 12, -1),))
+            if dead_bank else None)
+    om = oracle_twin(sys_)
+    peaks = {}
+    _count_written_cells(om, peaks)
+    rng = np.random.default_rng(21)
+    trace = _hot_write_storm(rng, 8, 48, p.n_data,
+                             rng.choice(n_rows, 6, replace=False))
+    n_cycles = 120
+    st, _ = sys_._run(sys_.init(fault_plan=plan), trace, n_cycles)
+    ost = om.run(trace, n_cycles, st=om.init_state(fault_plan=plan))
+    assert_state_matches_oracle(st, ost, f"{scheme} {n_rows} rows")
+    slots = ctl.write_slots(p)
+    for name in ctl.WriteSlots._fields:
+        assert peaks[name] <= getattr(slots, name), (name, peaks, slots)
+    # the storm fills the data ports, and the parity ports where there are
+    assert peaks["banks_data"] == slots.banks_data
+    if scheme != "uncoded":
+        assert peaks["parity_data"] == slots.parity_data
+        assert peaks["golden"] > p.n_data
+    if dead_bank:
+        assert int(jax.device_get(st.mem.fault.dead_cycles)[2]) > 0
+
+
 @pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])
 def test_wide_bank_sweep_matches_oracle(scheme):
     """The sweep engine's batched program over 8,192-row banks, on the five

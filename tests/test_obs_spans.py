@@ -118,6 +118,24 @@ def test_init_counts_the_state_it_puts_on_the_device(profiled):
         assert want > 3 * 4 * n * sys_.p.n_data * SMALL_N_ROWS
 
 
+def test_summarize_counts_the_write_slots_a_trip_uses(profiled):
+    """``sweep.summarize`` gives the cells a cycle's write datapath has
+    room for (``write_slots``, one a port) and the writes a point served
+    on average in a loop trip (``writes_per_trip``), which they bound."""
+    from repro.core.controller import write_slots
+
+    _, results, _, spans = profiled
+    stats = [s[3] for s in spans if s[0] == "sweep.summarize"]
+    for batch, st in zip(partition(POINTS), stats):
+        p = engine.system_for(batch.points[0]).p
+        assert st["write_slots"] == write_slots(p).golden == p.n_ports
+        writes = sum(results[i].served_writes for i in batch.indices)
+        assert writes > 0
+        assert st["writes_per_trip"] == pytest.approx(
+            writes / (len(batch.points) * st["trips"]))
+        assert 0 < st["writes_per_trip"] <= st["write_slots"]
+
+
 def test_profiling_changes_no_result_and_compiles_nothing(profiled):
     off, on, seen, _ = profiled
     assert on == off
