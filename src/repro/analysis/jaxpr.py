@@ -289,7 +289,7 @@ def batched_cycle_jaxpr(pt=None, batch: int = 2):
     """(the jaxpr of ``cycle_fn`` as the sweep engine runs it, under
     ``vmap`` over ``batch`` points; a bank's widths for ``_wide``; the
     system) for ``pt``, by default 8,192-row banks, where the pattern
-    builders' lookups pick gathers (``core/controller.py``)."""
+    builders' lookups pick gathers (``core/state.py``)."""
     from repro.sweep import engine
     from repro.sweep.grid import SweepPoint
 
@@ -313,7 +313,9 @@ def lint_row_wide(pt=None, batch: int = 2) -> List[Finding]:
     (a dimension of ``n_rows``) or a whole parity bank wide (``n_slots x
     region_size``), except in-place updates, the loops that carry the
     state and ``ROW_WIDE_ALLOWED``. Checked at 8,192 rows, where the
-    pattern builders' lookups pick gathers (``core/controller.py``)."""
+    pattern builders' lookups pick gathers (``core/state.py``); a finding
+    is named by its innermost function (a one-hot lookup or write of a bank
+    table: ``_onehot``, ``_count``)."""
     jpr, widths, sys = batched_cycle_jaxpr(pt, batch)
     rows, par_rows = widths[:2]
     return [Finding("jaxpr-row-wide", f"cycle_fn[{fn}]",

@@ -83,18 +83,17 @@ ALWAYS_STATIC_CALLS = {"_concrete_int"}
 # (qualified as "func" or "Class.method"; "Class.*" and "*" are wildcards).
 TRACED_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/controller.py": {
-        "_walk_bounds", "_Walk.*", "_first_min", "_pick", "_onehot",
-        "_count", "_take", "_col", "_cell", "_put", "_compact",
-        "build_read_pattern",
-        "build_write_pattern", "_rc_push"},
-    "src/repro/core/recoding.py": {"recode_step"},
+        "locate", "_walk_bounds", "_Walk.*", "_first_min", "_pick",
+        "build_read_pattern", "build_write_pattern"},
+    "src/repro/core/recoding.py": {"recode_step", "rc_push"},
     "src/repro/core/dynamic.py": {
         "_on", "_encode_region_data", "_set_slot_valid", "priors_layout",
         "dynamic_step"},
     "src/repro/core/state.py": {
-        "bank_table", "cells", "set_cells", "_lane_rows", "columns",
-        "set_columns", "active_geometry", "wide_zero", "wide_add",
-        "init_state"},
+        "bank_table", "cells", "set_cells", "_compact", "_onehot", "_count",
+        "take", "bank_col", "bank_cell", "bank_put", "_lane_rows",
+        "columns", "set_columns", "active_geometry", "wide_zero",
+        "wide_add", "init_state"},
     "src/repro/core/system.py": {
         "quiescent", "CodedMemorySystem._arbiter",
         "CodedMemorySystem._read_values", "CodedMemorySystem._commit_writes",

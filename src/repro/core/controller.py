@@ -49,20 +49,23 @@ contains:
     capacity-bounded implementation of the same semantics could never
     saturate — the per-cycle symbol count is bounded by port claims.
   * **lookups before the walk, writes after it** — the table is built
-    once per call, by one-hot lookups (``_take``, ``_cell``; a gather
-    where the state keeps a bank in rows of lanes, see
-    ``state.bank_table``); a trip
-    picks its candidate's row with one masked reduction and updates every
-    row by compare-and-select, so the loop body holds no gather or
+    once per call: each candidate's coded address by ``locate`` (the one
+    derivation of a request's region, parity row and parity options,
+    shared by every unit of the cycle), the bank tables' cells by
+    ``state.bank_cell`` (one-hot, or gather where the state keeps a bank
+    in rows of lanes: ``core/state.py`` alone knows the two forms). A
+    trip picks its candidate's row with one masked reduction and updates
+    every row by compare-and-select, so the loop body holds no gather or
     scatter. Under the sweep engine's ``vmap`` the trip counter is
     batched, and every indexed read or write inside the loop would become
     a batched gather or scatter: an op that does not fuse, whose launch a
     trip pays each time. The write walk's write-only state
     (``fresh_loc``, ``parity_valid``, ``parked_count``, the recode ring)
-    is recorded per candidate and applied once after it (``_put``); into
-    a table kept in rows of lanes, the cells it writes are first
-    compacted into as many slots as the cycle has ports (``write_slots``,
-    ``_compact``).
+    is recorded per candidate and applied once after it
+    (``state.bank_put``, given as many slots as the cycle has ports,
+    ``write_slots``). Each plan carries the address it served (the parity
+    and row, and a read's siblings), so the datapath reads and writes the
+    data values there and looks nothing up again.
 
 The greedy semantics are genuinely sequential only across candidates that
 contend (same ports, or symbols on the same row of one parity group), so
@@ -80,10 +83,8 @@ states and full workloads; see docs/testing.md.
 
 Region geometry is traced, not static: both builders take an optional
 ``rs_active`` (the point's own region size inside a padded sweep
-allocation, see ``state.active_geometry``). Region lookups use it;
-parity-row addressing keeps the *allocated* ``p.region_size`` stride so
-padded slots never alias. ``None`` (the default) means the allocation is
-the geometry.
+allocation, see ``state.active_geometry``) and pass it to ``locate``.
+``None`` (the default) means the allocation is the geometry.
 """
 from __future__ import annotations
 
@@ -94,7 +95,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codes import MAX_OPTS, CodeTables
-from repro.core.state import MemParams, cells, set_cells
+from repro.core.state import MemParams, bank_cell, bank_put, take
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 INF_SCORE = jnp.int32(1 << 30)
@@ -132,12 +133,63 @@ def jtables(tables: CodeTables) -> JTables:
     )
 
 
+class Loc(NamedTuple):
+    """Where requests' cells live in the coded memory (``locate``), each
+    field shaped as the requests (``...``)."""
+
+    b: jnp.ndarray       # (...) data bank (clamped to >= 0)
+    i: jnp.ndarray       # (...) row (clamped to >= 0)
+    region: jnp.ndarray  # (...) region id
+    slot: jnp.ndarray    # (...) the region's parity slot, -1 = uncoded
+    coded: jnp.ndarray   # (...) bool, slot >= 0
+    pr: jnp.ndarray      # (...) parity row
+    optj: jnp.ndarray    # (..., K) the bank's options' parities, -1 pad
+    optjj: jnp.ndarray   # (..., K) optj clamped to >= 0
+    pport: jnp.ndarray   # (..., K) their ports; the sink (n_ports) where
+                         # none
+    sibs: jnp.ndarray    # (..., K, MAX_SIBS) their sibling banks, -1 pad
+    mem: jnp.ndarray     # (..., K, MAX_SIBS+1) their members, -1 pad
+
+
+def locate(p: MemParams, t: JTables, region_slot, bank, row,
+           rs_a=None) -> Loc:
+    """The coded address of requests to (``bank``, ``row``): the one
+    derivation every unit of the cycle uses. ``rs_a`` is the point's own
+    region size (``state.active_geometry``; ``None``: the allocation's).
+    Regions are counted at ``rs_a``; a parity row keeps the allocated
+    ``p.region_size`` stride, so padded slots never alias. The lookups are
+    by compare and select (``take``); a field a caller does not use is
+    dead code under ``jit``."""
+    rs = p.region_size
+    rs_a = rs if rs_a is None else rs_a
+    b = jnp.maximum(bank, 0)
+    i = jnp.maximum(row, 0)
+    region = i // rs_a
+    slot = take(region_slot, region)
+    optj = take(t.opt_parity, b)
+    optjj = jnp.maximum(optj, 0)
+    pport = take(t.par_port, optjj)
+    # a negative port id (scheme with no parities) points a claim at the
+    # dummy sink slot, where the lookup of the padded -1 lands
+    return Loc(b=b, i=i, region=region, slot=slot, coded=slot >= 0,
+               pr=jnp.maximum(slot, 0) * rs + i % rs_a, optj=optj,
+               optjj=optjj, pport=jnp.where(pport < 0, p.n_ports, pport),
+               sibs=take(t.opt_sibs, b), mem=take(t.par_members, optjj))
+
+
 class ReadPlan(NamedTuple):
     served: jnp.ndarray      # (N,) bool
     mode: jnp.ndarray        # (N,) int32
     port_busy: jnp.ndarray   # (n_ports+1,) bool (updated)
     n_served: jnp.ndarray    # () int32
     n_degraded: jnp.ndarray  # () int32 — served via parity/symbol reuse
+    # the served address the read datapath decodes from
+    par: jnp.ndarray         # (N,) int32 parity read: the chosen option's
+                             # (OPT) or the holder of a parked value
+                             # (REDIRECT); -1 where none
+    prow: jnp.ndarray        # (N,) int32 parity row of the candidate's row
+    sibs: jnp.ndarray        # (N, MAX_SIBS) int32 sibling banks XORed in
+                             # (OPT); -1 where none
 
 
 class WritePlan(NamedTuple):
@@ -153,12 +205,16 @@ class WritePlan(NamedTuple):
     n_served: jnp.ndarray
     n_parked: jnp.ndarray
     n_rc_dropped: jnp.ndarray  # () int32 — recode requests lost to a full ring
+    # the served address the write datapath commits to
+    par: jnp.ndarray          # (N,) int32 parity parked into (PARK); -1 else
+    prow: jnp.ndarray         # (N,) int32 parity row of the candidate's row
 
 
 class WriteSlots(NamedTuple):
     """The most cells one cycle's write datapath writes into each table:
     the static number of slots its writes into a table kept in rows of
-    lanes are compacted into before they are scattered (``_compact``)."""
+    lanes are compacted into before they are scattered
+    (``state.set_cells``)."""
 
     banks_data: int
     parity_data: int
@@ -270,9 +326,9 @@ def _first_min(scores):
     unrolled into selects, so it fuses with the work around it."""
     act, best = jnp.int32(0), scores[0]
     for a, s in enumerate(scores[1:], 1):
-        take = s < best
-        act = jnp.where(take, a, act)
-        best = jnp.where(take, s, best)
+        better = s < best
+        act = jnp.where(better, a, act)
+        best = jnp.where(better, s, best)
     return act, best
 
 
@@ -283,139 +339,6 @@ def _pick(values, k):
     for j in range(1, len(values)):
         out = jnp.where(k == j, values[j], out)
     return out
-
-
-# Indexing by traced indices compiles to gathers and scatters, which a TPU
-# runs element by element: on a v5e a batched gather of 3,200 elements took
-# 25-38 us, and a scatter sorts its keys first. A one-hot lookup instead
-# compares every index with every column, or contracts 0/1 matrices with
-# small whole numbers (exact in float32): work that grows with the table's
-# width. The small tables (queues, ports, code tables, region maps) are
-# always indexed by one-hot. The tables a bank wide (``fresh_loc``,
-# ``parity_valid``, the data) are indexed by one-hot up to
-# ``state.ONEHOT_MAX_COLS`` columns; wider, the state keeps them in rows of
-# 128 lanes (``state.bank_table``) and they are indexed by gather and
-# scatter, where a one-hot would cost a cycle what the memory holds, not
-# what its requests touch. Measured on one TPU v5e: a loop trip of 40
-# points, each looking up (or writing) 80 cells of an (8, W) int32 table,
-# in microseconds:
-#
-#       W     cell: one-hot / gather   write: one-hot / scatter
-#     512              9.8 / 49.3               14.3 / 26.5
-#   2,048             20.5 / 48.0               25.3 / 28.3
-#   8,192             64.1 / 46.8               73.2 / 36.1
-#  65,536            463.1 / 49.1              498.5 / 504.4
-#
-# The one-hot's cost is the table's width; the scatter's 504 us at 65,536
-# is the relayout of the whole (8, W) array that a TPU scatter makes, which
-# the rows of 128 lanes avoid (``core/state.py``). From a table in rows of
-# lanes a gather costs what it reads (same loop, 65,536 columns: 80 cells
-# a point 52 us, 640 cells 396 us), so ``_cell`` gathers the fewer cells
-# of its two ways.
-
-
-def _onehot(idx, n):
-    """(..., n) float32 one-hot rows of ``idx`` (all zero out of range)."""
-    return (idx[..., None] == jnp.arange(n)).astype(jnp.float32)
-
-
-def _count(spec, *operands):
-    """``einsum`` of one-hots and small whole numbers, exact in float32."""
-    import jax
-
-    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
-
-
-def _take(table, idx):
-    """``table[idx]`` for a small table and integer ``idx`` of any shape,
-    as indexing does it (a negative index counts from the end, the rest
-    clamp), by compare and select over the table's rows."""
-    size = table.shape[0]
-    idx = jnp.clip(jnp.where(idx < 0, idx + size, idx), 0, size - 1)
-    hit = idx[..., None] == jnp.arange(size)
-    hit = hit.reshape(hit.shape + (1,) * (table.ndim - 1))
-    return jnp.sum(jnp.where(hit, table, 0), axis=idx.ndim).astype(
-        table.dtype)
-
-
-def _col(table, cols, small=True):
-    """``table[:, cols]`` of a stored bank table with the table's rows
-    last: (..., banks), for in-range ``cols``. By gather from a table in
-    rows of lanes, else by one-hot: a contraction where the values are
-    ``small`` whole numbers, else compare and select."""
-    # static: the stored form and a python flag
-    if table.ndim == 3:
-        # one cell a gathered element: a gather whose window spans the
-        # banks has the compiler lay the whole table out again first
-        return cells(table, jnp.arange(table.shape[0]), cols[..., None])
-    hit = _onehot(cols, table.shape[1])
-    if small:  # analysis: tracer-branch
-        return _count("...r,xr->...x", hit,
-                      table.astype(jnp.float32)).astype(table.dtype)
-    return jnp.sum(jnp.where(hit[..., None, :] > 0, table, 0),
-                   axis=-1).astype(table.dtype)
-
-
-def _cell(table, rows, cols, small=True):
-    """``table[rows, cols]`` of a stored bank table for in-range indices:
-    the columns by ``_col`` and the row by compare and select, or, from a
-    table in rows of lanes where that gathers fewer cells, by gather."""
-    # static: the cells each way would gather, counted from the shapes
-    n_cells = math.prod(jnp.broadcast_shapes(rows.shape, cols.shape))
-    by_cell = table.ndim == 3 and n_cells <= cols.size * table.shape[0]
-    if by_cell:  # analysis: tracer-branch
-        return cells(table, rows, cols)
-    at_cols = _col(table, cols, small)
-    hit = rows[..., None] == jnp.arange(table.shape[0])
-    return jnp.sum(jnp.where(hit, at_cols, 0), axis=-1).astype(table.dtype)
-
-
-def _put(table, rows, cols, vals, mask, small=True):
-    """``table`` with ``table[rows, cols] = vals`` where ``mask`` (indices
-    in range; a cell written more than once gets equal values). By
-    scatter into a table in rows of lanes, else by one-hot: a
-    contraction where the values are ``small`` whole numbers, else
-    compare and select."""
-    rows, cols, vals, mask = (jnp.broadcast_to(a, mask.shape).reshape(-1)
-                              for a in (rows, cols, vals, mask))
-    if table.ndim == 3:
-        return set_cells(table, jnp.where(mask, rows, table.shape[0]), cols,
-                         vals.astype(table.dtype))
-    on_row = _onehot(jnp.where(mask, rows, -1), table.shape[0])
-    on_col = _onehot(cols, table.shape[1])
-    if small:  # analysis: tracer-branch
-        copies = _count("nx,nr->xr", on_row, on_col)
-        total = _count("nx,nr->xr", on_row * vals[:, None], on_col)
-        return jnp.where(copies > 0, total / jnp.maximum(copies, 1),
-                         table).astype(table.dtype)
-    hit = (on_row[:, :, None] * on_col[:, None, :]) > 0        # (n, x, r)
-    new = jnp.max(jnp.where(hit, vals[:, None, None], jnp.iinfo(
-        jnp.int32).min), axis=0)
-    return jnp.where(jnp.any(hit, axis=0), new, table).astype(table.dtype)
-
-
-def _compact(table, n_slots, mask, *cols):
-    """The writes into ``table`` where ``mask`` holds, as (mask, each of
-    ``cols``) to write from. Into a table kept in rows of lanes they are
-    compacted, first to last, into ``n_slots`` slots: its scatter costs
-    each update it is given, kept or dropped. An entry past the last slot
-    is left out: the caller proves that there are none. A rank from a
-    cumulative sum and compare and select over the (slots, entries) grid,
-    since an index gather or scatter here would be such an op itself. A
-    table indexed by one-hot, whose write costs its width, takes them as
-    they are: there, on a v5e, the compactions cost a 512-row cycle more
-    than the smaller writes saved."""
-    # static: the stored form of the table
-    if table.ndim != 3:
-        return (mask,) + cols
-    rank = jnp.cumsum(mask, dtype=jnp.int32) - 1
-    on = mask & (rank == jnp.arange(n_slots, dtype=jnp.int32)[:, None])
-
-    def of_slot(x):
-        s = on.reshape(on.shape + (1,) * (x.ndim - 1))
-        return jnp.sum(jnp.where(s, x[None], 0), axis=1).astype(x.dtype)
-
-    return (jnp.any(on, axis=1),) + tuple(of_slot(x) for x in cols)
 
 
 def build_read_pattern(
@@ -434,32 +357,20 @@ def build_read_pattern(
     import jax
 
     n = cand_bank.shape[0]
-    rs = p.region_size
-    rs_a = rs if rs_active is None else rs_active
     rank, n_trips = _walk_bounds(cand_age, cand_valid)
     nop = jnp.int32(p.n_ports)
     oob = jnp.int32(p.n_data)
     K = MAX_OPTS
 
     # ---- per-candidate tables, looked up once (read state is loop-invariant)
-    b = jnp.maximum(cand_bank, 0)
-    i = jnp.maximum(cand_row, 0)
-    fl = _cell(fresh_loc, b, i)
-    slot = _take(region_slot, i // rs_a)
-    coded = slot >= 0
-    pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    hold_port = _take(t.par_port, jnp.maximum(fl - 1, 0))
-    # a negative port id (scheme with no parities) points the claim at the
-    # dummy sink slot, where the lookup of the padded -1 lands
+    loc = locate(p, t, region_slot, cand_bank, cand_row, rs_active)
+    b, i, optj, opt_pport = loc.b, loc.i, loc.optj, loc.pport
+    fl = bank_cell(fresh_loc, b, i)
+    hold_port = take(t.par_port, jnp.maximum(fl - 1, 0))
     hold_idx = jnp.where(hold_port < 0, nop, hold_port)
-    optj = _take(t.opt_parity, b)             # (N, K)
-    optjj = jnp.maximum(optj, 0)
-    opt_pv = ((optj >= 0) & coded[:, None]
-              & _cell(parity_valid, optjj, pr[:, None]))
-    opt_pport = _take(t.par_port, optjj)
-    opt_pport = jnp.where(opt_pport < 0, nop, opt_pport)
-    sibs = _take(t.opt_sibs, b)
-    s0, s1 = sibs[:, :, 0], sibs[:, :, 1]
+    opt_pv = ((optj >= 0) & loc.coded[:, None]
+              & bank_cell(parity_valid, loc.optjj, loc.pr[:, None]))
+    s0, s1 = loc.sibs[:, :, 0], loc.sibs[:, :, 1]
     may_serve = cand_valid & (fl == 0)
     # The banks and ports a candidate's scoring looks up; the walk keeps
     # their state per candidate. ``have``: the bank of each column has the
@@ -474,7 +385,7 @@ def build_read_pattern(
         b=b, i=i, may=may_serve, can_rd=cand_valid & (fl > 0), hold=hold_idx,
         opt_may=may_serve[:, None] & opt_pv, pport=opt_pport, s0=s0, s1=s1,
         have=jnp.zeros(sym_bank.shape, bool),
-        busy=_take(port_busy, port_ix),
+        busy=take(port_busy, port_ix),
         mode=jnp.full((n,), MODE_UNSERVED, jnp.int32),
         claim=jnp.full((n, 4), nop, jnp.int32),
     ), keys=dict(have=sym_bank, busy=port_ix))
@@ -559,7 +470,17 @@ def build_read_pattern(
     n_degraded = jnp.sum(
         served & ((mode == MODE_FROM_SYM) | ((mode >= MODE_OPT0) & (mode < MODE_REDIRECT)))
     ).astype(jnp.int32)
-    return ReadPlan(served, mode, port_busy, n_served, n_degraded)
+    # the address each served read decodes from: its chosen option's
+    # parity and siblings, or the parity holding its parked value
+    opt_sel = (jnp.arange(K, dtype=jnp.int32)
+               == (mode - MODE_OPT0)[:, None])                     # (N, K)
+    is_opt = jnp.any(opt_sel, axis=1)
+    par = jnp.where(mode == MODE_REDIRECT, fl - 1, jnp.where(
+        is_opt, jnp.sum(jnp.where(opt_sel, optj, 0), axis=1), -1))
+    sibs = jnp.where(is_opt[:, None], jnp.sum(
+        jnp.where(opt_sel[:, :, None], loc.sibs, 0), axis=1), -1)
+    return ReadPlan(served, mode, port_busy, n_served, n_degraded, par,
+                    loc.pr, sibs)
 
 
 def build_write_pattern(
@@ -583,24 +504,14 @@ def build_write_pattern(
     import jax
 
     n = cand_bank.shape[0]
-    rs = p.region_size
-    rs_a = rs if rs_active is None else rs_active
     rank, n_trips = _walk_bounds(cand_age, cand_valid)
     nop = jnp.int32(p.n_ports)
     K = MAX_OPTS
 
     # ---- per-candidate tables, looked up once ---------------------------
-    b = jnp.maximum(cand_bank, 0)
-    i = jnp.maximum(cand_row, 0)
-    region = i // rs_a
-    slot = _take(region_slot, region)
-    coded = slot >= 0
-    pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    optj = _take(t.opt_parity, b)             # (N, K)
-    optjj = jnp.maximum(optj, 0)
-    opt_pport = _take(t.par_port, optjj)
-    opt_pport = jnp.where(opt_pport < 0, nop, opt_pport)
-    mem = _take(t.par_members, optjj)         # (N, K, MAX_SIBS+1)
+    loc = locate(p, t, region_slot, cand_bank, cand_row, rs_active)
+    b, i, coded, pr = loc.b, loc.i, loc.coded, loc.pr
+    optj, optjj, opt_pport, mem = loc.optj, loc.optjj, loc.pport, loc.mem
     memc = jnp.maximum(mem, 0)
     park_base = 2 + jnp.arange(K, dtype=jnp.int32)
     # ---- degraded-write mode (``down`` = currently-down data banks).
@@ -620,8 +531,8 @@ def build_write_pattern(
         park_score = jnp.broadcast_to(park_base, (n, K))
     else:
         opt_down = jnp.any((mem >= 0) & (mem != b[:, None, None])
-                           & _take(down, memc), axis=2)      # (N, K)
-        sticky = _take(down, b) | jnp.any(
+                           & take(down, memc), axis=2)       # (N, K)
+        sticky = take(down, b) | jnp.any(
             (optj >= 0) & coded[:, None] & opt_down, axis=1)
         dir_score = jnp.where(sticky, 2 + 2 * MAX_OPTS + 2, 1)
         park_score = park_base + jnp.where(opt_down, MAX_OPTS + 2, 0)
@@ -640,11 +551,11 @@ def build_write_pattern(
     n_free = jnp.sum(free).astype(jnp.int32)
     w = _Walk(dict(
         b=b, i=i, valid=cand_valid, dir_score=dir_score, sticky=sticky,
-        need_rc_dir=coded & (_take(t.opt_n, b) > 0),
+        need_rc_dir=coded & (take(t.opt_n, b) > 0),
         park_possible=cand_valid[:, None] & (optj >= 0) & coded[:, None],
         pport=opt_pport, optjj=optjj, park_score=park_score, mem=mem,
-        busy=_take(port_busy, port_ix),
-        fl=_cell(fresh_loc, jnp.maximum(fl_bank, 0), i[:, None]),
+        busy=take(port_busy, port_ix),
+        fl=bank_cell(fresh_loc, jnp.maximum(fl_bank, 0), i[:, None]),
         pend=jnp.any(rc_valid & (rc_bank == b[:, None])
                      & (rc_row == i[:, None]), axis=1),
         mode=jnp.full((n,), WMODE_UNSERVED, jnp.int32),
@@ -729,21 +640,17 @@ def build_write_pattern(
     # from as many slots as ``write_slots`` proves a cycle serves
     slots = write_slots(p)
     served = mode_w != WMODE_UNSERVED
-    ok, b_s, i_s, fl_s = _compact(fresh_loc, slots.fresh_loc, served, b, i,
-                                  w.get(table, "fl")[:, 0])
-    fresh_loc = _put(fresh_loc, b_s, i_s, fl_s, ok)
+    fresh_loc = bank_put(fresh_loc, b, i, w.get(table, "fl")[:, 0], served,
+                         n_slots=slots.fresh_loc)
     parked_count = parked_count + jnp.sum(
-        jnp.where(region[:, None] == jnp.arange(parked_count.shape[0]),
+        jnp.where(loc.region[:, None] == jnp.arange(parked_count.shape[0]),
                   w.get(table, "delta")[:, None], 0), axis=0)
     # parity invalidation
     inv = ((optj >= 0) & coded[:, None]
            & (is_dir[:, None]
               | (is_park[:, None] & (optjj == j_sel[:, None]))))
-    ok, j_s, pr_s = _compact(parity_valid, slots.parity_valid,
-                             inv.reshape(-1), optjj.reshape(-1),
-                             jnp.broadcast_to(pr[:, None], inv.shape)
-                             .reshape(-1))
-    parity_valid = _put(parity_valid, j_s, pr_s, False, ok)
+    parity_valid = bank_put(parity_valid, optjj, pr[:, None], False, inv,
+                            n_slots=slots.parity_valid)
     # the t-th push of the walk fills the t-th free slot of the ring
     slot_rank = jnp.where(free, jnp.cumsum(free) - 1, -2)
     hit = slot_rank[:, None] == w.get(table, "push")[None, :]   # (cap, N)
@@ -757,19 +664,4 @@ def build_write_pattern(
     n_parked = jnp.sum(is_park).astype(jnp.int32)
     return WritePlan(served, mode_w, port_busy, fresh_loc, parity_valid,
                      parked_count, rc_bank, rc_row, rc_valid, n_served,
-                     n_parked, dropped)
-
-
-def _rc_push(rc_bank, rc_row, rc_valid, b, i, do):
-    """Push (b, i) into the recode ring unless present; returns ok flag.
-    The first free slot is taken by a one-hot select, not a scatter."""
-    dup = jnp.any(rc_valid & (rc_bank == b) & (rc_row == i))
-    free = ~rc_valid
-    has_free = jnp.any(free)
-    first = jnp.arange(free.shape[0]) == jnp.argmax(free)  # first free slot
-    put = first & do & ~dup & has_free
-    rc_bank = jnp.where(put, b, rc_bank)
-    rc_row = jnp.where(put, i, rc_row)
-    rc_valid = rc_valid | put
-    ok = dup | has_free
-    return rc_bank, rc_row, rc_valid, ok
+                     n_parked, dropped, jnp.where(is_park, j_sel, -1), pr)

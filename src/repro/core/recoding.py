@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.codes import MAX_OPTS, MAX_SIBS
-from repro.core.controller import JTables, _cell, _col, _put, _take
-from repro.core.state import MemParams
+from repro.core.controller import JTables, locate
+from repro.core.state import MemParams, bank_cell, bank_col, bank_put, take
 
 
 class RecodeOut(NamedTuple):
@@ -72,7 +72,7 @@ def recode_step(
     The walk carries only what its decisions read, per entry: the
     freshness of every bank at the entry's row and the validity of each
     of its options' parities at its parity row, looked up once before it
-    (``_col``, ``_cell``). A
+    (``state.bank_col``, ``state.bank_cell``). A
     retirement updates the copies of the cells it writes, by compare and
     select, and records itself; the data it moves (the restored row, the
     recomputed parities, which decide nothing) and the state it leaves are
@@ -89,20 +89,11 @@ def recode_step(
     ring forever; the rebuild sweep re-enqueues their cells once the bank
     recovers.
     """
-    rs = p.region_size
-    rs_a = rs if rs_active is None else rs_active
     cap = rc_valid.shape[0]
     n_rec = p.recode_budget
-    b = jnp.maximum(rc_bank, 0)                 # (E,)
-    i = jnp.maximum(rc_row, 0)
-    region = i // rs_a
-    slot = _take(region_slot, region)
-    coded = slot >= 0
-    pr = jnp.maximum(slot, 0) * rs + i % rs_a
-    optj = _take(t.opt_parity, b)               # (E, K)
-    optjj = jnp.maximum(optj, 0)
-    opt_pport = _take(t.par_port, optjj)
-    mem = _take(t.par_members, optjj)           # (E, K, MAX_SIBS+1)
+    loc = locate(p, t, region_slot, rc_bank, rc_row, rs_active)   # (E, ...)
+    b, i, coded, pr = loc.b, loc.i, loc.coded, loc.pr
+    optj, optjj, mem = loc.optj, loc.optjj, loc.mem
     memc = jnp.maximum(mem, 0)
     epos = jnp.arange(cap, dtype=jnp.int32)
     banks = jnp.arange(p.n_data, dtype=jnp.int32)
@@ -116,8 +107,8 @@ def recode_step(
         # fault-blocking is loop-invariant: down membership doesn't change
         # within a cycle
         blocked_f = jnp.any((mem >= 0) & (mem != b[:, None, None])
-                            & _take(down, memc), axis=2)     # (E, K)
-        self_down = _take(down, b)                           # (E,)
+                            & take(down, memc), axis=2)      # (E, K)
+        self_down = take(down, b)                            # (E,)
 
     def cond(carry):
         cursor, budget = carry[0], carry[1]
@@ -152,9 +143,9 @@ def recode_step(
         rc_k = recompute & work[:, None]
         needed_idx = jnp.concatenate([
             jnp.where(work, b, nsink)[:, None],
-            jnp.where(work & parked, _take(t.par_port, holder),
+            jnp.where(work & parked, take(t.par_port, holder),
                       nsink)[:, None],
-            jnp.where(rc_k, opt_pport, nsink),
+            jnp.where(rc_k, loc.pport, nsink),
             jnp.where(rc_k[:, :, None] & (mem >= 0), memc,
                       nsink).reshape(cap, -1),
         ], axis=1)
@@ -205,7 +196,8 @@ def recode_step(
 
     no_k = jnp.zeros((n_rec, MAX_OPTS), bool)
     carry = (jnp.int32(-1), jnp.int32(p.recode_budget), port_busy,
-             _col(fresh_loc, i), _cell(parity_valid, optjj, pr[:, None]),
+             bank_col(fresh_loc, i),
+             bank_cell(parity_valid, optjj, pr[:, None]),
              rc_valid,
              jnp.full((n_rec,), -1, jnp.int32), no_k, no_k,
              jnp.zeros((n_rec,), jnp.int32))
@@ -225,8 +217,8 @@ def recode_step(
     r_parked = done & (rec_fl > 0)
     # a parked row comes back from its parity row, which no earlier
     # retirement this cycle recomputed (the parked value blocks it)
-    restored = _cell(parity_data, jnp.maximum(rec_fl - 1, 0), rpr,
-                     small=False)
+    restored = bank_cell(parity_data, jnp.maximum(rec_fl - 1, 0), rpr,
+                         small=False)
     # each member's value as the retirement saw it: restored by an earlier
     # (or this) retirement of the same cell, else as stored
     earlier = jnp.arange(n_rec)[None, :] <= jnp.arange(n_rec)[:, None]
@@ -236,7 +228,7 @@ def recode_step(
     seen = jnp.where(jnp.any(by, axis=1),
                      jnp.sum(jnp.where(by, restored[None, :, None], 0),
                              axis=1),
-                     _col(banks_data, ri, small=False))      # (R, n_data)
+                     bank_col(banks_data, ri, small=False))  # (R, n_data)
     at_mem = jnp.maximum(rmem, 0)[..., None] == banks       # (R, K, S, n)
     member = jnp.sum(jnp.where(at_mem, seen[:, None, None, :], 0), axis=3)
     val = jnp.zeros(rj.shape, jnp.int32)
@@ -253,15 +245,34 @@ def recode_step(
                                & mask[None, None], axis=(2, 3))
 
     rows = rpr[:, None]
-    parity_data = _put(parity_data, rj, rows, val, last(rec_do), small=False)
-    parity_valid = _put(parity_valid, rj, rows, rec_do,
-                        last(rec_do | rec_inv))
-    banks_data = _put(banks_data, rb, ri, restored, r_parked, small=False)
-    fresh_loc = _put(fresh_loc, rb, ri, jnp.int32(0), done)
+    parity_data = bank_put(parity_data, rj, rows, val, last(rec_do),
+                           small=False)
+    parity_valid = bank_put(parity_valid, rj, rows, rec_do,
+                            last(rec_do | rec_inv))
+    banks_data = bank_put(banks_data, rb, ri, restored, r_parked,
+                          small=False)
+    fresh_loc = bank_put(fresh_loc, rb, ri, jnp.int32(0), done)
     parked_count = parked_count - jnp.sum(
         r_parked[:, None]
-        & (of_rec(region)[:, None] == jnp.arange(parked_count.shape[0])),
+        & (of_rec(loc.region)[:, None]
+           == jnp.arange(parked_count.shape[0])),
         axis=0, dtype=jnp.int32)
     return RecodeOut(port_busy, fresh_loc, parity_valid, parked_count,
                      rc_valid, banks_data, parity_data,
                      jnp.int32(p.recode_budget) - budget)
+
+
+def rc_push(rc_bank, rc_row, rc_valid, b, i, do):
+    """Push (b, i) into the recode ring where ``do``, unless present;
+    returns the ring and an ok flag (present, or a slot was free). The
+    first free slot is taken by a one-hot select, not a scatter."""
+    dup = jnp.any(rc_valid & (rc_bank == b) & (rc_row == i))
+    free = ~rc_valid
+    has_free = jnp.any(free)
+    first = jnp.arange(free.shape[0]) == jnp.argmax(free)  # first free slot
+    put = first & do & ~dup & has_free
+    rc_bank = jnp.where(put, b, rc_bank)
+    rc_row = jnp.where(put, i, rc_row)
+    rc_valid = rc_valid | put
+    ok = dup | has_free
+    return rc_bank, rc_row, rc_valid, ok

@@ -23,6 +23,7 @@ Dynamic coding (§IV-E): rows are grouped into ``n_regions`` regions of
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -284,14 +285,39 @@ def make_params(
 # ------------------------------------------------------------ bank tables
 # The tables a bank wide (``fresh_loc``, ``parity_valid``, the data) are
 # logically (banks, width). Up to ``ONEHOT_MAX_COLS`` columns the state
-# keeps them so and the controller indexes them by one-hot (see the note
-# in ``core/controller.py``). Wider, it keeps them in rows of ``LANES``:
-# (banks, ceil(width / LANES), LANES), the tail padded. A TPU scatter
-# addresses its operand as one flat run of elements; a (banks, width) array
-# tiled (8, 128) is not one, so the compiler relays the whole array out and
-# back around each scatter into it (at 65,536 rows, ~1 ms or more of a
-# cycle on a v5e), while rows of 128 lanes already are one. The helpers
-# below index either form; which one a table is follows from its shape.
+# keeps them so and they are indexed by one-hot. Wider, it keeps them in
+# rows of ``LANES``: (banks, ceil(width / LANES), LANES), the tail padded,
+# and they are indexed by gather and scatter. A TPU scatter addresses its
+# operand as one flat run of elements; a (banks, width) array tiled
+# (8, 128) is not one, so the compiler relays the whole array out and back
+# around each scatter into it (at 65,536 rows, ~1 ms or more of a cycle on
+# a v5e), while rows of 128 lanes already are one. This module alone knows
+# the two forms: which one a table is follows from its shape, and the
+# helpers below read and write either.
+#
+# Why two forms. Indexing by traced indices compiles to gathers and
+# scatters, which a TPU runs element by element: on a v5e a batched gather
+# of 3,200 elements took 25-38 us, and a scatter sorts its keys first. A
+# one-hot lookup instead compares every index with every column, or
+# contracts 0/1 matrices with small whole numbers (exact in float32): work
+# that grows with the table's width, where a gather costs a cycle what its
+# requests touch, not what the memory holds. Measured on one TPU v5e: a
+# loop trip of 40 points, each looking up (or writing) 80 cells of an
+# (8, W) int32 table, in microseconds:
+#
+#       W     cell: one-hot / gather   write: one-hot / scatter
+#     512              9.8 / 49.3               14.3 / 26.5
+#   2,048             20.5 / 48.0               25.3 / 28.3
+#   8,192             64.1 / 46.8               73.2 / 36.1
+#  65,536            463.1 / 49.1              498.5 / 504.4
+#
+# The one-hot's cost is the table's width; the scatter's 504 us at 65,536
+# is the relayout of the whole (8, W) array that a TPU scatter makes, which
+# the rows of 128 lanes avoid. From a table in rows of lanes a gather costs
+# what it reads (same loop, 65,536 columns: 80 cells a point 52 us, 640
+# cells 396 us), so ``bank_cell`` gathers the fewer cells of its two ways.
+# The small tables (queues, ports, code tables, region maps) are always
+# indexed by compare and select (``take``).
 ONEHOT_MAX_COLS = 2048
 LANES = 128
 
@@ -318,13 +344,127 @@ def cells(table, rows, cols):
     return table[rows, cols]
 
 
-def set_cells(table, rows, cols, vals):
-    """``table`` with ``table[rows, cols] = vals`` by scatter; a row out of
-    range drops its write."""
+def set_cells(table, rows, cols, vals, mask, n_slots=None):
+    """``table`` with ``table[rows, cols] = vals`` where ``mask``, by
+    scatter (indices and values broadcast to ``mask``, columns in range,
+    a row out of range dropped; a cell written more than once gets equal
+    values). Given ``n_slots``, the writes into a table kept in rows of
+    lanes are first compacted into that many slots (``_compact``); a
+    table indexed by one-hot takes them as they are."""
+    def flat(a):
+        return jnp.broadcast_to(a, mask.shape).reshape(-1)
+
+    vals = jnp.asarray(vals).astype(table.dtype)
+    rows, cols, vals = flat(rows), flat(cols), flat(vals) if vals.ndim else vals
+    mask = mask.reshape(-1)
+    if n_slots is not None and table.ndim == 3:
+        mask, rows, cols, vals = _compact(n_slots, mask, rows, cols, vals)
+    rows = jnp.where(mask, rows, table.shape[0])
     if table.ndim == 3:
         return table.at[rows, cols // LANES, cols % LANES].set(
             vals, mode="drop")
     return table.at[rows, cols].set(vals, mode="drop")
+
+
+def _compact(n_slots, mask, *cols):
+    """The entries where ``mask`` holds, as (mask, each of ``cols``),
+    compacted first to last into ``n_slots`` slots: a scatter costs each
+    update it is given, kept or dropped. An entry past the last slot is
+    left out: the caller proves that there are none. A rank from a
+    cumulative sum and compare and select over the (slots, entries) grid,
+    since an index gather or scatter here would be such an op itself. A
+    scalar of ``cols`` is the same for every slot. Only writes into a table
+    in rows of lanes are compacted: into a table indexed by one-hot, whose
+    write costs its width, the compactions cost a 512-row cycle on a v5e
+    more than the smaller writes saved."""
+    rank = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    on = mask & (rank == jnp.arange(n_slots, dtype=jnp.int32)[:, None])
+
+    def of_slot(x):
+        if not x.ndim:
+            return x
+        s = on.reshape(on.shape + (1,) * (x.ndim - 1))
+        return jnp.sum(jnp.where(s, x[None], 0), axis=1).astype(x.dtype)
+
+    return (jnp.any(on, axis=1),) + tuple(of_slot(x) for x in cols)
+
+
+def _onehot(idx, n):
+    """(..., n) float32 one-hot rows of ``idx`` (all zero out of range)."""
+    return (idx[..., None] == jnp.arange(n)).astype(jnp.float32)
+
+
+def _count(spec, *operands):
+    """``einsum`` of one-hots and small whole numbers, exact in float32."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
+
+
+def take(table, idx):
+    """``table[idx]`` for a small table and integer ``idx`` of any shape,
+    as indexing does it (a negative index counts from the end, the rest
+    clamp), by compare and select over the table's rows."""
+    size = table.shape[0]
+    idx = jnp.clip(jnp.where(idx < 0, idx + size, idx), 0, size - 1)
+    hit = idx[..., None] == jnp.arange(size)
+    hit = hit.reshape(hit.shape + (1,) * (table.ndim - 1))
+    return jnp.sum(jnp.where(hit, table, 0), axis=idx.ndim).astype(
+        table.dtype)
+
+
+def bank_col(table, cols, small=True):
+    """``table[:, cols]`` of a stored bank table with the table's rows
+    last: (..., banks), for in-range ``cols``. By gather from a table in
+    rows of lanes, else by one-hot: a contraction where the values are
+    ``small`` whole numbers, else compare and select."""
+    # static: the stored form and a python flag
+    if table.ndim == 3:
+        # one cell a gathered element: a gather whose window spans the
+        # banks has the compiler lay the whole table out again first
+        return cells(table, jnp.arange(table.shape[0]), cols[..., None])
+    hit = _onehot(cols, table.shape[1])
+    if small:  # analysis: tracer-branch
+        return _count("...r,xr->...x", hit,
+                      table.astype(jnp.float32)).astype(table.dtype)
+    return jnp.sum(jnp.where(hit[..., None, :] > 0, table, 0),
+                   axis=-1).astype(table.dtype)
+
+
+def bank_cell(table, rows, cols, small=True):
+    """``table[rows, cols]`` of a stored bank table for in-range indices:
+    the columns by ``bank_col`` and the row by compare and select, or,
+    from a table in rows of lanes where that gathers fewer cells, by
+    gather."""
+    # static: the cells each way would gather, counted from the shapes
+    n_cells = math.prod(jnp.broadcast_shapes(rows.shape, cols.shape))
+    by_cell = table.ndim == 3 and n_cells <= cols.size * table.shape[0]
+    if by_cell:  # analysis: tracer-branch
+        return cells(table, rows, cols)
+    at_cols = bank_col(table, cols, small)
+    hit = rows[..., None] == jnp.arange(table.shape[0])
+    return jnp.sum(jnp.where(hit, at_cols, 0), axis=-1).astype(table.dtype)
+
+
+def bank_put(table, rows, cols, vals, mask, small=True, n_slots=None):
+    """``table`` with ``table[rows, cols] = vals`` where ``mask`` (indices
+    in range; a cell written more than once gets equal values). By
+    scatter into a table in rows of lanes (``set_cells``, compacted into
+    ``n_slots`` slots where given), else by one-hot: a contraction where
+    the values are ``small`` whole numbers, else compare and select."""
+    if table.ndim == 3:
+        return set_cells(table, rows, cols, vals, mask, n_slots)
+    rows, cols, vals, mask = (jnp.broadcast_to(a, mask.shape).reshape(-1)
+                              for a in (rows, cols, vals, mask))
+    on_row = _onehot(jnp.where(mask, rows, -1), table.shape[0])
+    on_col = _onehot(cols, table.shape[1])
+    if small:  # analysis: tracer-branch
+        copies = _count("nx,nr->xr", on_row, on_col)
+        total = _count("nx,nr->xr", on_row * vals[:, None], on_col)
+        return jnp.where(copies > 0, total / jnp.maximum(copies, 1),
+                         table).astype(table.dtype)
+    hit = (on_row[:, :, None] * on_col[:, None, :]) > 0        # (n, x, r)
+    new = jnp.max(jnp.where(hit, vals[:, None, None], jnp.iinfo(
+        jnp.int32).min), axis=0)
+    return jnp.where(jnp.any(hit, axis=0), new, table).astype(table.dtype)
 
 
 def _lane_rows(table, start, size):

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import controller as ctl
-from repro.core.codes import MAX_OPTS, MAX_SIBS, CodeTables
+from repro.core.codes import MAX_SIBS, CodeTables
 from repro.core.dynamic import dynamic_step
 from repro.core.recoding import recode_step
 from repro.core.state import (MemParams, MemState, TunableParams,
@@ -282,8 +282,9 @@ class CodedMemorySystem:
         wq_age = m.wq_age.at[bw, slot_w].set(cyc, mode="drop")
         wq_valid = m.wq_valid.at[bw, slot_w].set(True, mode="drop")
         wq_data = m.wq_data.at[bw, slot_w].set(payload, mode="drop")
+        region = ctl.locate(p, self.t, m.region_slot, b, i, rs_a).region
         access_count = m.access_count.at[
-            jnp.where(push, i // rs_a, p.n_regions)].add(1, mode="drop")
+            jnp.where(push, region, p.n_regions)].add(1, mode="drop")
         stalls = wide_add(m.stall_cycles, jnp.sum(v & full))
         ptr = pos + (in_range & (push | ~v)).astype(jnp.int32)
 
@@ -312,35 +313,24 @@ class CodedMemorySystem:
         return st._replace(mem=mem, core_ptr=ptr)
 
     # ----------------------------------------------------------- read values
-    def _read_values(self, m: MemState, plan: ctl.ReadPlan, cb, ci, rs_a):
-        """Vectorized XOR-decode datapath for the served reads."""
-        p, t = self.p, self.t
-        rs = p.region_size
+    def _read_values(self, m: MemState, plan: ctl.ReadPlan, cb, ci):
+        """Vectorized XOR-decode datapath for the served reads, at the
+        address the plan served: a direct (or chained) read's data cell, a
+        redirect's parked value, or a degraded read's parity cell XOR its
+        siblings' data cells."""
         b = jnp.maximum(cb, 0)
         i = jnp.maximum(ci, 0)
-        slot = m.region_slot[i // rs_a]
-        pr = jnp.maximum(slot, 0) * rs + i % rs_a
-        direct_val = cells(m.banks_data, b, i)
-        fl = cells(m.fresh_loc, b, i)
-        holder = jnp.maximum(fl - 1, 0)
-        redirect_val = cells(m.parity_data, holder, pr)
-        k = jnp.clip(plan.mode - ctl.MODE_OPT0, 0, MAX_OPTS - 1)
-        j = jnp.maximum(t.opt_parity[b, k], 0)
-        dec = cells(m.parity_data, j, pr)
+        dec = cells(m.parity_data, jnp.maximum(plan.par, 0), plan.prow)
         for mm in range(MAX_SIBS):
-            s = t.opt_sibs[b, k, mm]
+            s = plan.sibs[:, mm]
             dec = dec ^ jnp.where(
                 s >= 0, cells(m.banks_data, jnp.maximum(s, 0), i), 0)
-        val = jnp.where(
-            plan.mode == ctl.MODE_REDIRECT, redirect_val,
-            jnp.where((plan.mode >= ctl.MODE_OPT0) & (plan.mode < ctl.MODE_REDIRECT),
-                      dec, direct_val),
-        )
+        val = jnp.where(plan.par >= 0, dec, cells(m.banks_data, b, i))
         return jnp.where(plan.served, val, 0)
 
     # ------------------------------------------------------- write datapath
     def _commit_writes(self, m: MemState, plan: ctl.WritePlan, cb, ci_, ca,
-                       cd, rs_a):
+                       cd):
         """Commit served write payloads in age order (last write wins).
 
         Vectorized: rather than walking candidates in a fori_loop, each
@@ -350,10 +340,9 @@ class CodedMemorySystem:
         Into a table kept in rows of lanes, the cells that land are
         compacted into as many slots as ``ctl.write_slots`` proves a cycle
         writes, so its scatter is given those and not every candidate of
-        the queues.
+        the queues. A parked write lands at the parity and row its plan
+        parked it in.
         """
-        p, t = self.p, self.t
-        rs = p.region_size
         b = jnp.maximum(cb, 0)
         i = jnp.maximum(ci_, 0)
         idx = jnp.arange(cb.shape[0], dtype=jnp.int32)
@@ -361,13 +350,9 @@ class CodedMemorySystem:
         younger = ((ca[None, :] > ca[:, None])
                    | ((ca[None, :] == ca[:, None])
                       & (idx[None, :] > idx[:, None])))
-        slot = m.region_slot[i // rs_a]
-        pr = jnp.maximum(slot, 0) * rs + i % rs_a
-        kk = jnp.clip(plan.mode - ctl.WMODE_PARK0, 0, MAX_OPTS - 1)
-        j = jnp.maximum(t.opt_parity[b, kk], 0)
         is_dir = plan.served & (plan.mode == ctl.WMODE_DIRECT)
         is_park = plan.served & (plan.mode >= ctl.WMODE_PARK0)
-        slots = ctl.write_slots(p)
+        slots = ctl.write_slots(self.p)
 
         def commit(table, n_slots, mask, rows, cols):
             # no younger write in ``mask`` lands on the same cell: compared
@@ -376,13 +361,11 @@ class CodedMemorySystem:
                      & (cols[None, :] == cols[:, None])
                      & mask[None, :] & younger)
             win = mask & ~jnp.any(later, axis=1)
-            ok, r_s, c_s, v_s = ctl._compact(table, n_slots, win, rows, cols,
-                                             cd)
-            return set_cells(table, jnp.where(ok, r_s, table.shape[0]), c_s,
-                             v_s)
+            return set_cells(table, rows, cols, cd, win, n_slots)
 
         return (commit(m.banks_data, slots.banks_data, is_dir, b, i),
-                commit(m.parity_data, slots.parity_data, is_park, j, pr),
+                commit(m.parity_data, slots.parity_data, is_park, plan.par,
+                       plan.prow),
                 commit(m.golden, slots.golden, plan.served, b, i))
 
     # ------------------------------------------------------------- one cycle
@@ -474,7 +457,7 @@ class CodedMemorySystem:
                 p, t, cb, ci_, ca, cv, port_busy0, m.fresh_loc, m.parity_valid,
                 m.region_slot, rs_a,
             )
-            vals = self._read_values(m, plan, cb, ci_, rs_a)
+            vals = self._read_values(m, plan, cb, ci_)
             lat = jnp.sum(jnp.where(plan.served, m.cycle - ca, 0))
             tele = m.tele
             if p.telemetry:
@@ -538,7 +521,7 @@ class CodedMemorySystem:
                 rs_a, down=down if p.faults else None,
             )
             banks_data, parity_data, golden = self._commit_writes(
-                m, plan, cb, ci_, ca, cd, rs_a)
+                m, plan, cb, ci_, ca, cd)
             lat = jnp.sum(jnp.where(plan.served, m.cycle - ca, 0))
             tele = m.tele
             if p.telemetry:

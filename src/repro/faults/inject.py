@@ -33,7 +33,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.controller import _rc_push
+from repro.core.controller import locate
+from repro.core.recoding import rc_push
 from repro.core.state import cells
 from repro.faults.plan import FaultState, NEVER
 
@@ -46,32 +47,21 @@ def drop_unservable(p, t, down_hard, rq_row, rq_valid, wq_row, wq_valid,
     predicate (no cross-candidate interaction), so the vectorized form is
     trivially order-independent and the oracle's loop matches it exactly.
     """
-    rs = p.region_size
     dq = p.queue_depth
     cb = jnp.repeat(jnp.arange(p.n_data, dtype=jnp.int32), dq)
 
-    def read_side(rows, valid):
-        i = jnp.maximum(rows.reshape(-1), 0)
-        slot = region_slot[i // rs_active]
-        coded = slot >= 0
-        pr = jnp.maximum(slot, 0) * rs + i % rs_active
-        optj = t.opt_parity[cb]                              # (N, K)
-        optjj = jnp.maximum(optj, 0)
-        opt_ok = ((optj >= 0) & coded[:, None]
-                  & cells(parity_valid, optjj, pr[:, None]))
-        sibs = t.opt_sibs[cb]                                # (N, K, S)
-        sib_dead = jnp.any((sibs >= 0) & down_hard[jnp.maximum(sibs, 0)],
-                           axis=2)
-        return valid.reshape(-1), i, coded, opt_ok & ~sib_dead
+    r = locate(p, t, region_slot, cb, rq_row.reshape(-1), rs_active)
+    opt_ok = ((r.optj >= 0) & r.coded[:, None]
+              & cells(parity_valid, r.optjj, r.pr[:, None]))    # (N, K)
+    sib_dead = jnp.any((r.sibs >= 0) & down_hard[jnp.maximum(r.sibs, 0)],
+                       axis=2)
+    drop_r = (rq_valid.reshape(-1) & down_hard[cb]
+              & (cells(fresh_loc, cb, r.i) == 0)
+              & ~jnp.any(opt_ok & ~sib_dead, axis=1))
 
-    rv, ri, _, viable = read_side(rq_row, rq_valid)
-    drop_r = (rv & down_hard[cb] & (cells(fresh_loc, cb, ri) == 0)
-              & ~jnp.any(viable, axis=1))
-
-    wv = wq_valid.reshape(-1)
-    wi = jnp.maximum(wq_row.reshape(-1), 0)
-    w_coded = region_slot[wi // rs_active] >= 0
-    drop_w = wv & down_hard[cb] & (~w_coded | (t.opt_n[cb] == 0))
+    w = locate(p, t, region_slot, cb, wq_row.reshape(-1), rs_active)
+    drop_w = (wq_valid.reshape(-1) & down_hard[cb]
+              & (~w.coded | (t.opt_n[cb] == 0)))
 
     return (rq_valid & ~drop_r.reshape(p.n_data, dq),
             wq_valid & ~drop_w.reshape(p.n_data, dq),
@@ -102,24 +92,19 @@ def rebuild_scan(p, t, fault: FaultState, cycle, rebuilding, down_hard,
     newly = jnp.any((fault.recover_at == cycle) & (fault.fail_at <= cycle)
                     & ~fault.rebuilt)
     ptr = jnp.where(newly, 0, fault.rebuild_ptr)
-    rs = p.region_size
 
     def body(_, carry):
         ptr, rc_bank, rc_row, rc_valid = carry
         cell = jnp.minimum(ptr, total - 1)
-        x = cell // p.n_rows
-        i = cell % p.n_rows
+        c = locate(p, t, region_slot, cell // p.n_rows, cell % p.n_rows,
+                   rs_active)
+        x, i = c.b, c.i
         in_range = any_rb & (ptr < total)
-        region = i // rs_active
-        in_geom = (region < nr_active) & (i % rs_active < rs_active)
-        slot = region_slot[jnp.minimum(region, region_slot.shape[0] - 1)]
-        coded = slot >= 0
-        pr = jnp.maximum(slot, 0) * rs + i % rs_active
-        optj = t.opt_parity[x]
-        stale = jnp.any((optj >= 0) & coded
-                        & ~cells(parity_valid, jnp.maximum(optj, 0), pr))
+        in_geom = c.region < nr_active
+        stale = jnp.any((c.optj >= 0) & c.coded
+                        & ~cells(parity_valid, c.optjj, c.pr))
         need = in_range & in_geom & ((cells(fresh_loc, x, i) > 0) | stale)
-        rc_bank, rc_row, rc_valid, ok = _rc_push(
+        rc_bank, rc_row, rc_valid, ok = rc_push(
             rc_bank, rc_row, rc_valid, x, i, need)
         advance = in_range & (~need | ok)
         return ptr + advance.astype(jnp.int32), rc_bank, rc_row, rc_valid
@@ -127,8 +112,8 @@ def rebuild_scan(p, t, fault: FaultState, cycle, rebuilding, down_hard,
     ptr, rc_bank, rc_row, rc_valid = jax.lax.fori_loop(
         0, p.recode_budget, body, (ptr, rc_bank, rc_row, rc_valid))
 
-    pending_park = jnp.any(jnp.any(fresh_loc > 0, axis=tuple(
-        range(1, fresh_loc.ndim))) & ~down_hard)
+    pending_park = jnp.any(jnp.any(
+        fresh_loc.reshape(fresh_loc.shape[0], -1) > 0, axis=1) & ~down_hard)
     complete = (ptr >= total) & ~jnp.any(rc_valid) & ~pending_park
     rebuilt = fault.rebuilt | (rebuilding & complete)
     return rc_bank, rc_row, rc_valid, fault._replace(

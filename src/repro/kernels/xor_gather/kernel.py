@@ -22,8 +22,8 @@ lanes IS the decode.
 
 Mode encoding matches repro.core.controller: 0 FROM_SYM, 1 DIRECT,
 2..2+MAX_OPTS-1 degraded options, 2+MAX_OPTS REDIRECT; -1 entries yield 0.
-Served requests carry in-range lane indices by contract (``plan_columns``
-clamps); -1 padding rows added by the wrapper select nothing.
+Served requests carry in-range lane indices by contract (the read plan's
+served address); -1 padding rows added by the wrapper select nothing.
 """
 from __future__ import annotations
 

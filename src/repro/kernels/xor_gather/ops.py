@@ -1,4 +1,4 @@
-"""Public wrapper: coded gather + the controller-plan → kernel-plan bridge."""
+"""Public wrapper: coded gather of one cycle's served reads."""
 from __future__ import annotations
 
 from typing import NamedTuple
@@ -6,14 +6,16 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.codes import MAX_OPTS, CodeTables
-from repro.core.controller import MODE_OPT0, MODE_REDIRECT, ReadPlan
-from repro.core.state import cells
 from repro.kernels.common import uint_view_dtype
 from repro.kernels.xor_gather.kernel import gather_decode_pallas
 
 
 class PlanColumns(NamedTuple):
+    """The kernel's per-request columns: a read's data cell, its mode
+    (-1 unserved), and the address it decodes from, as
+    ``core.controller.ReadPlan`` carries it (parity ``par`` at row
+    ``prow``, siblings ``sib0`` / ``sib1``, -1 where none)."""
+
     bank: jnp.ndarray
     row: jnp.ndarray
     mode: jnp.ndarray
@@ -21,36 +23,6 @@ class PlanColumns(NamedTuple):
     prow: jnp.ndarray
     sib0: jnp.ndarray
     sib1: jnp.ndarray
-
-
-def plan_columns(
-    tables: CodeTables,
-    plan: ReadPlan,
-    cand_bank: jnp.ndarray,
-    cand_row: jnp.ndarray,
-    region_slot: jnp.ndarray,
-    region_size: int,
-    fresh_loc: jnp.ndarray,
-) -> PlanColumns:
-    """Expand a controller ReadPlan into the kernel's per-request columns."""
-    b = jnp.maximum(cand_bank, 0)
-    i = jnp.maximum(cand_row, 0)
-    opt_parity = jnp.asarray(tables.opt_parity)
-    opt_sibs = jnp.asarray(tables.opt_sibs)
-    k = jnp.clip(plan.mode - MODE_OPT0, 0, MAX_OPTS - 1)
-    is_opt = (plan.mode >= MODE_OPT0) & (plan.mode < MODE_REDIRECT)
-    is_rd = plan.mode == MODE_REDIRECT
-    j_opt = opt_parity[b, k]
-    j_rd = jnp.maximum(cells(fresh_loc, b, i) - 1, 0)
-    par = jnp.where(is_opt, j_opt, jnp.where(is_rd, j_rd, 0))
-    slot = region_slot[i // region_size]
-    prow = jnp.maximum(slot, 0) * region_size + i % region_size
-    sib0 = jnp.where(is_opt, opt_sibs[b, k, 0], -1)
-    sib1 = jnp.where(is_opt, opt_sibs[b, k, 1], -1)
-    mode = jnp.where(plan.served, plan.mode, -1)
-    return PlanColumns(b.astype(jnp.int32), i.astype(jnp.int32), mode,
-                       par.astype(jnp.int32), prow.astype(jnp.int32),
-                       sib0.astype(jnp.int32), sib1.astype(jnp.int32))
 
 
 def gather_decode(

@@ -1,13 +1,13 @@
-"""The stored form of the bank-wide tables (``core/state.py``): kept as
-(banks, width) up to ``ONEHOT_MAX_COLS`` columns, in rows of 128 lanes
-above it; every helper reads and writes the same cells in either form."""
+"""The stored form of the bank-wide tables (``core/state.py``, the one
+module that knows it): kept as (banks, width) up to ``ONEHOT_MAX_COLS``
+columns, in rows of 128 lanes above it; every helper reads and writes the
+same cells in either form."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import state
-from repro.core.controller import _cell, _col, _put
 
 
 @pytest.mark.parametrize("width", [200, state.ONEHOT_MAX_COLS,
@@ -27,14 +27,16 @@ def test_helpers_match_the_logical_table(width):
     cols = jnp.asarray(rng.integers(0, width, 40), jnp.int32)
     np.testing.assert_array_equal(state.cells(stored, rows, cols),
                                   logical[rows, cols])
-    np.testing.assert_array_equal(_cell(stored, rows, cols),
-                                  logical[rows, cols])
-    np.testing.assert_array_equal(_col(stored, cols), logical[:, cols].T)
+    for small in (True, False):
+        np.testing.assert_array_equal(
+            state.bank_cell(stored, rows, cols, small), logical[rows, cols])
+        np.testing.assert_array_equal(state.bank_col(stored, cols, small),
+                                      logical[:, cols].T)
 
     vals = jnp.arange(40, dtype=jnp.int32) + 5000
     want = logical.copy()
     keep = np.asarray(rows) % 3 > 0
-    # a cell written twice takes equal values, as ``_put`` requires
+    # a cell written twice takes equal values, as ``bank_put`` requires
     first = {}
     for r, c, v, k in zip(np.asarray(rows), np.asarray(cols),
                           np.asarray(vals), keep):
@@ -44,11 +46,28 @@ def test_helpers_match_the_logical_table(width):
                         zip(np.asarray(rows), np.asarray(cols))], jnp.int32)
     for (r, c), v in first.items():
         want[r, c] = v
-    got = _put(stored, rows, cols, v_eq, jnp.asarray(keep))
+    # by one-hot (either way) or scatter, and into a table in rows of
+    # lanes from exactly as many slots as there are writes (``_compact``)
+    n_kept = int(keep.sum())
+    mask = jnp.asarray(keep)
+    for got in (state.bank_put(stored, rows, cols, v_eq, mask),
+                state.bank_put(stored, rows, cols, v_eq, mask, small=False),
+                state.bank_put(stored, rows, cols, v_eq, mask,
+                               n_slots=n_kept),
+                state.set_cells(stored, rows, cols, v_eq, mask),
+                state.set_cells(stored, rows, cols, v_eq, mask,
+                                n_slots=n_kept)):
+        np.testing.assert_array_equal(
+            state.bank_view(np.asarray(got), width), want)
+    drop = jnp.where(mask, rows, 12)
+    got = state.set_cells(stored, drop, cols, v_eq, jnp.ones(40, bool))
     np.testing.assert_array_equal(state.bank_view(np.asarray(got), width),
                                   want)
-    drop = jnp.where(jnp.asarray(keep), rows, 12)
-    got = state.set_cells(stored, drop, cols, v_eq)
+    # one value for every write, and (rows, columns) broadcast to the mask
+    want = logical.copy()
+    want[np.asarray(rows)[keep], np.asarray(cols)[keep]] = 7
+    got = state.bank_put(stored, rows[:, None], cols[:, None], 7,
+                         mask[:, None], n_slots=n_kept)
     np.testing.assert_array_equal(state.bank_view(np.asarray(got), width),
                                   want)
 

@@ -1,10 +1,11 @@
 """Pattern-builder unit tests: the paper's worked examples (§III-B, Fig 3,
 Fig 12, Fig 14) plus structural invariants (port exclusivity)."""
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import controller as ctl
-from repro.core.codes import get_tables
+from repro.core.codes import MAX_OPTS, get_tables
 from repro.core.state import make_params
 
 
@@ -165,3 +166,112 @@ def test_walk_bodies_hold_no_gather_or_scatter(scheme, faults):
         bad = {k: v for k, v in prims.items()
                if k == "gather" or k.startswith("scatter")}
         assert not bad, bad
+
+
+def _np_locate(t, p, region_slot, bank, row, rs_a):
+    """A request's coded address, re-derived from the code tables by plain
+    NumPy indexing."""
+    b, i = np.maximum(bank, 0), np.maximum(row, 0)
+    slot = region_slot[i // rs_a]
+    optj = t.opt_parity[b]
+    optjj = np.maximum(optj, 0)
+    pport = t.par_port[optjj]
+    return dict(b=b, i=i, region=i // rs_a, slot=slot, coded=slot >= 0,
+                pr=np.maximum(slot, 0) * p.region_size + i % rs_a,
+                optj=optj, optjj=optjj,
+                pport=np.where(pport < 0, p.n_ports, pport),
+                sibs=t.opt_sibs[b], mem=t.par_members[optjj])
+
+
+@pytest.mark.parametrize("padded", [False, True], ids=["static", "padded"])
+@pytest.mark.parametrize("n_rows", [512, 65536])
+@pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])
+def test_locate_matches_the_code_tables(scheme, n_rows, padded):
+    """``locate``, the one derivation of a request's coded address, against
+    the code tables: regions counted at the point's own region size, parity
+    rows at the allocated stride (a padded traced-geometry group allocates
+    twice the point's), mapped and unmapped regions, queue rows of -1."""
+    t = get_tables(scheme)
+    rs = max(1, round(n_rows * 0.05))
+    p = make_params(t, n_rows=n_rows, alpha=0.25, r=0.05,
+                    region_size_alloc=2 * rs if padded else None,
+                    traced_geometry=padded)
+    rs_a = jnp.int32(rs) if padded else None
+    assert p.region_size == (2 * rs if padded else rs)
+    rng = np.random.default_rng(n_rows + padded)
+    region_slot = np.full(p.n_regions, -1, np.int32)
+    mapped = rng.permutation(p.n_regions)[:p.n_slots]
+    region_slot[mapped] = rng.permutation(p.n_slots)
+    bank = rng.integers(0, p.n_data, 200).astype(np.int32)
+    row = rng.integers(-1, n_rows, 200).astype(np.int32)
+    row[:2] = (mapped[0] * rs, n_rows - 1)
+    got = ctl.locate(p, ctl.jtables(t), jnp.asarray(region_slot),
+                     jnp.asarray(bank), jnp.asarray(row), rs_a)
+    want = _np_locate(t, p, region_slot, bank, row, rs)
+    assert set(got._fields) == set(want)
+    for name, w in want.items():
+        np.testing.assert_array_equal(np.asarray(getattr(got, name)), w,
+                                      err_msg=name)
+    coded = np.asarray(got.coded)
+    assert coded[0] and not coded.all()
+
+
+@pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])
+def test_plans_carry_the_address_they_served(scheme):
+    """One cycle's read and write plans over queues with parked values,
+    stale parities and unmapped regions: each served candidate's parity,
+    parity row and siblings are what ``locate`` gives for its chosen
+    option (a read's parked value: the parity holding it), and -1 where
+    the action reads or writes no parity."""
+    t = get_tables(scheme)
+    p = make_params(t, n_rows=64, alpha=1.0, r=0.25)
+    jt = ctl.jtables(t)
+    rng = np.random.default_rng(7)
+    n, n_par = 24, max(t.n_parities, 1)
+    seen = set()
+    for _ in range(6):
+        fresh = (rng.integers(0, t.n_parities + 1, (p.n_data, 64))
+                 * (rng.random((p.n_data, 64)) < 0.2)).astype(np.int32)
+        pv = rng.random((n_par, p.n_slots * p.region_size)) < 0.8
+        rslot = np.where(rng.random(p.n_regions) < 0.75,
+                         np.arange(p.n_regions), -1).astype(np.int32)
+        cb = rng.integers(0, p.n_data, n).astype(np.int32)
+        ci = rng.integers(0, 64, n).astype(np.int32)
+        ca = rng.integers(0, 50, n).astype(np.int32)
+        cv = rng.random(n) < 0.9
+        pb = np.zeros(p.n_ports + 1, bool)
+        rc = np.full(p.recode_cap, -1, np.int32)
+        args = [jnp.asarray(a) for a in (cb, ci, ca, cv, pb, fresh, pv,
+                                         rslot)]
+        loc = ctl.locate(p, jt, args[7], args[0], args[1])
+        k = np.arange(MAX_OPTS)
+        rd = ctl.build_read_pattern(p, jt, *args)
+        mode = np.asarray(rd.mode)
+        opt = (mode >= ctl.MODE_OPT0) & (mode < ctl.MODE_REDIRECT)
+        sel = k == (mode - ctl.MODE_OPT0)[:, None]
+        want_par = np.where(mode == ctl.MODE_REDIRECT, fresh[cb, ci] - 1,
+                            np.where(opt, np.sum(np.where(
+                                sel, np.asarray(loc.optj), 0), axis=1), -1))
+        want_sibs = np.where(opt[:, None], np.sum(np.where(
+            sel[:, :, None], np.asarray(loc.sibs), 0), axis=1), -1)
+        np.testing.assert_array_equal(np.asarray(rd.par), want_par)
+        np.testing.assert_array_equal(np.asarray(rd.prow), loc.pr)
+        np.testing.assert_array_equal(np.asarray(rd.sibs), want_sibs)
+        assert (np.asarray(rd.par)[opt] >= 0).all()
+        wr = ctl.build_write_pattern(
+            p, jt, *args, jnp.zeros(p.n_regions, jnp.int32),
+            jnp.asarray(rc), jnp.asarray(rc), jnp.zeros(p.recode_cap, bool))
+        wmode = np.asarray(wr.mode)
+        park = wmode >= ctl.WMODE_PARK0
+        sel = k == (wmode - ctl.WMODE_PARK0)[:, None]
+        np.testing.assert_array_equal(
+            np.asarray(wr.par), np.where(park, np.sum(np.where(
+                sel, np.asarray(loc.optj), 0), axis=1), -1))
+        np.testing.assert_array_equal(np.asarray(wr.prow), loc.pr)
+        for name, hit in (("direct", mode == ctl.MODE_DIRECT),
+                          ("opt", opt), ("redirect", mode == ctl.MODE_REDIRECT),
+                          ("park", park)):
+            if hit.any():
+                seen.add(name)
+    assert seen == ({"direct", "opt", "redirect", "park"}
+                    if t.n_parities else {"direct"})
