@@ -285,6 +285,99 @@ def wide_scatters(closed_jaxpr, widths,
     return found
 
 
+def _derived(e, flags) -> List[bool]:
+    """Per output of the first-order equation ``e``: whether it derives
+    from an input marked in ``flags``, as an array shaped as one (an
+    in-place update, a select, a copy) and not a value read out of it."""
+    marked = {(tuple(v.aval.shape), v.aval.dtype)
+              for v, f in zip(e.invars, flags) if f}
+    return [(tuple(v.aval.shape), v.aval.dtype) in marked
+            for v in e.outvars]
+
+
+def wide_gathers(closed_jaxpr, widths, scope: str = "",
+                 sources: Optional[Sequence[int]] = None
+                 ) -> List[Tuple[tuple, str, int]]:
+    """``(shape, dtype, cells a point)`` of every gather, sub-jaxprs
+    included, traced under the named scope ``scope`` from an array a whole
+    bank wide (``widths`` as for ``_wide``). The cells a point are the
+    elements the gather reads for one point of a batch: from a table kept
+    in rows of lanes a TPU gather costs what it reads. Given ``sources``
+    (positions among the jaxpr's inputs), only gathers from those inputs
+    count: from an input itself or from what in-place updates, selects,
+    calls and loops make of it."""
+    from jax.extend.core import Literal
+
+    is_wide = _wide(widths)
+    found: List[Tuple[tuple, str, int]] = []
+
+    def visit(jpr, flags, collect):
+        """Walk ``jpr`` whose inputs are marked by ``flags``; the marks of
+        its outputs."""
+        marked = {v for v, f in zip(jpr.invars, flags) if f}
+
+        def on(vs):
+            return [sources is None or (not isinstance(v, Literal)
+                                        and v in marked) for v in vs]
+
+        for e in jpr.eqns:
+            ins, prm = on(e.invars), e.params
+            name = e.primitive.name
+            if name == "while":
+                nc, nb = prm["cond_nconsts"], prm["body_nconsts"]
+                consts, carry = ins[nc:nc + nb], ins[nc + nb:]
+                body = prm["body_jaxpr"].jaxpr
+                while True:          # the marks a carry takes in any trip
+                    out = visit(body, consts + carry, False)
+                    new = [a or b for a, b in zip(carry, out)]
+                    if new == carry:
+                        break
+                    carry = new
+                visit(prm["cond_jaxpr"].jaxpr, ins[:nc] + carry, collect)
+                outs = visit(body, consts + carry, collect)
+            else:
+                subs = list(jax.core.jaxprs_in_params(prm))
+                if len(subs) == 1 and len(subs[0].invars) == len(ins):
+                    outs = visit(subs[0], ins, collect)    # a call
+                else:
+                    # any other form (a branch, a scatter's combiner): its
+                    # sub-jaxprs see every input marked where one is
+                    for sub in subs:
+                        visit(sub, [any(ins)] * len(sub.invars), collect)
+                    outs = _derived(e, ins)
+            marked.update(v for v, f in zip(e.outvars, outs) if f)
+            if not (collect and name == "gather" and ins[0]
+                    and scope in str(e.source_info.name_stack)):
+                continue
+            operand, indices = e.invars[0].aval, e.invars[1].aval
+            if not is_wide(operand.shape):
+                continue
+            # the last dimension of the indices holds one index tuple
+            dn = prm["dimension_numbers"]
+            per_point = [n for k, n in enumerate(indices.shape[:-1])
+                         if k not in dn.start_indices_batching_dims]
+            window = [n for k, n in enumerate(prm["slice_sizes"])
+                      if k not in dn.operand_batching_dims]
+            found.append((operand.shape, str(operand.dtype),
+                          math.prod(per_point) * math.prod(window)))
+        return [sources is None or v in marked for v in jpr.outvars]
+
+    jpr = closed_jaxpr.jaxpr
+    visit(jpr, [sources is None or k in sources
+                for k in range(len(jpr.invars))], True)
+    return found
+
+
+def cycle_state_input(sys, field: str) -> int:
+    """The position of the state leaf ``mem.<field>`` among the inputs of
+    ``sys.cycle_fn``'s jaxpr (``batched_cycle_jaxpr``): the state's leaves
+    come first."""
+    st = jax.eval_shape(sys.init)
+    leaves, treedef = jax.tree.flatten(st)
+    return getattr(jax.tree.unflatten(treedef, range(len(leaves))).mem,
+                   field)
+
+
 def batched_cycle_jaxpr(pt=None, batch: int = 2):
     """(the jaxpr of ``cycle_fn`` as the sweep engine runs it, under
     ``vmap`` over ``batch`` points; a bank's widths for ``_wide``; the

@@ -116,10 +116,11 @@ TRACED_FUNCTIONS: Dict[str, Set[str]] = {
 HOST_FUNCTIONS: Dict[str, Set[str]] = {
     "src/repro/core/__init__.py": {"*"},
     "src/repro/core/codes.py": {"*"},
-    "src/repro/core/controller.py": {"jtables", "write_slots"},
+    "src/repro/core/controller.py": {"_neighbourhoods", "jtables",
+                                     "write_slots", "fresh_cells"},
     "src/repro/core/state.py": {
         "make_tunables", "wide_total", "derive_geometry", "make_params",
-        "_concrete_int", "bank_view"},
+        "_concrete_int", "bank_view", "in_lanes"},
     "src/repro/core/system.py": {
         "drain_bound", "result_from_host", "CodedMemorySystem.__init__",
         "CodedMemorySystem.init", "CodedMemorySystem.run",

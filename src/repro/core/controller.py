@@ -53,7 +53,13 @@ contains:
     derivation of a request's region, parity row and parity options,
     shared by every unit of the cycle), the bank tables' cells by
     ``state.bank_cell`` (one-hot, or gather where the state keeps a bank
-    in rows of lanes: ``core/state.py`` alone knows the two forms). A
+    in rows of lanes: ``core/state.py`` alone knows the two forms).
+    Freshness is looked up over a cell's neighbourhood alone
+    (``JTables.near``: its own bank, then every other member of its
+    options' parities; 4 banks under schemes I and II, up to 7 under
+    scheme III, 1 uncoded), not over every bank at its row: no decision
+    reads another bank's freshness, and a gather costs what it reads
+    (``fresh_cells``). A
     trip picks its candidate's row with one masked reduction and updates
     every row by compare-and-select, so the loop body holds no gather or
     scatter. Under the sweep engine's ``vmap`` the trip counter is
@@ -95,7 +101,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codes import MAX_OPTS, CodeTables
-from repro.core.state import MemParams, bank_cell, bank_put, take
+from repro.core.state import MemParams, bank_cell, bank_put, in_lanes, take
 
 INT32_MAX = jnp.iinfo(jnp.int32).max
 INF_SCORE = jnp.int32(1 << 30)
@@ -121,6 +127,21 @@ class JTables(NamedTuple):
     opt_parity: jnp.ndarray    # (n_data, MAX_OPTS)
     opt_sibs: jnp.ndarray      # (n_data, MAX_OPTS, MAX_SIBS)
     opt_n: jnp.ndarray         # (n_data,)
+    near: jnp.ndarray          # (n_data, U) each bank's neighbourhood
+
+
+def _neighbourhoods(tables: CodeTables) -> np.ndarray:
+    """(n_data, U) int32: row b holds bank b, then every other member of
+    b's options' parities in ascending order, -1 padded to U, the widest
+    neighbourhood of the code. A cell's freshness decides nothing about
+    another cell of its row outside these banks."""
+    near = []
+    for b in range(tables.n_data):
+        opts = tables.opt_parity[b]
+        mem = tables.par_members[opts[opts >= 0]]
+        near.append([b] + sorted(set(mem[mem >= 0].tolist()) - {b}))
+    u = max(map(len, near))
+    return np.array([r + [-1] * (u - len(r)) for r in near], np.int32)
 
 
 def jtables(tables: CodeTables) -> JTables:
@@ -130,6 +151,7 @@ def jtables(tables: CodeTables) -> JTables:
         opt_parity=jnp.asarray(tables.opt_parity),
         opt_sibs=jnp.asarray(tables.opt_sibs),
         opt_n=jnp.asarray(tables.opt_n),
+        near=jnp.asarray(_neighbourhoods(tables)),
     )
 
 
@@ -149,6 +171,8 @@ class Loc(NamedTuple):
                          # none
     sibs: jnp.ndarray    # (..., K, MAX_SIBS) their sibling banks, -1 pad
     mem: jnp.ndarray     # (..., K, MAX_SIBS+1) their members, -1 pad
+    near: jnp.ndarray    # (..., U) the bank's neighbourhood (``b`` first),
+                         # -1 pad
 
 
 def locate(p: MemParams, t: JTables, region_slot, bank, row,
@@ -174,7 +198,8 @@ def locate(p: MemParams, t: JTables, region_slot, bank, row,
     return Loc(b=b, i=i, region=region, slot=slot, coded=slot >= 0,
                pr=jnp.maximum(slot, 0) * rs + i % rs_a, optj=optj,
                optjj=optjj, pport=jnp.where(pport < 0, p.n_ports, pport),
-               sibs=take(t.opt_sibs, b), mem=take(t.par_members, optjj))
+               sibs=take(t.opt_sibs, b), mem=take(t.par_members, optjj),
+               near=take(t.near, b))
 
 
 class ReadPlan(NamedTuple):
@@ -244,6 +269,19 @@ def write_slots(p: MemParams) -> WriteSlots:
     return WriteSlots(banks_data=p.n_data, parity_data=n_par_ports,
                       golden=p.n_ports, fresh_loc=p.n_ports,
                       parity_valid=p.n_data * MAX_OPTS + n_par_ports)
+
+
+def fresh_cells(p: MemParams, t: JTables) -> int:
+    """The ``fresh_loc`` cells a point's read builder, write builder and
+    ReCoding unit gather in a cycle: each candidate's own cell, and each
+    candidate's and each ring entry's neighbourhood at its row
+    (``JTables.near``, U banks). ``state.bank_cell`` gathers these by cell,
+    since a neighbourhood is never wider than the banks. 0 where the table
+    is read by one-hot (``state.in_lanes``)."""
+    if not in_lanes(p.n_rows):
+        return 0
+    n, u = p.n_data * p.queue_depth, t.near.shape[1]
+    return n + n * u + p.recode_cap * u
 
 
 def _walk_bounds(cand_age, cand_valid):
@@ -536,16 +574,16 @@ def build_write_pattern(
             (optj >= 0) & coded[:, None] & opt_down, axis=1)
         dir_score = jnp.where(sticky, 2 + 2 * MAX_OPTS + 2, 1)
         park_score = park_base + jnp.where(opt_down, MAX_OPTS + 2, 0)
-    # The walk's state, per candidate. ``fl``: freshness of its own cell
-    # (column 0) and of its options' parity-group members at its row; a
-    # write updates every copy of its cell, and copies of one cell stay
-    # equal, so one scatter of column 0 writes ``fresh_loc`` back after the
-    # walk. ``busy``: the port of each column is claimed. The recode ring
+    # The walk's state, per candidate. ``fl``: freshness at its row of the
+    # banks of its neighbourhood (``near``: its own cell in column 0, then
+    # every other member of its options' parities, -1 padded); a write
+    # updates every copy of its cell, and copies of one cell stay equal, so
+    # one scatter of column 0 writes ``fresh_loc`` back after the walk.
+    # ``busy``: the port of each column is claimed. The recode ring
     # only fills during the walk, the t-th push into the t-th free slot;
     # ``pend``: the candidate's cell is in the ring. ``delta`` records each
     # candidate's change of its region's parked count, ``push`` the ordinal
     # of its push (-1: none).
-    fl_bank = jnp.concatenate([b[:, None], mem.reshape(n, -1)], axis=1)
     port_ix = jnp.concatenate([b[:, None], opt_pport], axis=1)   # (N, 1+K)
     free = ~rc_valid
     n_free = jnp.sum(free).astype(jnp.int32)
@@ -555,12 +593,12 @@ def build_write_pattern(
         park_possible=cand_valid[:, None] & (optj >= 0) & coded[:, None],
         pport=opt_pport, optjj=optjj, park_score=park_score, mem=mem,
         busy=take(port_busy, port_ix),
-        fl=bank_cell(fresh_loc, jnp.maximum(fl_bank, 0), i[:, None]),
+        fl=bank_cell(fresh_loc, jnp.maximum(loc.near, 0), i[:, None]),
         pend=jnp.any(rc_valid & (rc_bank == b[:, None])
                      & (rc_row == i[:, None]), axis=1),
         mode=jnp.full((n,), WMODE_UNSERVED, jnp.int32),
         delta=jnp.zeros((n,), jnp.int32), push=jnp.full((n,), -1, jnp.int32),
-    ), keys=dict(busy=port_ix, fl=fl_bank, pend=b))
+    ), keys=dict(busy=port_ix, fl=loc.near, pend=b))
 
     def cond(carry):
         return carry[0] < n_trips
@@ -574,14 +612,16 @@ def build_write_pattern(
         rc_space = n_ins < n_free
 
         # --- score direct + park options ---------------------------------
+        # an option is occupied where another member's value is parked in
+        # it; a member's freshness is the ``fl`` column keyed by its bank
+        near_c = take(t.near, bc)
         f_dir = c["valid"] & ~bz[0]
         scores = [jnp.where(f_dir, c["dir_score"], INF_SCORE)]
         for j in range(K):
             occ = jnp.bool_(False)
-            for s in range(mem_c.shape[1]):
-                occ = occ | ((mem_c[j, s] >= 0) & (mem_c[j, s] != bc)
-                             & (flr[1 + j * mem_c.shape[1] + s]
-                                == optjj_c[j] + 1))
+            for m in mem_c[j]:
+                fl_m = jnp.sum(jnp.where(near_c == m, flr, 0))
+                occ = occ | ((m >= 0) & (m != bc) & (fl_m == optjj_c[j] + 1))
             park_feas = (c["park_possible"][j] & ~bz[1 + j] & ~occ
                          & (rc_space | c["sticky"]))
             scores.append(jnp.where(park_feas, c["park_score"][j], INF_SCORE))

@@ -70,9 +70,10 @@ def recode_step(
     tests/test_conformance.py; an empty or workless ring costs one trip.
 
     The walk carries only what its decisions read, per entry: the
-    freshness of every bank at the entry's row and the validity of each
-    of its options' parities at its parity row, looked up once before it
-    (``state.bank_col``, ``state.bank_cell``). A
+    freshness at the entry's row of the banks of its neighbourhood
+    (``Loc.near``: its own bank, then every other member of its options'
+    parities) and the validity of each of its options' parities at its
+    parity row, looked up once before it (``state.bank_cell``). A
     retirement updates the copies of the cells it writes, by compare and
     select, and records itself; the data it moves (the restored row, the
     recomputed parities, which decide nothing) and the state it leaves are
@@ -93,15 +94,16 @@ def recode_step(
     n_rec = p.recode_budget
     loc = locate(p, t, region_slot, rc_bank, rc_row, rs_active)   # (E, ...)
     b, i, coded, pr = loc.b, loc.i, loc.coded, loc.pr
-    optj, optjj, mem = loc.optj, loc.optjj, loc.mem
+    optj, optjj, mem, near = loc.optj, loc.optjj, loc.mem, loc.near
     memc = jnp.maximum(mem, 0)
     epos = jnp.arange(cap, dtype=jnp.int32)
     banks = jnp.arange(p.n_data, dtype=jnp.int32)
     pars = jnp.arange(p.n_parities, dtype=jnp.int32)
     ports = jnp.arange(port_busy.shape[0], dtype=jnp.int32)
     nsink = jnp.int32(p.n_ports)     # masked-index slot: never busy/claimed
-    on_b = b[:, None] == banks                                  # (E, n_data)
-    on_mem = memc[..., None] == banks                     # (E, K, S, n_data)
+    # ``fl_row``'s columns are keyed by ``near``: the entry's own cell is
+    # column 0, each member's its bank's column
+    on_mem = memc[..., None] == near[:, None, None, :]         # (E, K, S, U)
     on_opt = optjj[..., None] == pars                        # (E, K, n_par)
     if down is not None:
         # fault-blocking is loop-invariant: down membership doesn't change
@@ -118,7 +120,7 @@ def recode_step(
         (cursor, budget, port_busy, fl_row, pv, rc_valid, rec_e,
          rec_do, rec_inv, rec_fl) = carry
         # ---- per-entry work set under the current state ------------------
-        fl = jnp.sum(jnp.where(on_b, fl_row, 0), axis=1)
+        fl = fl_row[:, 0]
         parked = fl > 0
         holder = jnp.maximum(fl - 1, 0)
         mem_fl = jnp.sum(jnp.where(on_mem, fl_row[:, None, None, :], 0),
@@ -175,7 +177,7 @@ def recode_step(
         inv_k = of_e(blocked_l) & of_e(parked)
         # the cells it writes, in every entry's copies: its own freshness,
         # and the validity of the parities it recomputes or invalidates
-        fl_row = jnp.where((i[:, None] == of_e(i)) & (banks == of_e(b))
+        fl_row = jnp.where((i[:, None] == of_e(i)) & (near == of_e(b))
                            & any_tf, 0, fl_row)
         on_k = of_e(on_opt)                                # (K, n_par)
         wrote = jnp.any(on_k & (do_k | inv_k)[:, None], axis=0)
@@ -196,7 +198,7 @@ def recode_step(
 
     no_k = jnp.zeros((n_rec, MAX_OPTS), bool)
     carry = (jnp.int32(-1), jnp.int32(p.recode_budget), port_busy,
-             bank_col(fresh_loc, i),
+             bank_cell(fresh_loc, jnp.maximum(near, 0), i[:, None]),
              bank_cell(parity_valid, optjj, pr[:, None]),
              rc_valid,
              jnp.full((n_rec,), -1, jnp.int32), no_k, no_k,

@@ -322,11 +322,17 @@ ONEHOT_MAX_COLS = 2048
 LANES = 128
 
 
+def in_lanes(width: int) -> bool:
+    """Whether a bank table ``width`` columns wide is kept in rows of
+    lanes, and so read by gather, not by one-hot."""
+    return width > ONEHOT_MAX_COLS
+
+
 def bank_table(x):
     """The stored form of a logical (banks, width) table."""
     n, width = x.shape
     # static: a shape against a module constant
-    if width <= ONEHOT_MAX_COLS:  # analysis: tracer-branch
+    if not in_lanes(width):  # analysis: tracer-branch
         return x
     return jnp.pad(x, ((0, 0), (0, -width % LANES))).reshape(n, -1, LANES)
 

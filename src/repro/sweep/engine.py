@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.codes import get_tables
-from repro.core.controller import write_slots
+from repro.core.controller import fresh_cells, write_slots
 from repro.core.state import TunableParams, make_params, make_tunables
 from repro.core.system import (CodedMemorySystem, SimResult, SimState, Trace,
                                quiescent, result_from_host)
@@ -265,11 +265,13 @@ def run_batch(batch: GridBatch, traces: Optional[Sequence[Trace]] = None,
             # holds the while-loop's trip count
             trips = int(np.max(host.mem.cycle))
             # the cells a cycle's write datapath has room for (one a port)
-            # beside the writes a point served on average in a trip
+            # beside the writes a point served on average in a trip, and
+            # the freshness cells a point gathers in a trip
             writes = int(np.sum(host.mem.served_writes[:len(pts)]))
             s.set_metadata(trips=trips,
                            write_slots=write_slots(sys.p).golden,
-                           writes_per_trip=writes / max(len(pts) * trips, 1))
+                           writes_per_trip=writes / max(len(pts) * trips, 1),
+                           fresh_cells=fresh_cells(sys.p, sys.t))
             results = summarize_batch(host, n_points=len(pts))
         if not collect_telemetry:
             return results

@@ -296,6 +296,54 @@ def test_write_datapath_scatters_follow_the_ports():
     assert max(n for _, _, n in found) < p.n_data * p.queue_depth
 
 
+def test_wide_gathers_follow_a_source_through_updates_and_loops():
+    """Given a source, ``wide_gathers`` counts the gathers from that input
+    and from what a scatter and a loop carry make of it, and leaves out a
+    gather from another table of the same shape."""
+    def fn(a, b, idx):
+        a = a.at[idx].set(0)
+        a = jax.lax.fori_loop(0, 2, lambda k, x: x.at[k].set(k), a)
+        return a[idx[:3]].sum() + b[idx].sum()
+
+    bank = jax.ShapeDtypeStruct((512,), jnp.int32)
+    idx = jax.ShapeDtypeStruct((5,), jnp.int32)
+    jpr = jax.make_jaxpr(fn)(bank, bank, idx)
+    cells = lambda src: sorted(n for _, _, n in jaxpr.wide_gathers(  # noqa
+        jpr, (512,), sources=src))
+    assert cells(None) == [3, 5]
+    assert cells((0,)) == [3]
+    assert cells((1,)) == [5]
+    assert cells((2,)) == []
+
+
+def test_freshness_gathers_follow_the_neighbourhood():
+    """In the batched ``cycle_fn`` at 65,536 rows (``fresh_loc`` kept in
+    rows of lanes), each gather from ``fresh_loc`` reads a cell's
+    neighbourhood (``JTables.near``, U banks) at most: 80 x U cells a
+    point in the pattern builders, ``recode_cap`` x U in the ReCoding
+    unit, not every bank at each row. Their sum is ``fresh_cells``."""
+    from repro.core.controller import fresh_cells
+    from repro.sweep.grid import SweepPoint
+
+    jpr, widths, sys = jaxpr.batched_cycle_jaxpr(
+        SweepPoint(n_rows=65536, length=8, alpha=0.25, r=0.05))
+    p, u = sys.p, sys.t.near.shape[1]
+    n = p.n_data * p.queue_depth
+    src = (jaxpr.cycle_state_input(sys, "fresh_loc"),)
+
+    def cells(scope):
+        return sorted(c for _, _, c in jaxpr.wide_gathers(
+            jpr, widths, scope=scope, sources=src))
+
+    patterns, recode = cells("cycle.patterns"), cells("cycle.recode")
+    # the read builder's own cells, the write builder's neighbourhoods
+    assert patterns == [n, n * u] and max(patterns) <= n * u
+    assert recode == [p.recode_cap * u]
+    assert (u, n * u, p.recode_cap * u) == (4, 320, 256)     # scheme I
+    assert sum(patterns + recode) == fresh_cells(p, sys.t) == 656
+    assert cells("") == sorted(patterns + recode)
+
+
 @pytest.mark.slow
 def test_jaxpr_layer_clean_on_src():
     assert jaxpr.run() == []

@@ -30,7 +30,8 @@ from conftest import assert_state_matches_oracle, oracle_twin, rand_trace
 from repro.core import controller as ctl
 from repro.core.codes import get_tables
 from repro.core.recoding import recode_step as jax_recode_step
-from repro.core.state import ONEHOT_MAX_COLS, make_params, make_tunables
+from repro.core.state import (ONEHOT_MAX_COLS, bank_table, bank_view,
+                              make_params, make_tunables)
 from repro.core.system import CodedMemorySystem, drain_bound
 from repro import oracle
 from repro.oracle import (OracleMemorySystem, OracleParams, build_read_plan,
@@ -42,6 +43,29 @@ SCHEMES = ["scheme_i", "scheme_ii", "scheme_iii", "replication_2", "uncoded"]
 _read_jax = jax.jit(ctl.build_read_pattern, static_argnums=0)
 _write_jax = jax.jit(ctl.build_write_pattern, static_argnums=0)
 _recode_jax = jax.jit(jax_recode_step, static_argnums=0)
+
+# Plans and recode outcomes are checked at 16 rows, where the state keeps
+# the bank tables (banks, rows) and indexes them by one-hot, and at 4,096
+# rows, where it keeps them in rows of lanes (``state.bank_table``) and the
+# builders and the ReCoding unit gather their cells (a cell's neighbourhood
+# of banks, ``JTables.near``: up to 7 under scheme III, padded to 7).
+WIDE_PLAN_ROWS = 4096
+
+
+def _rows_cases(schemes, wide_schemes=None):
+    """(scheme, n_rows) cases: ``schemes`` at 16 rows, under their own
+    names, and ``wide_schemes`` (default: the same) at ``WIDE_PLAN_ROWS``."""
+    wide = schemes if wide_schemes is None else wide_schemes
+    return ([pytest.param(s, 16, id=s) for s in schemes]
+            + [pytest.param(s, WIDE_PLAN_ROWS, id=f"{s}-{WIDE_PLAN_ROWS}")
+               for s in wide])
+
+
+def _stored(a):
+    """The JAX input for a host array: a bank table in the form the state
+    keeps it (``bank_table``), any other array as it is."""
+    a = jnp.asarray(a)
+    return bank_table(a) if a.ndim == 2 else a
 
 
 # ------------------------------------------------------------------- tables
@@ -108,16 +132,37 @@ def _geom(scheme, n_rows=16, alpha=1.0, r=0.25, rc_cap=8):
     return t, p, ctl.jtables(t), om
 
 
-def _rand_mem(rng, t, p, n_rows):
+def _hot_rows(rng, n_rows):
+    """A few rows for the candidates and the recode ring of a wide state
+    to share, so that they meet on a row as often as at 16 rows; None at
+    16 rows, where every row is drawn."""
+    return None if n_rows <= 16 else rng.integers(0, n_rows, 6)
+
+
+def _rows(rng, n_rows, n, hot):
+    return (rng.integers(0, n_rows, n) if hot is None
+            else rng.choice(hot, n)).astype(np.int32)
+
+
+def _rand_mem(rng, t, p, n_rows, hot=None):
     """Random reachable controller state: freshness points only at real
     logical parities (a bank with no parities can never be parked), the
     recode ring fill includes FULL, the region map is a random partial
-    injection."""
+    injection. ``hot``: the rows the ring's entries are drawn from."""
     nb = p.n_data
     n_logical = len(t.scheme.members)
     fresh = np.asarray(
         rng.integers(0, n_logical + 1, (nb, n_rows))
         * (rng.random((nb, n_rows)) < 0.25), np.int32)
+    if hot is not None:
+        # at the shared rows, half the cells park in one of their own
+        # bank's options, so that an option another member's value is
+        # parked in, and a ring entry's parked neighbour, are common
+        for b in range(nb):
+            opts = t.opt_parity[b, :t.opt_n[b]]
+            if opts.size:
+                fresh[b, hot] = np.where(rng.random(hot.size) < 0.5,
+                                         rng.choice(opts, hot.size) + 1, 0)
     pv = rng.random((p.n_parities, p.n_slots * p.region_size)) < 0.7
     rslot = np.full(p.n_regions, -1, np.int32)
     slots = rng.permutation(p.n_slots)
@@ -129,14 +174,14 @@ def _rand_mem(rng, t, p, n_rows):
     rcv = np.zeros(cap, bool)
     rcv[rng.permutation(cap)[:fill]] = True
     rcb = np.where(rcv, rng.integers(0, nb, cap), -1).astype(np.int32)
-    rcr = np.where(rcv, rng.integers(0, n_rows, cap), -1).astype(np.int32)
+    rcr = np.where(rcv, _rows(rng, n_rows, cap, hot), -1).astype(np.int32)
     parked = rng.integers(0, 3, p.n_regions).astype(np.int32)
     return fresh, pv, rslot, parked, rcb, rcr, rcv
 
 
-def _rand_cands(rng, p, n_rows, n=24):
+def _rand_cands(rng, p, n_rows, n=24, hot=None):
     cb = rng.integers(0, p.n_data, n).astype(np.int32)
-    ci = rng.integers(0, n_rows, n).astype(np.int32)
+    ci = _rows(rng, n_rows, n, hot)
     ca = rng.integers(0, 50, n).astype(np.int32)   # age ties likely
     cv = rng.random(n) < 0.8
     pb = np.append(rng.random(p.n_ports) < 0.3, False)
@@ -144,24 +189,27 @@ def _rand_cands(rng, p, n_rows, n=24):
 
 
 def _assert_plans_equal(got, want, label):
-    """jax plan pytree vs oracle plan namedtuple, matched by field name."""
+    """jax plan pytree vs oracle plan namedtuple, matched by field name; a
+    bank table kept in rows of lanes is compared as its logical table."""
     for name in want._fields:
-        np.testing.assert_array_equal(
-            np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
-            err_msg=f"{label}: field {name!r}")
+        g, w = np.asarray(getattr(got, name)), np.asarray(getattr(want, name))
+        if g.ndim == w.ndim + 1:
+            g = bank_view(g, w.shape[-1])
+        np.testing.assert_array_equal(g, w, err_msg=f"{label}: field {name!r}")
 
 
-def check_plan_conformance(scheme, seed):
-    n_rows = 16
-    t, p, jt, om = _geom(scheme)
+def check_plan_conformance(scheme, seed, n_rows=16):
+    t, p, jt, om = _geom(scheme, n_rows=n_rows)
     rng = np.random.default_rng(seed)
-    fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(rng, t, p, n_rows)
-    cb, ci, ca, cv, pb = _rand_cands(rng, p, n_rows)
-    got = _read_jax(p, jt, *map(jnp.asarray,
+    hot = _hot_rows(rng, n_rows)
+    fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(rng, t, p, n_rows,
+                                                        hot)
+    cb, ci, ca, cv, pb = _rand_cands(rng, p, n_rows, hot=hot)
+    got = _read_jax(p, jt, *map(_stored,
                                 (cb, ci, ca, cv, pb, fresh, pv, rslot)))
     want = build_read_plan(om, cb, ci, ca, cv, pb, fresh, pv, rslot)
     _assert_plans_equal(got, want, f"ReadPlan {scheme} seed={seed}")
-    got = _write_jax(p, jt, *map(jnp.asarray,
+    got = _write_jax(p, jt, *map(_stored,
                                  (cb, ci, ca, cv, pb, fresh, pv, rslot,
                                   parked, rcb, rcr, rcv)))
     want = build_write_plan(om, cb, ci, ca, cv, pb, fresh, pv, rslot,
@@ -169,15 +217,15 @@ def check_plan_conformance(scheme, seed):
     _assert_plans_equal(got, want, f"WritePlan {scheme} seed={seed}")
 
 
-def check_recode_conformance(scheme, seed):
-    n_rows = 16
-    t, p, jt, om = _geom(scheme)
+def check_recode_conformance(scheme, seed, n_rows=16):
+    t, p, jt, om = _geom(scheme, n_rows=n_rows)
     rng = np.random.default_rng(seed)
-    fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(rng, t, p, n_rows)
+    fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(
+        rng, t, p, n_rows, _hot_rows(rng, n_rows))
     pb = np.append(rng.random(p.n_ports) < 0.3, False)
     banks = rng.integers(0, 1 << 20, (p.n_data, n_rows)).astype(np.int32)
     pdata = rng.integers(0, 1 << 20, pv.shape).astype(np.int32)
-    got = _recode_jax(p, jt, *map(jnp.asarray,
+    got = _recode_jax(p, jt, *map(_stored,
                                   (pb, fresh, pv, parked, rcb, rcr, rcv,
                                    rslot, banks, pdata)))
     want = oracle_recode_step(om, pb, fresh, pv, parked, rcb, rcr, rcv,
@@ -185,31 +233,31 @@ def check_recode_conformance(scheme, seed):
     _assert_plans_equal(got, want, f"RecodeOut {scheme} seed={seed}")
 
 
-@pytest.mark.parametrize("scheme", SCHEMES)
-def test_plan_conformance_random_states(scheme):
+@pytest.mark.parametrize("scheme,n_rows", _rows_cases(SCHEMES))
+def test_plan_conformance_random_states(scheme, n_rows):
     """Read/write plans are bit-identical to the golden model across random
     queue/port/freshness/parity/ring states (incl. full recode rings)."""
     for seed in range(6):
-        check_plan_conformance(scheme, seed)
+        check_plan_conformance(scheme, seed, n_rows)
 
 
-def _batch_states(scheme, seed, n_points=8, n=24):
+def _batch_states(scheme, seed, n_points=8, n=24, n_rows=16):
     """``n_points`` random states, each with another count of valid
     candidates (none to all), duplicate (bank, row) candidates, candidates
     sharing a row across banks, and a full recode ring at every third
     point."""
-    n_rows = 16
-    t, p, jt, om = _geom(scheme)
+    t, p, jt, om = _geom(scheme, n_rows=n_rows)
     rng = np.random.default_rng(seed)
     pts = []
     for j, n_valid in enumerate(np.linspace(0, n, n_points).astype(int)):
+        hot = _hot_rows(rng, n_rows)
         fresh, pv, rslot, parked, rcb, rcr, rcv = _rand_mem(rng, t, p,
-                                                            n_rows)
+                                                            n_rows, hot)
         if j % 3 == 1:
             rcv = np.ones_like(rcv)
             rcb = rng.integers(0, p.n_data, rcv.size).astype(np.int32)
-            rcr = rng.integers(0, n_rows, rcv.size).astype(np.int32)
-        cb, ci, ca, _, pb = _rand_cands(rng, p, n_rows, n)
+            rcr = _rows(rng, n_rows, rcv.size, hot)
+        cb, ci, ca, _, pb = _rand_cands(rng, p, n_rows, n, hot)
         cb[4:8], ci[4:8] = cb[0:4], ci[0:4]           # duplicate cells
         ci[8:12] = ci[0]                               # one row, many banks
         cv = np.zeros(n, bool)
@@ -221,15 +269,15 @@ def _batch_states(scheme, seed, n_points=8, n=24):
 
 
 @pytest.mark.parametrize("degraded", [False, True], ids=["all-up", "down"])
-@pytest.mark.parametrize("scheme", SCHEMES)
-def test_batched_plan_conformance(scheme, degraded):
+@pytest.mark.parametrize("scheme,n_rows", _rows_cases(SCHEMES))
+def test_batched_plan_conformance(scheme, n_rows, degraded):
     """The builders vmapped over a batch of states, as the sweep engine
     runs them: the walk's trip counter is batched, and the walk runs as
     many trips as the fullest point needs. Every point's plan equals the
     golden model's, field by field; ``degraded`` gives each point its own
     down banks (the write builder's ``down=``)."""
-    p, jt, om, pts = _batch_states(scheme, seed=11 + degraded)
-    cols = [jnp.asarray(np.stack(a)) for a in zip(*pts)]
+    p, jt, om, pts = _batch_states(scheme, seed=11 + degraded, n_rows=n_rows)
+    cols = [jnp.stack([_stored(x) for x in a]) for a in zip(*pts)]
     read = jax.jit(jax.vmap(functools.partial(ctl.build_read_pattern, p, jt)))
     got_r = read(*cols[:8])
 
@@ -253,10 +301,11 @@ def test_batched_plan_conformance(scheme, degraded):
             f"WritePlan {label}")
 
 
-@pytest.mark.parametrize("scheme", ["scheme_i", "scheme_iii"])
-def test_recode_conformance_random_states(scheme):
+@pytest.mark.parametrize("scheme,n_rows",
+                         _rows_cases(["scheme_i", "scheme_iii"], SCHEMES))
+def test_recode_conformance_random_states(scheme, n_rows):
     for seed in range(6):
-        check_recode_conformance(scheme, 1000 + seed)
+        check_recode_conformance(scheme, 1000 + seed, n_rows)
 
 
 # ------------------------------------------------------------ full workloads

@@ -176,11 +176,17 @@ def _np_locate(t, p, region_slot, bank, row, rs_a):
     optj = t.opt_parity[b]
     optjj = np.maximum(optj, 0)
     pport = t.par_port[optjj]
+    # a bank's neighbourhood: itself, then the other banks it shares a
+    # parity with, padded with -1 to the widest
+    near = [[d] + sorted({m for ms in t.scheme.members if d in ms
+                          for m in ms} - {d}) for d in range(t.n_data)]
+    u = max(map(len, near))
+    near = np.array([r + [-1] * (u - len(r)) for r in near])
     return dict(b=b, i=i, region=i // rs_a, slot=slot, coded=slot >= 0,
                 pr=np.maximum(slot, 0) * p.region_size + i % rs_a,
                 optj=optj, optjj=optjj,
                 pport=np.where(pport < 0, p.n_ports, pport),
-                sibs=t.opt_sibs[b], mem=t.par_members[optjj])
+                sibs=t.opt_sibs[b], mem=t.par_members[optjj], near=near[b])
 
 
 @pytest.mark.parametrize("padded", [False, True], ids=["static", "padded"])
@@ -214,6 +220,24 @@ def test_locate_matches_the_code_tables(scheme, n_rows, padded):
                                       err_msg=name)
     coded = np.asarray(got.coded)
     assert coded[0] and not coded.all()
+
+
+@pytest.mark.parametrize("scheme,u", [
+    ("scheme_i", 4), ("scheme_ii", 4), ("scheme_iii", 7), ("uncoded", 1),
+    ("replication_2", 1)])
+def test_fresh_cells_count_the_neighbourhoods(scheme, u):
+    """The ``fresh_loc`` cells a point gathers in a cycle: each of the 80
+    candidates' own cell and its neighbourhood of U banks, and each of the
+    64 ring entries' neighbourhood; none where the table is read by
+    one-hot (512 rows)."""
+    t = get_tables(scheme)
+    jt = ctl.jtables(t)
+    assert jt.near.shape == (t.n_data, u)
+    for n_rows, want in ((512, 0), (65536, 80 + 80 * u + 64 * u)):
+        p = make_params(t, n_rows=n_rows, alpha=0.25, r=0.05)
+        assert ctl.fresh_cells(p, jt) == want
+    if scheme == "scheme_i":
+        assert want == 656
 
 
 @pytest.mark.parametrize("scheme", ["scheme_i", "uncoded"])

@@ -136,6 +136,20 @@ def test_summarize_counts_the_write_slots_a_trip_uses(profiled):
         assert 0 < st["writes_per_trip"] <= st["write_slots"]
 
 
+def test_summarize_counts_the_freshness_cells_a_trip_gathers(profiled):
+    """``sweep.summarize`` gives the ``fresh_loc`` cells a point's cycle
+    gathers (``fresh_cells``): none at these small banks, which the state
+    keeps for one-hot lookups."""
+    from repro.core.controller import fresh_cells
+
+    _, _, _, spans = profiled
+    stats = [s[3] for s in spans if s[0] == "sweep.summarize"]
+    assert len(stats) == len(partition(POINTS))
+    for batch, st in zip(partition(POINTS), stats):
+        sys_ = engine.system_for(batch.points[0])
+        assert st["fresh_cells"] == fresh_cells(sys_.p, sys_.t) == 0
+
+
 def test_profiling_changes_no_result_and_compiles_nothing(profiled):
     off, on, seen, _ = profiled
     assert on == off
